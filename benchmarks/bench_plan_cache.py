@@ -43,6 +43,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core import CSR, random_csr, random_spd_csr
+from repro.launch.compile_cache import init_compile_cache
 from repro.runtime import ReapRuntime, RuntimeConfig, add_runtime_args
 
 # per-op coverage is registry-driven and shared with fig6/fig10 (and the
@@ -354,6 +355,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="write result rows to this JSON file")
     add_runtime_args(ap)
     args = ap.parse_args(argv)
+    init_compile_cache()
     global _BASE_CFG
     _BASE_CFG = RuntimeConfig.from_args(args)
     rows = run(reduced=args.reduced)

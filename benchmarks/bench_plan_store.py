@@ -61,6 +61,7 @@ import jax.numpy as jnp
 
 from repro.core import random_csr, random_spd_csr, spgemm_ref_numpy
 from repro.core.spgemm import _block_execute_jnp
+from repro.launch.compile_cache import init_compile_cache
 from repro.runtime import (BlockChunkSet, ExecCache, ReapRuntime,
                            RuntimeConfig, bucket_block_schedule)
 from repro.runtime.exec_store import EXE_DIR
@@ -422,8 +423,18 @@ def bench_fleet_warm(reduced: bool, verbose: bool = True) -> dict:
     built them, with bit-for-bit identical results.  This is the gate for
     the sharded-runtime PR's "many inspectors, one plan namespace" claim
     (``bench.yml`` fleet step).
+
+    Each worker needs the device, and a device belongs to one process: the
+    parent must not have initialised a JAX backend, and the workers run
+    one after the other.
     """
     import subprocess
+
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError("bench_fleet_warm: this process already holds a "
+                           "JAX backend, so its workers could not reach the "
+                           "device; run it through --fleet-only")
     rows: List[dict] = []
     with tempfile.TemporaryDirectory(prefix="fleet-bench-") as d:
         for _ in range(2):
@@ -554,6 +565,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "--shared-store and print a FLEET result line")
     add_runtime_args(ap)    # --plan-store/--exec-store + shared knobs
     args = ap.parse_args(argv)
+    init_compile_cache()
     if args.fleet_worker:
         return _fleet_worker(args.shared_store, args.reduced)
     if args.fleet_only:

@@ -36,6 +36,7 @@ from pathlib import Path
 import jax
 
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.scheduler import Request, ServeScheduler, synthetic_trace
 from repro.models import model as M
 from repro.models import moe
@@ -97,16 +98,22 @@ def _instrumented_run(sch, trace, rt):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dbrx-132b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="tiny same-family config (default); "
+                         "--no-reduced uses the published widths")
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", default=None, metavar="OUT")
     add_runtime_args(ap)
     args = ap.parse_args(argv)
+    init_compile_cache()
     base_cfg = RuntimeConfig.from_args(args)
 
-    cfg = reduced_config(get_config(args.arch))
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced_config(cfg)
     if cfg.ffn != "moe":
         print(f"bench_serve: {args.arch} has no MoE layers; the warm-"
               "dispatch gate needs one (default: dbrx-132b)", file=sys.stderr)

@@ -106,4 +106,6 @@ def run(verbose: bool = True) -> List[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import init_compile_cache
+    init_compile_cache()
     run()

@@ -8,6 +8,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import init_compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
@@ -15,6 +17,7 @@ def main(argv=None) -> None:
                     help="comma-separated subset: fig6,fig7_11,fig8,fig9,"
                          "fig10,roofline,plan_cache")
     args = ap.parse_args(argv)
+    init_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     def want(name):

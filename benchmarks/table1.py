@@ -81,8 +81,11 @@ def spgemm_scale(spec: MatrixSpec) -> int:
     return k
 
 
-def make_spgemm_matrix(spec: MatrixSpec, seed: int = 0):
-    k = spgemm_scale(spec)
+def make_spgemm_matrix(spec: MatrixSpec, seed: int = 0,
+                       k: Optional[int] = None):
+    """Stand-in for ``spec`` with rows and nnz divided by ``k`` (default:
+    ``spgemm_scale``; ``k=1`` is the published size).  Returns (A, k)."""
+    k = spgemm_scale(spec) if k is None else k
     rows, nnz = max(64, spec.rows // k), max(128, spec.nnz // k)
     rng = np.random.default_rng(seed)
     a = random_csr(rows, rows, nnz / (rows * float(rows)), rng, spec.pattern)
@@ -93,8 +96,11 @@ def chol_scale(spec: MatrixSpec) -> int:
     return max(1, int(np.ceil(spec.rows / CHOL_MAX_ROWS)))
 
 
-def make_chol_matrix(spec: MatrixSpec, seed: int = 0):
-    k = chol_scale(spec)
+def make_chol_matrix(spec: MatrixSpec, seed: int = 0,
+                     k: Optional[int] = None):
+    """SPD stand-in for ``spec`` with rows and nnz divided by ``k``
+    (default: ``chol_scale``; ``k=1`` is the published size)."""
+    k = chol_scale(spec) if k is None else k
     rows = max(64, spec.rows // k)
     nnz = max(128, spec.nnz // k)
     rng = np.random.default_rng(seed)

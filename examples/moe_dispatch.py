@@ -13,7 +13,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import init_compile_cache
 from repro.models.moe import expert_capacity, route_and_bundle, unbundle
+
+init_compile_cache()
 
 T, D, E, K = 512, 128, 8, 2
 key = jax.random.PRNGKey(0)

@@ -5,6 +5,9 @@
 import numpy as np
 
 from repro.core import random_csr, spgemm, spgemm_ref_numpy
+from repro.launch.compile_cache import init_compile_cache
+
+init_compile_cache()
 
 # 1. a sparse matrix in a standard format (CSR), like the paper's inputs
 rng = np.random.default_rng(0)

@@ -21,7 +21,10 @@ import numpy as np
 
 from repro.core import CSR, random_spd_csr
 from repro.core.solver import cg_solve
+from repro.launch.compile_cache import init_compile_cache
 from repro.runtime import ReapRuntime, RuntimeConfig, add_runtime_args
+
+init_compile_cache()
 
 ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
 add_runtime_args(ap)

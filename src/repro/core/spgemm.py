@@ -138,6 +138,7 @@ def spgemm_gather_execute_chunk(plan: SpGemmGatherPlan, a_data: np.ndarray,
 @persistent_jit(static_argnames=("n_out",))
 def _block_execute_jnp(a_blocks, b_blocks, a_id, b_id, out_id, n_out: int):
     prods = jnp.einsum("tij,tjk->tik", a_blocks[a_id], b_blocks[b_id],
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
     return jax.ops.segment_sum(prods, out_id, num_segments=n_out,
                                indices_are_sorted=True)

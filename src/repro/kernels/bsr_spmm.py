@@ -39,6 +39,8 @@ from repro.core.inspector import (PatternFingerprint, fingerprint_pattern,
                                   next_pow2)
 from repro.core.rir import ScheduleBundle
 
+from . import I0, dot_precision, resolve_interpret
+
 
 def _sorted_job_schedule(kk: np.ndarray, jj: np.ndarray, carry: np.ndarray,
                          carry_fill, n_k_blocks: int, n_j_blocks: int):
@@ -111,13 +113,15 @@ def _kernel(w_id, k_blk, j_blk, is_first, is_last, x_ref, w_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += jnp.dot(x_ref[...], w_ref[0],
+                          precision=dot_precision(x_ref.dtype),
                           preferred_element_type=jnp.float32
                           ).astype(o_ref.dtype)
 
 
 @persistent_jit(static_argnames=("n_j_blocks", "bt", "interpret"))
 def bsr_spmm(x, w_blocks, w_id, k_blk, j_blk, is_first, is_last, *,
-             n_j_blocks: int, bt: int = 128, interpret: bool = True):
+             n_j_blocks: int, bt: int = 128,
+             interpret: Optional[bool] = None):
     """out = x @ W_bsr.  x: (T, d_in); w_blocks: (n_jobs, bs, bs).
 
     Schedule arrays (n_jobs,) are sorted by output block column with
@@ -135,7 +139,7 @@ def bsr_spmm(x, w_blocks, w_id, k_blk, j_blk, is_first, is_last, *,
             pl.BlockSpec((bt, bs),
                          lambda ti, t, wid, kb, jb, fi, la: (ti, kb[t])),
             pl.BlockSpec((1, bs, bs),
-                         lambda ti, t, wid, kb, jb, fi, la: (wid[t], 0, 0)),
+                         lambda ti, t, wid, kb, jb, fi, la: (wid[t], I0, I0)),
         ],
         out_specs=pl.BlockSpec((bt, bs),
                                lambda ti, t, wid, kb, jb, fi, la:
@@ -145,7 +149,7 @@ def bsr_spmm(x, w_blocks, w_id, k_blk, j_blk, is_first, is_last, *,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_total, n_j_blocks * bs), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         cost_estimate=pl.CostEstimate(
             flops=2 * (t_total // bt) * n_jobs * bt * bs * bs,
             bytes_accessed=(t_total * d_in + n_jobs * bs * bs) * 2,
@@ -234,6 +238,7 @@ def _spmm_math(x_tiles, w_tiles, w_id, k_blk, j_blk, n_j: int):
     sharded (shard_map) executor in ``runtime/shard.py`` — one definition
     keeps the two paths bit-for-bit interchangeable."""
     prods = jnp.einsum("tij,tjk->tik", x_tiles[k_blk], w_tiles[w_id],
+                       precision=dot_precision(x_tiles.dtype),
                        preferred_element_type=x_tiles.dtype)
     return jax.ops.segment_sum(prods, j_blk, num_segments=n_j,
                                indices_are_sorted=True)
@@ -279,8 +284,7 @@ def spmm_execute(plan: SpmmPlan, x: np.ndarray, w_data: np.ndarray,
                        # reaplint: disable=REAP004 plan-static shape: the
                        # output block count is fixed per cached plan (bt,
                        # the streamed axis, IS pow-2-bucketed)
-                       n_j_blocks=plan.n_j_blocks, bt=bt,
-                       interpret=jax.default_backend() != "tpu")
+                       n_j_blocks=plan.n_j_blocks, bt=bt)
     else:
         x_tiles = xp.reshape(t_pad, plan.n_k_blocks, bs).swapaxes(0, 1)
         out_j = _spmm_execute_jnp(jnp.asarray(x_tiles),

@@ -43,6 +43,8 @@ from repro.core.formats import CSR, bsr_pattern_from_csr
 from repro.core.inspector import (PatternFingerprint, fingerprint_pattern,
                                   next_pow2)
 
+from . import I0, resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -115,7 +117,8 @@ def _kernel(kv_lo, n_kv, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
                               "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale: float | None = None,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128,
+                    interpret: Optional[bool] = None):
     """q: (B, H, S, D); k, v: (B, Hkv, S, D) with H % Hkv == 0 (GQA).
 
     The GQA mapping is zero-copy: the KV BlockSpec index map folds the
@@ -138,7 +141,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         grid=(b, h, s // bq, nk_max),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
-                         lambda bi, hi, qi, j, lo, nk: (bi, hi, qi, 0)),
+                         lambda bi, hi, qi, j, lo, nk: (bi, hi, qi, I0)),
             pl.BlockSpec(
                 (1, 1, bk, d),
                 lambda bi, hi, qi, j, lo, nk:
@@ -151,7 +154,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                  0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bi, hi, qi, j, lo, nk: (bi, hi, qi, 0)),
+                               lambda bi, hi, qi, j, lo, nk: (bi, hi, qi, I0)),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
@@ -165,7 +168,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * visible * bq * bk * d,
             bytes_accessed=q.size * q.dtype.itemsize * 4,
@@ -273,7 +276,7 @@ def _block_attn_kernel(kv_ids, n_kv, q_ref, k_ref, v_ref, o_ref, acc, m_s,
     jax.jit, static_argnames=("softcap", "scale", "seq", "interpret"))
 def block_sparse_attention(q, k, v, kv_ids, n_kv, *, softcap: float = 0.0,
                            scale: float | None = None, seq: int | None = None,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """q: (B, H, S_pad, D); kv_ids: (S_pad//bs, nk_cap) visible kv blocks.
 
     Gathered flash attention: the grid's kv axis walks each q block's
@@ -296,16 +299,17 @@ def block_sparse_attention(q, k, v, kv_ids, n_kv, *, softcap: float = 0.0,
         grid=(b, h, nq, nk_cap),
         in_specs=[
             pl.BlockSpec((1, 1, bs, d),
-                         lambda bi, hi, qi, j, ids, nk: (bi, hi, qi, 0)),
+                         lambda bi, hi, qi, j, ids, nk: (bi, hi, qi, I0)),
             pl.BlockSpec((1, 1, bs, d),
                          lambda bi, hi, qi, j, ids, nk:
-                         (bi, hi // group, ids[qi, j], 0)),
+                         (bi, hi // group, ids[qi, j], I0)),
             pl.BlockSpec((1, 1, bs, d),
                          lambda bi, hi, qi, j, ids, nk:
-                         (bi, hi // group, ids[qi, j], 0)),
+                         (bi, hi // group, ids[qi, j], I0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bs, d),
-                               lambda bi, hi, qi, j, ids, nk: (bi, hi, qi, 0)),
+                               lambda bi, hi, qi, j, ids, nk:
+                               (bi, hi, qi, I0)),
         scratch_shapes=[
             pltpu.VMEM((bs, d), jnp.float32),
             pltpu.VMEM((bs, 128), jnp.float32),
@@ -318,7 +322,7 @@ def block_sparse_attention(q, k, v, kv_ids, n_kv, *, softcap: float = 0.0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * nq * nk_cap * bs * bs * d,
             bytes_accessed=q.size * q.dtype.itemsize * 4,
@@ -391,8 +395,7 @@ def block_attention_execute(plan: BlockAttentionPlan, q, k, v,
         out = block_sparse_attention(
             jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
             jnp.asarray(plan.kv_ids), jnp.asarray(plan.n_kv),
-            softcap=softcap, scale=d_scale, seq=plan.seq,
-            interpret=jax.default_backend() != "tpu")
+            softcap=softcap, scale=d_scale, seq=plan.seq)
     else:
         out = _block_attention_jnp(
             jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),

@@ -15,11 +15,14 @@ stays VMEM-resident across the contraction.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import I0, resolve_interpret
 
 
 def _kernel(expert_of_bundle, x_ref, w_ref, o_ref, acc_ref):
@@ -41,7 +44,7 @@ def _kernel(expert_of_bundle, x_ref, w_ref, o_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("bk", "bf", "interpret"))
 def moe_gemm(x_bundles, w, bundle_expert, *, bk: int = 512, bf: int = 512,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """out[b] = x_bundles[b] @ w[bundle_expert[b]].
 
     x_bundles: (nb, cap, d_in); w: (E, d_in, d_out);
@@ -56,17 +59,17 @@ def moe_gemm(x_bundles, w, bundle_expert, *, bk: int = 512, bf: int = 512,
         num_scalar_prefetch=1,
         grid=(nb, d_out // bf, d_in // bk),
         in_specs=[
-            pl.BlockSpec((1, cap, bk), lambda b, f, k, e: (b, 0, k)),
+            pl.BlockSpec((1, cap, bk), lambda b, f, k, e: (b, I0, k)),
             pl.BlockSpec((1, bk, bf), lambda b, f, k, e: (e[b], k, f)),
         ],
-        out_specs=pl.BlockSpec((1, cap, bf), lambda b, f, k, e: (b, 0, f)),
+        out_specs=pl.BlockSpec((1, cap, bf), lambda b, f, k, e: (b, I0, f)),
         scratch_shapes=[pltpu.VMEM((cap, bf), jnp.float32)],
     )
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, cap, d_out), x_bundles.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         cost_estimate=pl.CostEstimate(
             flops=2 * int(nb) * cap * d_in * d_out,
             bytes_accessed=int(nb) * cap * (d_in + d_out) * 2
@@ -76,7 +79,7 @@ def moe_gemm(x_bundles, w, bundle_expert, *, bk: int = 512, bf: int = 512,
 
 
 def moe_gemm_schedule(schedule, x_bundles, w, *, bk: int = 512, bf: int = 512,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """Runtime entry point: drive the kernel from a ``MoeDispatchPlan``'s RIR
     ScheduleBundle (mirrors ``bsr_spgemm_schedule``).
 

@@ -1,79 +1,29 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-On TPU the kernels compile natively; on this CPU container they run in
-``interpret=True`` (the kernel body executed in Python) so every code path
-is validated against the ref.py oracles.  ``interpret=None`` auto-detects.
+Each kernel runs natively on a TPU and in the Pallas interpreter elsewhere
+(``interpret=None`` resolves through ``kernels.resolve_interpret``), so the
+CPU test suite checks every code path against the ``ref.py`` oracles.
 """
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
+import jax.numpy as jnp
 
 from . import ref  # noqa: F401  (re-exported for tests/benchmarks)
-from .bsr_spgemm import bsr_spgemm as _bsr_spgemm
-from .bsr_spgemm import bsr_spgemm_schedule as _bsr_spgemm_schedule
+from .bsr_spgemm import bsr_spgemm, bsr_spgemm_schedule  # noqa: F401
 from .flash_attention import attention_block_schedule  # noqa: F401
-from .flash_attention import flash_attention as _flash_attention
-from .moe_gemm import moe_gemm as _moe_gemm
-from .moe_gemm import moe_gemm_schedule as _moe_gemm_schedule
-from .rwkv6_scan import rwkv6 as _rwkv6
-
-
-def _interpret(flag):
-    if flag is None:
-        return jax.default_backend() != "tpu"
-    return bool(flag)
-
-
-def bsr_spgemm(a_blocks, b_blocks, a_id, b_id, out_id, is_first, is_last, *,
-               n_out_blocks: int, interpret=None):
-    return _bsr_spgemm(a_blocks, b_blocks, a_id, b_id, out_id, is_first,
-                       is_last, n_out_blocks=n_out_blocks,
-                       interpret=_interpret(interpret))
-
-
-def bsr_spgemm_schedule(schedule, a_blocks, b_blocks, *, n_out_blocks: int,
-                        interpret=None):
-    """Schedule-bundle form used by runtime.api (cached-plan replay)."""
-    return _bsr_spgemm_schedule(schedule, a_blocks, b_blocks,
-                                n_out_blocks=n_out_blocks,
-                                interpret=_interpret(interpret))
-
-
-def moe_gemm(x_bundles, w, bundle_expert, *, bk: int = 512, bf: int = 512,
-             interpret=None):
-    return _moe_gemm(x_bundles, w, bundle_expert, bk=bk, bf=bf,
-                     interpret=_interpret(interpret))
-
-
-def moe_gemm_schedule(schedule, x_bundles, w, *, bk: int = 512, bf: int = 512,
-                      interpret=None):
-    """Schedule-bundle form used by runtime callers (cached-plan replay)."""
-    return _moe_gemm_schedule(schedule, x_bundles, w, bk=bk, bf=bf,
-                              interpret=_interpret(interpret))
-
-
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0, scale=None, bq: int = 128,
-                    bk: int = 128, interpret=None):
-    return _flash_attention(q, k, v, causal=causal, window=window,
-                            softcap=softcap, scale=scale, bq=bq, bk=bk,
-                            interpret=_interpret(interpret))
-
-
-def rwkv6(r, k, v, w, u, *, chunk: int = 32, interpret=None):
-    return _rwkv6(r, k, v, w, u, chunk=chunk,
-                  interpret=_interpret(interpret))
+from .flash_attention import flash_attention  # noqa: F401
+from .moe_gemm import moe_gemm, moe_gemm_schedule  # noqa: F401
+from .rwkv6_scan import rwkv6  # noqa: F401
 
 
 def bsr_spmm(x, w_blocks, sched, *, n_j_blocks: int, bt: int = 128,
-             interpret=None):
+             interpret: Optional[bool] = None):
     """Structured-sparse weight matmul (schedule from inspect_bsr_weight)."""
-    import jax.numpy as jnp
-
     from .bsr_spmm import bsr_spmm as _bsr_spmm
     return _bsr_spmm(x, w_blocks, jnp.asarray(sched["w_id"]),
                      jnp.asarray(sched["k_blk"]), jnp.asarray(sched["j_blk"]),
                      jnp.asarray(sched["is_first"]),
                      jnp.asarray(sched["is_last"]),
-                     n_j_blocks=n_j_blocks, bt=bt,
-                     interpret=_interpret(interpret))
+                     n_j_blocks=n_j_blocks, bt=bt, interpret=interpret)

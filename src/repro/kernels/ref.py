@@ -1,7 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 Each function is the semantic ground truth the kernels are tested against
-(interpret=True on CPU, shape/dtype sweeps in tests/test_kernels_*.py).
+(interpret mode on CPU, shape/dtype sweeps in tests/test_kernels.py).
 """
 from __future__ import annotations
 

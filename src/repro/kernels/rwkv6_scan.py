@@ -15,11 +15,14 @@ across chunk steps and is reset at c == 0.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import I0, resolve_interpret
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state, *, chunk):
@@ -64,7 +67,8 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state, *, chunk):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6(r, k, v, w, u, *, chunk: int = 32, interpret: bool = True):
+def rwkv6(r, k, v, w, u, *, chunk: int = 32,
+          interpret: Optional[bool] = None):
     """Chunked WKV. r,k,w: (B,H,T,K); v: (B,H,T,V); u: (H,K). T % chunk == 0.
 
     Returns o: (B,H,T,V) float32.
@@ -77,21 +81,21 @@ def rwkv6(r, k, v, w, u, *, chunk: int = 32, interpret: bool = True):
         num_scalar_prefetch=0,
         grid=(b, h, t // chunk),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, kk), lambda bi, hi, c: (bi, hi, c, 0)),
-            pl.BlockSpec((1, 1, chunk, kk), lambda bi, hi, c: (bi, hi, c, 0)),
-            pl.BlockSpec((1, 1, chunk, vv), lambda bi, hi, c: (bi, hi, c, 0)),
-            pl.BlockSpec((1, 1, chunk, kk), lambda bi, hi, c: (bi, hi, c, 0)),
-            pl.BlockSpec((1, kk), lambda bi, hi, c: (hi, 0)),
+            pl.BlockSpec((1, 1, chunk, kk), lambda bi, hi, c: (bi, hi, c, I0)),
+            pl.BlockSpec((1, 1, chunk, kk), lambda bi, hi, c: (bi, hi, c, I0)),
+            pl.BlockSpec((1, 1, chunk, vv), lambda bi, hi, c: (bi, hi, c, I0)),
+            pl.BlockSpec((1, 1, chunk, kk), lambda bi, hi, c: (bi, hi, c, I0)),
+            pl.BlockSpec((1, kk), lambda bi, hi, c: (hi, I0)),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, vv),
-                               lambda bi, hi, c: (bi, hi, c, 0)),
+                               lambda bi, hi, c: (bi, hi, c, I0)),
         scratch_shapes=[pltpu.VMEM((kk, vv), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, t, vv), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * h * t * kk * vv + 2 * b * h * t * chunk * (kk + vv),
             bytes_accessed=(3 * b * h * t * kk + 2 * b * h * t * vv) * 4,

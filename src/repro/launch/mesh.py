@@ -8,24 +8,17 @@ from __future__ import annotations
 import jax
 
 
-def _auto_kw(n):
-    # jax.sharding.AxisType landed after 0.4.37; older jax only has Auto
-    # semantics, so omitting the kwarg is equivalent there
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return {} if axis_type is None else dict(
-        axis_types=(axis_type.Auto,) * n)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) = 256 chips (data, model).
     Multi-pod: (2, 16, 16) = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Elastic variant: any shape over the available devices (used by the
     fault-tolerance runtime to rebuild a smaller mesh after node loss)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_auto_kw(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config, reduced_config
+from repro.launch.compile_cache import init_compile_cache
 from repro.models import model as M
 
 
@@ -209,7 +210,11 @@ def serve_continuous(cfg, args, rt):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="gemma2-2b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the tiny same-family CPU smoke config "
+                         "(default); --no-reduced serves the published "
+                         "widths and depth")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
@@ -263,6 +268,7 @@ def main(argv=None):
     from repro.runtime import add_runtime_args
     add_runtime_args(ap)
     args = ap.parse_args(argv)
+    init_compile_cache()
     if args.host_moe and args.routing == "auto":
         args.routing = "host"            # legacy alias keeps its meaning
 

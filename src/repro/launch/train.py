@@ -20,6 +20,7 @@ import jax
 from repro.checkpoint import manager as ckpt
 from repro.configs import ARCHS, get_config, reduced_config
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import model as M
 from repro.optim import adamw
@@ -52,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

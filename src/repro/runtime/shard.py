@@ -36,8 +36,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.formats import CSR
@@ -126,9 +126,9 @@ def _gather_shard_fn(mesh):
             def body(av, bv, ai, bi, oi):
                 return _gather_math(av[0], bv, ai[0], bi[0], oi[0],
                                     c_cap)[None]
-            return shard_map(body, mesh=mesh,
-                             in_specs=(sh, P(), sh, sh, sh),
-                             out_specs=sh, check_rep=False)(
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(sh, P(), sh, sh, sh),
+                                 out_specs=sh, check_vma=False)(
                 a_vals, b_vals, a_idx, b_idx, out_idx)
 
         return persistent_jit(impl, static_argnames=("c_cap",),
@@ -146,9 +146,9 @@ def _spmm_shard_fn(mesh):
         def impl(x_tiles, w_tiles, w_id, k_blk, j_blk, *, n_j: int):
             def body(xt, wt, wi, kb, jb):
                 return _spmm_math(xt[0], wt, wi, kb, jb, n_j)[None]
-            return shard_map(body, mesh=mesh,
-                             in_specs=(sh, P(), P(), P(), P()),
-                             out_specs=sh, check_rep=False)(
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(sh, P(), P(), P(), P()),
+                                 out_specs=sh, check_vma=False)(
                 x_tiles, w_tiles, w_id, k_blk, j_blk)
 
         return persistent_jit(impl, static_argnames=("n_j",),
@@ -166,8 +166,8 @@ def _moe_shard_fn(mesh):
         def impl(slot_token, padded):
             def body(st, pad):
                 return pad[st[0]][None]
-            return shard_map(body, mesh=mesh, in_specs=(sh, P()),
-                             out_specs=sh, check_rep=False)(
+            return jax.shard_map(body, mesh=mesh, in_specs=(sh, P()),
+                                 out_specs=sh, check_vma=False)(
                 slot_token, padded)
 
         return persistent_jit(impl, key_extra=key)

@@ -6,18 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# Every test in this module fails on the container's pinned jax 0.4.37
-# (8-fake-device lower/compile subprocess harness; identical failures on
-# the seed tree, tracked in ROADMAP).  Version-guarded quarantine so
-# tier-1 green/red is signal again: remove when jax is upgraded.
-pytestmark = pytest.mark.skipif(
-    jax.__version__ == "0.4.37",
-    reason="pre-existing failures on the container's jax 0.4.37 "
-           "(same on seed); see ROADMAP known-noise note")
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

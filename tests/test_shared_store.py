@@ -277,3 +277,15 @@ print("DIGEST", hashlib.sha256(
         warm = self._collect(self._spawn(script, root))
         assert int(warm["MISSES"]) == 0 and int(warm["COMPILES"]) == 0
         assert warm["DIGEST"] == first["DIGEST"]
+
+
+def test_fleet_bench_refuses_a_parent_holding_the_device():
+    """The fleet bench's workers each need the device, which belongs to
+    one process: a parent that has initialised a backend is refused before
+    any worker starts."""
+    import jax
+
+    from benchmarks.bench_plan_store import bench_fleet_warm
+    jax.devices()
+    with pytest.raises(RuntimeError, match="already holds a JAX backend"):
+        bench_fleet_warm(reduced=True, verbose=False)
