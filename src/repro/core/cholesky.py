@@ -31,6 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import spans
+
 from .etree import CholeskyPlan, cholesky_values, inspect_cholesky
 from .formats import CSR
 from .inspector import next_pow2
@@ -97,18 +99,18 @@ def cholesky_execute(plan: CholeskyPlan, a_vals: np.ndarray,
     Returns (L values in CSC order, stats).  ``a_vals`` comes from
     ``cholesky_values(a)`` — the plan itself is value-free.
     """
-    vals = init_values(plan, a_vals, dtype)
-    t0 = time.perf_counter()
-    for ell in range(plan.n_levels):
-        bundle = emit_level_bundle(plan, ell)
-        vals = _level_step(vals, *bundle)
-    # reaplint: disable=REAP003 deliberate timed drain: execute_s must
-    # measure device completion so sync/overlapped stats stay comparable
-    vals.block_until_ready()
-    exec_s = time.perf_counter() - t0
-    stats = dict(execute_s=exec_s, n_levels=plan.n_levels,
+    with spans.span("reap.values"):
+        vals = init_values(plan, a_vals, dtype)
+    with spans.span("reap.execute") as ex:
+        for ell in range(plan.n_levels):
+            bundle = emit_level_bundle(plan, ell)
+            vals = _level_step(vals, *bundle)
+        # reaplint: disable=REAP003 deliberate timed drain: execute_s must
+        # measure device completion so sync/overlapped stats stay comparable
+        vals.block_until_ready()
+    stats = dict(execute_s=ex.seconds, n_levels=plan.n_levels,
                  nnz_l=plan.nnz, flops=plan.flops())
-    return np.asarray(vals[:plan.nnz]), stats
+    return spans.to_host(vals[:plan.nnz]), stats
 
 
 def cholesky(a: CSR, dtype=jnp.float64, plan: CholeskyPlan = None):
@@ -121,12 +123,13 @@ def cholesky(a: CSR, dtype=jnp.float64, plan: CholeskyPlan = None):
     """
     inspect_s = 0.0
     if plan is None:
-        t0 = time.perf_counter()
-        plan = inspect_cholesky(a)
-        inspect_s = time.perf_counter() - t0
+        with spans.span("reap.inspect") as ins:
+            plan = inspect_cholesky(a)
+        inspect_s = ins.seconds
         a_vals = cholesky_values(a)
     else:
-        a_vals = plan.a_values(a)
+        with spans.span("reap.values"):
+            a_vals = plan.a_values(a)
     vals, stats = cholesky_execute(plan, a_vals, dtype)
     stats["inspect_s"] = inspect_s
     return plan, vals, stats
@@ -192,8 +195,10 @@ def _exec_cholesky(plan, operands, cfg, *, overlap, dtype=jnp.float64, **kw):
     (a,) = operands
     if overlap:
         from repro.runtime.pipeline import cholesky_execute_overlapped
-        vals, stats = cholesky_execute_overlapped(plan, plan.a_values(a),
-                                                  dtype, overlap=True)
+        with spans.span("reap.values"):
+            a_vals = plan.a_values(a)
+        vals, stats = cholesky_execute_overlapped(plan, a_vals, dtype,
+                                                  overlap=True)
     else:
         _, vals, stats = cholesky(a, dtype, plan=plan)
         stats["overlap"] = False

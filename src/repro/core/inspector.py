@@ -470,13 +470,12 @@ def _inspect_moe_dispatch(operands, cfg, fp, *, routing, capacity, **kw):
 
 def _exec_moe_dispatch(plan: MoeDispatchPlan, operands, cfg, *, overlap,
                        **kw):
-    import time
+    from repro.runtime import spans
     tokens = np.asarray(operands[0])
-    t0 = time.perf_counter()
-    x_bundles = plan.bundle(tokens)
-    bundle_s = time.perf_counter() - t0
-    stats = dict(method="moe_dispatch", bundle_s=bundle_s,
-                 capacity=plan.capacity, dropped=plan.dropped_frac)
+    with spans.span("reap.bundle"):
+        x_bundles = plan.bundle(tokens)
+    stats = dict(method="moe_dispatch", capacity=plan.capacity,
+                 dropped=plan.dropped_frac)
     return (x_bundles, plan), stats
 
 

@@ -28,12 +28,13 @@ any other.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional, Tuple
 
 import numpy as np
 
 import jax.numpy as jnp
+
+from repro.runtime import spans
 
 from .formats import CSR
 from .inspector import PatternFingerprint, fingerprint_pattern
@@ -84,8 +85,9 @@ def inspect_spmv(a: CSR, block: int = 128,
 def spmv_execute(plan: SpmvPlan, a_data: np.ndarray, x: np.ndarray,
                  use_pallas: bool = True, dtype=np.float32) -> np.ndarray:
     """y = A @ x from a plan + this call's values.  Returns (n_rows,)."""
-    y = spmm_execute(plan.inner, np.asarray(x, dtype)[None, :],
-                     np.asarray(a_data)[plan.perm],
+    with spans.span("reap.values"):
+        values = np.asarray(a_data)[plan.perm]
+    y = spmm_execute(plan.inner, np.asarray(x, dtype)[None, :], values,
                      use_pallas=use_pallas, dtype=dtype)
     return y[0]
 
@@ -161,6 +163,15 @@ def cg_solve(a: CSR, b: np.ndarray, runtime=None, *, tol: float = 1e-8,
     if a.n_cols != n:
         raise ValueError("cg_solve needs a square (SPD) matrix")
     dtype = np.dtype(dtype)
+    with spans.record("reap.solve", op="cg"):
+        return _cg(a, b, runtime, tol, maxiter, precond, precond_block,
+                   dtype)
+
+
+def _cg(a, b, runtime, tol, maxiter, precond, precond_block, dtype):
+    """The iteration of :func:`cg_solve`: each matvec is one nested
+    ``reap.run``; the host vector work around it is ``reap.cg_host``."""
+    n = a.n_rows
     b = np.asarray(b, np.float64)
     x = np.zeros(n, np.float64)
     r = b.copy()
@@ -188,24 +199,26 @@ def cg_solve(a: CSR, b: np.ndarray, runtime=None, *, tol: float = 1e-8,
     converged = relres < tol
     while not converged and it < maxiter:
         q, st = runtime.run("spmv", a, p, dtype=dtype)
-        q = np.asarray(q, np.float64)
-        hits += int(st["cache_hit"])
-        pq = float(p @ q)
-        if pq <= 0.0:
-            break                            # not SPD (or total breakdown)
-        alpha = rz / pq
-        x += alpha * p
-        r -= alpha * q
-        it += 1
-        relres = float(np.linalg.norm(r)) / bnorm
-        if relres < tol:
-            converged = True
-            break
-        z = apply_m(r) if apply_m else r
-        rz_new = float(r @ z)
-        beta = rz_new / rz
-        rz = rz_new
-        p = z + beta * p
+        with spans.span("reap.cg_host"):
+            q = np.asarray(q, np.float64)
+            hits += int(st["cache_hit"])
+            pq = float(p @ q)
+            if pq <= 0.0:
+                break                        # not SPD (or total breakdown)
+            alpha = rz / pq
+            x += alpha * p
+            r -= alpha * q
+            it += 1
+            spans.count("iterations")
+            relres = float(np.linalg.norm(r)) / bnorm
+            if relres < tol:
+                converged = True
+                break
+            z = apply_m(r) if apply_m else r
+            rz_new = float(r @ z)
+            beta = rz_new / rz
+            rz = rz_new
+            p = z + beta * p
     info = dict(converged=converged, iterations=it, relres=relres,
                 spmv_cache_hits=hits, preconditioned=apply_m is not None)
     return x, info
@@ -230,10 +243,10 @@ def _inspect_spmv(operands, cfg, fp, **kw):
 
 def _exec_spmv(plan, operands, cfg, *, overlap, dtype=np.float32, **kw):
     a, x = operands
-    t0 = time.perf_counter()
-    y = spmv_execute(plan, a.data, x, use_pallas=cfg.use_pallas, dtype=dtype)
-    exec_s = time.perf_counter() - t0
-    stats = dict(method="spmv", execute_s=exec_s, overlap=False,
+    with spans.span("reap.execute") as ex:
+        y = spmv_execute(plan, a.data, x, use_pallas=cfg.use_pallas,
+                         dtype=dtype)
+    stats = dict(method="spmv", execute_s=ex.seconds, overlap=False,
                  n_jobs=plan.inner.n_jobs, flops=2 * a.nnz)
     return y, stats
 

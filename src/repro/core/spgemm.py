@@ -18,7 +18,6 @@ The numpy reference ``spgemm_ref_numpy`` doubles as the CPU-library baseline
 """
 from __future__ import annotations
 
-import time
 from typing import Tuple
 
 import numpy as np
@@ -26,6 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import spans
 from repro.runtime.exec_store import persistent_jit
 
 from .formats import BsrPattern, CSR
@@ -229,31 +229,31 @@ def spgemm(a: CSR, b: CSR, method: str = "auto", block: int = 128,
     if plan is None:
         if method == "auto":
             method = choose_spgemm_path(a, b, block)
-        t0 = time.perf_counter()
-        if method == "gather":
-            plan = inspect_spgemm_gather(a, b, tile)
-        elif method == "block":
-            plan = inspect_spgemm_block(a, b, block)
-        else:
+        if method not in ("gather", "block"):
             raise ValueError(f"unknown method {method!r}")
-        inspect_s = time.perf_counter() - t0
+        with spans.span("reap.inspect") as ins:
+            if method == "gather":
+                plan = inspect_spgemm_gather(a, b, tile)
+            else:
+                plan = inspect_spgemm_block(a, b, block)
+        inspect_s = ins.seconds
 
     if isinstance(plan, SpGemmGatherPlan):
-        t0 = time.perf_counter()
-        c_data = spgemm_gather_execute(plan, a.data, b.data)
-        exec_s = time.perf_counter() - t0
+        with spans.span("reap.execute") as ex:
+            c_data = spgemm_gather_execute(plan, a.data, b.data)
         c = CSR(a.n_rows, b.n_cols, plan.c_indptr, plan.c_indices, c_data)
         stats = dict(method="gather", inspect_s=inspect_s,
-                     execute_s=exec_s, flops=plan.flops(), n_pp=plan.n_pp)
+                     execute_s=ex.seconds, flops=plan.flops(),
+                     n_pp=plan.n_pp)
         return c, stats
     if isinstance(plan, SpGemmBlockPlan):
-        t0 = time.perf_counter()
-        c_blocks = spgemm_block_execute(plan, a.data, b.data,
-                                        use_pallas=use_pallas)
-        exec_s = time.perf_counter() - t0
-        c = block_result_to_csr(plan, c_blocks, a.n_rows, b.n_cols)
+        with spans.span("reap.execute") as ex:
+            c_blocks = spgemm_block_execute(plan, a.data, b.data,
+                                            use_pallas=use_pallas)
+        with spans.span("reap.extract"):
+            c = block_result_to_csr(plan, c_blocks, a.n_rows, b.n_cols)
         stats = dict(method="block", inspect_s=inspect_s,
-                     execute_s=exec_s, flops=plan.flops(),
+                     execute_s=ex.seconds, flops=plan.flops(),
                      n_pairs=plan.n_pairs, fill=plan.a_pat.fill)
         return c, stats
     raise TypeError(f"unsupported plan type {type(plan).__name__}")

@@ -23,7 +23,6 @@ Two entry points:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import numpy as np
@@ -34,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BsrPattern, CSR, bsr_pattern_from_csr
+from repro.runtime import spans
 from repro.runtime.exec_store import persistent_jit
 from repro.core.inspector import (PatternFingerprint, fingerprint_pattern,
                                   next_pow2)
@@ -271,33 +271,38 @@ def spmm_execute(plan: SpmmPlan, x: np.ndarray, w_data: np.ndarray,
     bs = plan.block
     t_pad = next_pow2(max(1, t))
     bt = min(128, t_pad)
-    xp = np.zeros((t_pad, plan.pat.n_rows), dtype)
-    xp[:t, :d_in] = x
-    w_tiles = plan.scatter(w_data, dtype=dtype)
-    if use_pallas and dtype == np.float32:
-        out = bsr_spmm(jnp.asarray(xp), jnp.asarray(w_tiles),
-                       jnp.asarray(plan.w_id, jnp.int32),
-                       jnp.asarray(plan.k_blk, jnp.int32),
-                       jnp.asarray(plan.j_blk, jnp.int32),
-                       jnp.asarray(plan.is_first, jnp.int32),
-                       jnp.asarray(plan.is_last, jnp.int32),
-                       # reaplint: disable=REAP004 plan-static shape: the
-                       # output block count is fixed per cached plan (bt,
-                       # the streamed axis, IS pow-2-bucketed)
-                       n_j_blocks=plan.n_j_blocks, bt=bt)
-    else:
-        x_tiles = xp.reshape(t_pad, plan.n_k_blocks, bs).swapaxes(0, 1)
-        out_j = _spmm_execute_jnp(jnp.asarray(x_tiles),
-                                  jnp.asarray(w_tiles),
-                                  jnp.asarray(plan.w_id),
-                                  jnp.asarray(plan.k_blk),
-                                  jnp.asarray(plan.j_blk),
-                                  # reaplint: disable=REAP004 plan-static
-                                  # shape: fixed per cached plan (jnp
-                                  # fallback path)
-                                  n_j=plan.n_j_blocks)
-        out = jnp.swapaxes(out_j, 0, 1).reshape(t_pad, plan.n_j_blocks * bs)
-    return np.asarray(out)[:t, :plan.n_cols]
+    pallas = use_pallas and dtype == np.float32
+    with spans.span("reap.values"):
+        xp = np.zeros((t_pad, plan.pat.n_rows), dtype)
+        xp[:t, :d_in] = x
+        w_tiles = plan.scatter(w_data, dtype=dtype)
+    with spans.span("reap.h2d"):
+        if pallas:
+            args = (jnp.asarray(xp), jnp.asarray(w_tiles),
+                    jnp.asarray(plan.w_id, jnp.int32),
+                    jnp.asarray(plan.k_blk, jnp.int32),
+                    jnp.asarray(plan.j_blk, jnp.int32),
+                    jnp.asarray(plan.is_first, jnp.int32),
+                    jnp.asarray(plan.is_last, jnp.int32))
+        else:
+            x_tiles = xp.reshape(t_pad, plan.n_k_blocks, bs).swapaxes(0, 1)
+            args = (jnp.asarray(x_tiles), jnp.asarray(w_tiles),
+                    jnp.asarray(plan.w_id), jnp.asarray(plan.k_blk),
+                    jnp.asarray(plan.j_blk))
+        spans.count("h2d_bytes", sum(arg.nbytes for arg in args))
+    with spans.span("reap.launch"):
+        if pallas:
+            # reaplint: disable=REAP004 plan-static shape: the output block
+            # count is fixed per cached plan (bt, the streamed axis, IS
+            # pow-2-bucketed)
+            out = bsr_spmm(*args, n_j_blocks=plan.n_j_blocks, bt=bt)
+        else:
+            # reaplint: disable=REAP004 plan-static shape: fixed per cached
+            # plan (jnp fallback path)
+            out_j = _spmm_execute_jnp(*args, n_j=plan.n_j_blocks)
+            out = jnp.swapaxes(out_j, 0, 1).reshape(
+                t_pad, plan.n_j_blocks * bs)
+    return spans.to_host(out)[:t, :plan.n_cols]
 
 
 def spmm_ref_numpy(x: np.ndarray, w: CSR) -> np.ndarray:
@@ -324,10 +329,10 @@ def _inspect_spmm(operands, cfg, fp, **kw):
 
 def _exec_spmm(plan, operands, cfg, *, overlap, dtype=np.float32, **kw):
     x, w = operands
-    t0 = time.perf_counter()
-    y = spmm_execute(plan, x, w.data, use_pallas=cfg.use_pallas, dtype=dtype)
-    exec_s = time.perf_counter() - t0
-    stats = dict(method="spmm", execute_s=exec_s, overlap=False,
+    with spans.span("reap.execute") as ex:
+        y = spmm_execute(plan, x, w.data, use_pallas=cfg.use_pallas,
+                         dtype=dtype)
+    stats = dict(method="spmm", execute_s=ex.seconds, overlap=False,
                  n_jobs=plan.n_jobs, fill=plan.pat.fill,
                  flops=plan.flops(np.asarray(x).shape[0]))
     return y, stats

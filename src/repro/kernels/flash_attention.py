@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Optional
 
 import numpy as np
@@ -42,6 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.formats import CSR, bsr_pattern_from_csr
 from repro.core.inspector import (PatternFingerprint, fingerprint_pattern,
                                   next_pow2)
+from repro.runtime import spans
 
 from . import I0, resolve_interpret
 
@@ -458,11 +458,12 @@ def _inspect_block_attention(operands, cfg, fp, **kw):
 def _exec_block_attention(plan, operands, cfg, *, overlap, softcap=0.0,
                           scale=None, **kw):
     q, k, v = operands[0], operands[1], operands[2]
-    t0 = time.perf_counter()
-    o = block_attention_execute(plan, q, k, v, use_pallas=cfg.use_pallas,
-                                softcap=softcap, scale=scale)
-    exec_s = time.perf_counter() - t0
-    stats = dict(method="block_attention", execute_s=exec_s, overlap=False,
+    with spans.span("reap.execute") as ex:
+        o = block_attention_execute(plan, q, k, v,
+                                    use_pallas=cfg.use_pallas,
+                                    softcap=softcap, scale=scale)
+    stats = dict(method="block_attention", execute_s=ex.seconds,
+                 overlap=False,
                  n_visible_blocks=plan.n_visible, nk_cap=plan.nk_cap,
                  flops=plan.flops(np.asarray(q).shape[0],
                                   np.asarray(q).shape[1],

@@ -27,7 +27,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import threading
-import time
 import warnings
 from typing import Any, Dict, Iterator, Optional, Tuple
 
@@ -35,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ops as _ops
+from . import spans
 from .plan_cache import PlanCache
 
 
@@ -196,6 +196,11 @@ class RunStats:
     unaffected.  A None field means "not applicable to this run" (e.g.
     ``exec_cache_hit`` without an exec store) and is absent from the
     mapping view.
+
+    ``spans`` and ``counters`` are the call's own run record
+    (``runtime.spans``): seconds per span name (``reap.run`` is the whole
+    call, ``reap.acquire``, ``reap.emit``, ``reap.fetch``, ... its parts)
+    and the counters its stages added (``h2d_bytes``, ``launches``, ...).
     """
 
     cache_hit: Optional[bool] = None
@@ -203,6 +208,8 @@ class RunStats:
     exec_cache_hit: Optional[bool] = None
     fingerprint: Optional[str] = None
     inspect_s: Optional[float] = None
+    spans: Optional[Dict[str, float]] = None
+    counters: Optional[Dict[str, float]] = None
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     _FIELDS = _ops.RUNSTATS_FIELDS
@@ -355,87 +362,91 @@ class ReapRuntime:
 
         Returns ``(result, stats)``; ``result`` is op-defined (the
         back-compat wrappers unpack it).  ``stats`` is a ``RunStats``
-        (dict-compatible): always ``cache_hit`` and ``fingerprint``;
-        synchronous calls also get ``inspect_s`` (plan acquisition time —
-        ≈ digest cost when warm); with an exec store configured,
-        ``exec_cache_hit`` reports whether execution needed zero new XLA
-        compilations.
+        (dict-compatible): always ``cache_hit``, ``fingerprint``, and the
+        call's ``spans``/``counters``; synchronous calls also get
+        ``inspect_s`` (the plan build of a miss, 0.0 on a hit); with an
+        exec store configured, ``exec_cache_hit`` reports whether
+        execution needed zero new XLA compilations.
 
         ``mesh`` (or ``config.mesh_shape``) routes ops that registered a
         ``shard_plan`` hook through sharded execution; the hook owns the
         partitioning and must produce bit-identical results to the
         single-host path.  Non-shardable ops ignore the mesh.
+
+        The call is one run record (``runtime.spans``) with root span
+        ``reap.run``: ``reap.acquire`` (route, prepare, fingerprint, cache
+        lookup), ``reap.inspect`` (plan build of a synchronous miss), then
+        the executor's own spans.
         """
-        spec = _ops.get_op(op_tag)
-        hops = 0
-        while spec.route is not None:          # resolve router/alias ops
-            op_tag, kw = spec.route(operands, self.config, self._routes,
-                                    **kw)
+        with spans.record("reap.run") as rec:
+            result, stats = self._run(rec, op_tag, operands, overlap, mesh,
+                                      kw)
+        return result, dataclasses.replace(
+            stats, spans=dict(rec.seconds), counters=dict(rec.counters))
+
+    def _run(self, rec, op_tag, operands, overlap, mesh, kw):
+        with spans.span("reap.acquire"):
             spec = _ops.get_op(op_tag)
-            hops += 1
-            if hops > 4:
-                raise RuntimeError(f"op route loop resolving {op_tag!r}")
-        cfg = self.config
-        if spec.allowed_kw is not None:
-            unknown = set(kw) - set(spec.allowed_kw)
-            if unknown:
-                raise TypeError(
-                    f"op {op_tag!r} got unexpected keyword arguments "
-                    f"{sorted(unknown)}; accepts {sorted(spec.allowed_kw)}")
-        overlap = cfg.overlap if overlap is None else overlap
-        mesh = mesh if mesh is not None else self._default_mesh()
-        sharded = (mesh is not None and spec.shard_plan is not None
-                   and spec.capabilities.shardable)
-        chunked = (not sharded and spec.execute_chunked is not None
-                   and cfg.n_chunks > 1)
-        if spec.prepare is not None:    # derive once what fingerprint +
-            kw = spec.prepare(operands, cfg, **kw)   # inspect both need
-        fp = spec.fingerprint(operands, cfg, chunked=chunked, **kw)
-        if sharded:
-            # namespace sharded plans by mesh extent: the shard_plan
-            # artifact partitions rows for exactly this many shards, so a
-            # different mesh must miss and re-partition
-            from ..parallel.sharding import axis_size, dp_axes
-            n_shards = axis_size(mesh, dp_axes(mesh))
-            fp = dataclasses.replace(
-                fp, params=tuple(fp.params) + (("shards", n_shards),))
+            hops = 0
+            while spec.route is not None:      # resolve router/alias ops
+                op_tag, kw = spec.route(operands, self.config, self._routes,
+                                        **kw)
+                spec = _ops.get_op(op_tag)
+                hops += 1
+                if hops > 4:
+                    raise RuntimeError(
+                        f"op route loop resolving {op_tag!r}")
+            rec.op = op_tag
+            cfg = self.config
+            if spec.allowed_kw is not None:
+                unknown = set(kw) - set(spec.allowed_kw)
+                if unknown:
+                    raise TypeError(
+                        f"op {op_tag!r} got unexpected keyword arguments "
+                        f"{sorted(unknown)}; accepts "
+                        f"{sorted(spec.allowed_kw)}")
+            overlap = cfg.overlap if overlap is None else overlap
+            mesh = mesh if mesh is not None else self._default_mesh()
+            sharded = (mesh is not None and spec.shard_plan is not None
+                       and spec.capabilities.shardable)
+            chunked = (not sharded and spec.execute_chunked is not None
+                       and cfg.n_chunks > 1)
+            if spec.prepare is not None:    # derive once what fingerprint +
+                kw = spec.prepare(operands, cfg, **kw)  # inspect both need
+            fp = spec.fingerprint(operands, cfg, chunked=chunked, **kw)
+            if sharded:
+                # namespace sharded plans by mesh extent: the shard_plan
+                # artifact partitions rows for exactly this many shards, so
+                # a different mesh must miss and re-partition
+                from ..parallel.sharding import axis_size, dp_axes
+                n_shards = axis_size(mesh, dp_axes(mesh))
+                fp = dataclasses.replace(
+                    fp, params=tuple(fp.params) + (("shards", n_shards),))
+            cached, source = self.cache.get_with_source(fp)
+            self._record_op(op_tag, source)
+        hit = cached is not None
 
         inspect_s: Optional[float] = None
         with self._exec_scope() as exec_probe:
-            if sharded:
-                cached, source = self.cache.get_with_source(fp)
-                self._record_op(op_tag, source)
-                result, op_stats, artifact = spec.shard_plan(
-                    cached, operands, cfg, mesh=mesh, **kw)
+            if sharded or chunked:
+                hook = spec.shard_plan if sharded else spec.execute_chunked
+                extra = dict(mesh=mesh) if sharded else dict(overlap=overlap)
+                result, op_stats, artifact = hook(cached, operands, cfg,
+                                                  **extra, **kw)
                 if cached is None and artifact is not None:
                     try:
                         artifact.fingerprint = fp
                     except (AttributeError, TypeError):
                         pass    # custom artifacts need not carry a slot
                     self.cache.put(fp, artifact)
-                hit = cached is not None
-            elif chunked:
-                cached, source = self.cache.get_with_source(fp)
-                self._record_op(op_tag, source)
-                result, op_stats, artifact = spec.execute_chunked(
-                    cached, operands, cfg, overlap=overlap, **kw)
-                if cached is None and artifact is not None:
-                    try:
-                        artifact.fingerprint = fp
-                    except (AttributeError, TypeError):
-                        pass    # custom artifacts need not carry a slot
-                    self.cache.put(fp, artifact)
-                hit = cached is not None
             else:
-                t0 = time.perf_counter()
-                plan, source = self.cache.get_with_source(fp)
-                self._record_op(op_tag, source)
-                if plan is None:
-                    plan = spec.inspect(operands, cfg, fp, **kw)
-                    self.cache.put(fp, plan)
-                inspect_s = time.perf_counter() - t0
-                hit = source is not None
-                result, op_stats = spec.execute_sync(plan, operands, cfg,
+                inspect_s = 0.0
+                if cached is None:
+                    with spans.span("reap.inspect") as ins:
+                        cached = spec.inspect(operands, cfg, fp, **kw)
+                        self.cache.put(fp, cached)
+                    inspect_s = ins.seconds
+                result, op_stats = spec.execute_sync(cached, operands, cfg,
                                                      overlap=overlap, **kw)
         return result, RunStats(
             cache_hit=hit,

@@ -63,7 +63,8 @@ CAPABILITY_ROUTINGS: Tuple[str, ...] = ("host", "in_graph")
 # are violations until the key is declared here (and as a RunStats field),
 # so the typed stats surface and the linted one cannot drift apart.
 RUNSTATS_FIELDS: Tuple[str, ...] = (
-    "cache_hit", "store_hit", "exec_cache_hit", "fingerprint", "inspect_s")
+    "cache_hit", "store_hit", "exec_cache_hit", "fingerprint", "inspect_s",
+    "spans", "counters")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, List, Optional, Tuple
 
@@ -35,6 +34,8 @@ from repro.core.inspector import (PatternFingerprint, SpGemmBlockPlan,
                                   inspect_spgemm_gather, next_pow2)
 from repro.core.spgemm import (block_result_to_csr, _block_execute_jnp,
                                spgemm_gather_execute_chunk)
+
+from . import spans
 
 
 @dataclasses.dataclass
@@ -81,53 +82,65 @@ def _emit_pool() -> ThreadPoolExecutor:
 def run_overlapped(n_chunks: int,
                    inspect_fn: Callable[[int], object],
                    execute_fn: Callable[[int, object], object],
-                   overlap: bool = True) -> Tuple[List[object], OverlapStats]:
+                   overlap: bool = True,
+                   execute_span: str = "reap.execute"
+                   ) -> Tuple[List[object], OverlapStats]:
     """Double-buffered inspector/executor driver.
 
     ``inspect_fn(k)`` must be independent of execution results (pure host
     pattern work); ``execute_fn(k, artifact)`` may carry sequential state.
     While chunk *k* executes, chunk *k+1* is inspected on a worker thread.
+
+    Spans: ``reap.pipeline`` (the whole call: ``wall_s``), ``reap.emit``
+    (each ``inspect_fn`` call, on the worker when overlapped, counted into
+    the caller's run record: ``inspect_s``), ``reap.emit_wait`` (the caller
+    blocked on the worker) and ``execute_span`` (each ``execute_fn`` call:
+    ``execute_s``).
     """
-    t_wall = time.perf_counter()
     inspect_s = 0.0
     execute_s = 0.0
     results: List[object] = []
+    rec = spans.current()
 
     def timed_inspect(k: int):
-        t0 = time.perf_counter()
-        art = inspect_fn(k)
-        return art, time.perf_counter() - t0
+        with spans.bind(rec), spans.span("reap.emit") as emit:
+            art = inspect_fn(k)
+        return art, emit.seconds
 
-    if not overlap or n_chunks <= 1:
-        for k in range(n_chunks):
-            art, dt = timed_inspect(k)
-            inspect_s += dt
-            t0 = time.perf_counter()
+    def timed_execute(k: int, art) -> float:
+        with spans.span(execute_span) as ex:
             results.append(execute_fn(k, art))
-            execute_s += time.perf_counter() - t0
-    else:
-        pool = _emit_pool()
-        fut = pool.submit(timed_inspect, 0)
-        try:
+        return ex.seconds
+
+    with spans.span("reap.pipeline") as wall:
+        if not overlap or n_chunks <= 1:
             for k in range(n_chunks):
-                art, dt = fut.result()
+                art, dt = timed_inspect(k)
                 inspect_s += dt
-                if k + 1 < n_chunks:
-                    fut = pool.submit(timed_inspect, k + 1)   # prefetch k+1
-                t0 = time.perf_counter()
-                results.append(execute_fn(k, art))
-                execute_s += time.perf_counter() - t0
-        finally:
-            # on an execute_fn error, settle the in-flight prefetch so the
-            # shared worker is idle (and its exception consumed) before the
-            # caller unwinds — the per-call-pool join this pool replaced
-            fut.cancel()
+                execute_s += timed_execute(k, art)
+        else:
+            pool = _emit_pool()
+            fut = pool.submit(timed_inspect, 0)
             try:
-                fut.exception()
-            except BaseException:       # CancelledError is a BaseException
-                pass
+                for k in range(n_chunks):
+                    with spans.span("reap.emit_wait"):
+                        art, dt = fut.result()
+                    inspect_s += dt
+                    if k + 1 < n_chunks:
+                        fut = pool.submit(timed_inspect, k + 1)  # prefetch
+                    execute_s += timed_execute(k, art)
+            finally:
+                # on an execute_fn error, settle the in-flight prefetch so
+                # the shared worker is idle (and its exception consumed)
+                # before the caller unwinds — the per-call-pool join this
+                # pool replaced
+                fut.cancel()
+                try:
+                    fut.exception()
+                except BaseException:   # CancelledError is a BaseException
+                    pass
     stats = OverlapStats(n_chunks, overlap and n_chunks > 1, inspect_s,
-                         execute_s, time.perf_counter() - t_wall)
+                         execute_s, wall.seconds)
     return results, stats
 
 
@@ -402,14 +415,16 @@ def spgemm_block_chunked(a: CSR, b: CSR, block: int = 128, n_chunks: int = 4,
     so callers can cache the chunk set; a warm chunkset skips plan-build
     entirely and the pipeline is scatter+execute only.
     """
-    t0 = time.perf_counter()
+    plan_s = 0.0
     if chunkset is None:
-        plan = inspect_spgemm_block(a, b, block)
-        # bounds only: chunk slices materialize inside the emit stage, one
-        # chunk ahead of the device (hidden under execution when overlapped)
-        chunkset = build_block_chunkset(plan, n_chunks, lazy=True)
+        with spans.span("reap.inspect") as ins:
+            plan = inspect_spgemm_block(a, b, block)
+            # bounds only: chunk slices materialize inside the emit stage,
+            # one chunk ahead of the device (hidden under execution when
+            # overlapped)
+            chunkset = build_block_chunkset(plan, n_chunks, lazy=True)
+        plan_s = ins.seconds
     plan = chunkset.plan
-    plan_s = time.perf_counter() - t0
 
     base = dict(method="block_chunked", n_chunks=chunkset.n_chunks,
                 plan_s=plan_s, flops=plan.flops(), n_pairs=plan.n_pairs,
@@ -437,25 +452,34 @@ def spgemm_block_chunked(a: CSR, b: CSR, block: int = 128, n_chunks: int = 4,
         b_blocks[ch.b_eblk, ch.b_erow, ch.b_ecol] = b.data[ch.b_sel]
         return ch, sched, a_blocks, b_blocks
 
+    # the schedule arrays each executor takes (int32 already: bucketed)
+    sched_keys = (("a_id", "b_id", "out_id", "is_first", "is_last")
+                  if use_pallas else ("a_id", "b_id", "out_id"))
+
     def execute_fn(k: int, emitted) -> np.ndarray:
         ch, sched, a_blocks, b_blocks = emitted
         n_out_cap = sched["out_cap"] + 1    # +1: dummy tile for dead slots
-        if use_pallas:
-            from repro.kernels import ops as kops
-            out = kops.bsr_spgemm_schedule(
-                sched, jnp.asarray(a_blocks), jnp.asarray(b_blocks),
-                n_out_blocks=n_out_cap)
-        else:
-            out = _block_execute_jnp(
-                jnp.asarray(a_blocks), jnp.asarray(b_blocks),
-                jnp.asarray(sched["a_id"]), jnp.asarray(sched["b_id"]),
-                jnp.asarray(sched["out_id"]), n_out=n_out_cap)
-        return np.asarray(out)[:ch.n_out_blocks]
+        with spans.span("reap.h2d"):
+            a_dev, b_dev = jnp.asarray(a_blocks), jnp.asarray(b_blocks)
+            ids = {key: jnp.asarray(sched[key]) for key in sched_keys}
+            spans.count("h2d_bytes", a_dev.nbytes + b_dev.nbytes
+                        + sum(v.nbytes for v in ids.values()))
+        with spans.span("reap.launch"):
+            if use_pallas:
+                from repro.kernels import ops as kops
+                out = kops.bsr_spgemm_schedule(ids, a_dev, b_dev,
+                                               n_out_blocks=n_out_cap)
+            else:
+                out = _block_execute_jnp(a_dev, b_dev, ids["a_id"],
+                                         ids["b_id"], ids["out_id"],
+                                         n_out=n_out_cap)
+        return spans.to_host(out)[:ch.n_out_blocks]
 
     results, ostats = run_overlapped(chunkset.n_chunks, emit_fn,
                                      execute_fn, overlap)
-    c_blocks = np.concatenate(results, axis=0)
-    c = block_result_to_csr(plan, c_blocks, a.n_rows, b.n_cols)
+    with spans.span("reap.extract"):
+        c_blocks = np.concatenate(results, axis=0)
+        c = block_result_to_csr(plan, c_blocks, a.n_rows, b.n_cols)
     base.update(overlap=ostats.overlap, inspect_s=ostats.inspect_s,
                 execute_s=ostats.execute_s, wall_s=ostats.wall_s,
                 hidden_s=ostats.hidden_s)
@@ -497,32 +521,42 @@ def cholesky_execute_overlapped(plan: CholeskyPlan, a_vals: np.ndarray,
     per-handoff thread overhead is amortized (etree schedules routinely have
     hundreds of tiny levels).
     """
-    state = [init_values(plan, a_vals, dtype)]
+    with spans.span("reap.values"):
+        state = [init_values(plan, a_vals, dtype)]
+        spans.count("h2d_puts", 1)
+        spans.count("h2d_bytes", state[0].nbytes)
     groups = _level_groups(plan, max_chunks)
 
     def inspect_fn(k: int):
-        return [emit_level_bundle(plan, int(ell)) for ell in groups[k]]
+        bundles = [emit_level_bundle(plan, int(ell)) for ell in groups[k]]
+        return bundles, sum(arr.nbytes for b in bundles for arr in b)
 
-    def execute_fn(k: int, bundles) -> None:
+    def execute_fn(k: int, emitted) -> None:
+        # one launch per level; each of a bundle's host arrays is one
+        # host→device put when the step is called
+        bundles, nbytes = emitted
         for bundle in bundles:
             state[0] = _level_step(state[0], *bundle)
+        spans.count("launches", len(bundles))
+        spans.count("h2d_puts", sum(len(b) for b in bundles))
+        spans.count("h2d_bytes", nbytes)
 
-    _, ostats = run_overlapped(len(groups), inspect_fn, execute_fn, overlap)
+    _, ostats = run_overlapped(len(groups), inspect_fn, execute_fn, overlap,
+                               execute_span="reap.dispatch")
     vals = state[0]
-    t0 = time.perf_counter()
-    # reaplint: disable=REAP003 deliberate drain: queued device work is
-    # blocked on inside the timed region so the stats stay comparable
-    # with the sync path (which blocks before stamping)
-    vals.block_until_ready()
-    drain = time.perf_counter() - t0
-    execute_s = ostats.execute_s + drain
-    wall_s = ostats.wall_s + drain
+    with spans.span("reap.drain") as drain:
+        # reaplint: disable=REAP003 deliberate drain: queued device work is
+        # blocked on inside the timed region so the stats stay comparable
+        # with the sync path (which blocks before stamping)
+        vals.block_until_ready()
+    execute_s = ostats.execute_s + drain.seconds
+    wall_s = ostats.wall_s + drain.seconds
     stats = dict(execute_s=execute_s, emit_s=ostats.inspect_s,
                  wall_s=wall_s,
                  hidden_s=max(0.0, ostats.inspect_s + execute_s - wall_s),
                  overlap=ostats.overlap, n_levels=plan.n_levels,
                  nnz_l=plan.nnz, flops=plan.flops())
-    return np.asarray(vals[:plan.nnz]), stats
+    return spans.to_host(vals[:plan.nnz]), stats
 
 
 # ---------------------------------------------------------------------------

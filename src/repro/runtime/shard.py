@@ -31,7 +31,6 @@ cross device counts.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -47,6 +46,7 @@ from repro.core.inspector import (MoeDispatchPlan, PatternFingerprint,
 from repro.core.spgemm import _gather_math
 from repro.kernels.bsr_spmm import SpmmPlan, _spmm_math, inspect_spmm
 from repro.parallel.sharding import axis_size, dp_axes
+from repro.runtime import spans
 from repro.runtime.exec_store import persistent_jit
 from repro.runtime.ops import register_plan_type
 
@@ -192,15 +192,16 @@ def sharded_spgemm_gather(a: CSR, b: CSR, mesh, *, tile: int = 1024,
     ordered row ranges — the stitch is an exact concatenation.
     """
     n_shards = data_shard_count(mesh)
-    t0 = time.perf_counter()
+    inspect_s = 0.0
     if plan is None:
-        bounds = shard_bounds(a.n_rows, n_shards)
-        plans = [inspect_spgemm_gather(
-            a.row_slice(int(bounds[k]), int(bounds[k + 1])), b, tile)
-            for k in range(n_shards)]
-        plan = ShardedPlan(n_shards, a.n_rows, b.n_cols, tile, bounds,
-                           plans)
-    inspect_s = time.perf_counter() - t0
+        with spans.span("reap.inspect") as ins:
+            bounds = shard_bounds(a.n_rows, n_shards)
+            plans = [inspect_spgemm_gather(
+                a.row_slice(int(bounds[k]), int(bounds[k + 1])), b, tile)
+                for k in range(n_shards)]
+            plan = ShardedPlan(n_shards, a.n_rows, b.n_cols, tile, bounds,
+                               plans)
+        inspect_s = ins.seconds
     bounds, plans = plan.bounds, plan.plans
 
     pp_cap = max(next_pow2(max(1, p.a_idx.shape[0] // max(1, plan.tile)))
@@ -224,21 +225,20 @@ def sharded_spgemm_gather(a: CSR, b: CSR, mesh, *, tile: int = 1024,
         b_idx[k, :n] = p.b_idx
         out_idx[k, :n] = np.where(p.out_idx >= p.c_nnz, c_cap, p.out_idx)
 
-    t1 = time.perf_counter()
-    fn = _gather_shard_fn(mesh)
-    c_sh = np.asarray(fn(
-        jnp.asarray(a_vals), jnp.asarray(b.data), jnp.asarray(a_idx),
-        jnp.asarray(b_idx), jnp.asarray(out_idx), c_cap=int(c_cap)))
-    c_data = np.concatenate(
-        [c_sh[k, :p.c_nnz] for k, p in enumerate(plans)])
-    c_indptr = np.zeros(plan.n_rows + 1, np.int64)
-    c_indptr[1:] = np.cumsum(
-        np.concatenate([np.diff(p.c_indptr) for p in plans]))
-    c_indices = np.concatenate([p.c_indices for p in plans])
-    c = CSR(plan.n_rows, plan.n_cols, c_indptr, c_indices, c_data)
-    exec_s = time.perf_counter() - t1
+    with spans.span("reap.execute") as ex:
+        fn = _gather_shard_fn(mesh)
+        c_sh = np.asarray(fn(
+            jnp.asarray(a_vals), jnp.asarray(b.data), jnp.asarray(a_idx),
+            jnp.asarray(b_idx), jnp.asarray(out_idx), c_cap=int(c_cap)))
+        c_data = np.concatenate(
+            [c_sh[k, :p.c_nnz] for k, p in enumerate(plans)])
+        c_indptr = np.zeros(plan.n_rows + 1, np.int64)
+        c_indptr[1:] = np.cumsum(
+            np.concatenate([np.diff(p.c_indptr) for p in plans]))
+        c_indices = np.concatenate([p.c_indices for p in plans])
+        c = CSR(plan.n_rows, plan.n_cols, c_indptr, c_indices, c_data)
     stats = dict(method="gather_sharded", n_shards=n_shards,
-                 inspect_s=inspect_s, execute_s=exec_s,
+                 inspect_s=inspect_s, execute_s=ex.seconds,
                  n_pp=sum(p.n_pp for p in plans),
                  flops=sum(p.flops() for p in plans))
     return c, stats, plan
@@ -259,10 +259,11 @@ def sharded_spmm(x: np.ndarray, w: CSR, mesh, block: int, *,
     streams a single host-local grid and has no shard_map form.
     """
     n_shards = data_shard_count(mesh)
-    t0 = time.perf_counter()
+    inspect_s = 0.0
     if plan is None:
-        plan = inspect_spmm(w, block)
-    inspect_s = time.perf_counter() - t0
+        with spans.span("reap.inspect") as ins:
+            plan = inspect_spmm(w, block)
+        inspect_s = ins.seconds
     dtype = np.dtype(dtype)
     x = np.asarray(x, dtype)
     t, d_in = x.shape
@@ -279,21 +280,23 @@ def sharded_spmm(x: np.ndarray, w: CSR, mesh, block: int, *,
                          ).transpose(0, 2, 1, 3)
     w_tiles = plan.scatter(w.data, dtype=dtype)
 
-    t1 = time.perf_counter()
-    fn = _spmm_shard_fn(mesh)
-    out_j = np.asarray(fn(
-        jnp.asarray(x_tiles), jnp.asarray(w_tiles), jnp.asarray(plan.w_id),
-        jnp.asarray(plan.k_blk), jnp.asarray(plan.j_blk),
-        n_j=plan.n_j_blocks))           # (n_shards, n_j, t_cap, bs)
-    pieces = []
-    for k in range(n_shards):
-        s, e = int(bounds[k]), int(bounds[k + 1])
-        y_k = out_j[k].swapaxes(0, 1).reshape(t_cap, plan.n_j_blocks * bs)
-        pieces.append(y_k[:e - s])
-    y = np.concatenate(pieces)[:, :plan.n_cols]
-    exec_s = time.perf_counter() - t1
+    with spans.span("reap.execute") as ex:
+        fn = _spmm_shard_fn(mesh)
+        out_j = np.asarray(fn(
+            jnp.asarray(x_tiles), jnp.asarray(w_tiles),
+            jnp.asarray(plan.w_id), jnp.asarray(plan.k_blk),
+            jnp.asarray(plan.j_blk),
+            n_j=plan.n_j_blocks))       # (n_shards, n_j, t_cap, bs)
+        pieces = []
+        for k in range(n_shards):
+            s, e = int(bounds[k]), int(bounds[k + 1])
+            y_k = out_j[k].swapaxes(0, 1).reshape(t_cap,
+                                                  plan.n_j_blocks * bs)
+            pieces.append(y_k[:e - s])
+        y = np.concatenate(pieces)[:, :plan.n_cols]
     stats = dict(method="spmm_sharded", n_shards=n_shards,
-                 inspect_s=inspect_s, execute_s=exec_s, n_jobs=plan.n_jobs,
+                 inspect_s=inspect_s, execute_s=ex.seconds,
+                 n_jobs=plan.n_jobs,
                  fill=plan.pat.fill, flops=plan.flops(t))
     return y, stats, plan
 
@@ -315,26 +318,27 @@ def sharded_moe_dispatch(tokens: np.ndarray, routing: CSR, capacity: int,
     plan) — the result shape of the single-host executor.
     """
     n_shards = data_shard_count(mesh)
-    t0 = time.perf_counter()
+    inspect_s = 0.0
     if plan is None:
-        plan = inspect_moe_dispatch(routing, capacity)
-    inspect_s = time.perf_counter() - t0
+        with spans.span("reap.inspect") as ins:
+            plan = inspect_moe_dispatch(routing, capacity)
+        inspect_s = ins.seconds
     tokens = np.asarray(tokens)
-    t1 = time.perf_counter()
-    if plan.n_experts % n_shards:
-        x_bundles = plan.bundle(tokens)
-        sharded = False
-    else:
-        d = tokens.shape[-1]
-        pad = np.concatenate([tokens, np.zeros((1, d), tokens.dtype)])
-        st = plan.slot_token.reshape(
-            n_shards, plan.n_experts // n_shards, plan.capacity)
-        fn = _moe_shard_fn(mesh)
-        x_bundles = np.asarray(fn(jnp.asarray(st), jnp.asarray(pad))
-                               ).reshape(plan.n_experts, plan.capacity, d)
-        sharded = True
-    bundle_s = time.perf_counter() - t1
+    with spans.span("reap.bundle"):
+        if plan.n_experts % n_shards:
+            x_bundles = plan.bundle(tokens)
+            sharded = False
+        else:
+            d = tokens.shape[-1]
+            pad = np.concatenate([tokens, np.zeros((1, d), tokens.dtype)])
+            st = plan.slot_token.reshape(
+                n_shards, plan.n_experts // n_shards, plan.capacity)
+            fn = _moe_shard_fn(mesh)
+            x_bundles = np.asarray(fn(jnp.asarray(st), jnp.asarray(pad))
+                                   ).reshape(plan.n_experts, plan.capacity,
+                                             d)
+            sharded = True
     stats = dict(method="dispatch_sharded", n_shards=n_shards,
-                 sharded=sharded, inspect_s=inspect_s, bundle_s=bundle_s,
+                 sharded=sharded, inspect_s=inspect_s,
                  capacity=plan.capacity, dropped=plan.dropped_frac)
     return (x_bundles, plan), stats, plan
