@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -245,28 +245,61 @@ class SpGemmBlockPlan:
         """FLOPs a perfectly element-sparse executor would do (fill metric)."""
         return int(2 * self.a_pat.src_nnz * self.block)
 
-    def out_entry_order(self):
-        """Row-major global ordering of every stored output-tile entry.
+    def out_csr_index(self, execute: Callable) -> "BlockOutIndex":
+        """Where A·B's structural entries sit in the output tiles (memoized).
 
-        Returns ``(perm, rows, cols)``: ``c_blocks.reshape(-1)[perm]`` lists
-        the output entries in CSR (row, col) order with global coordinates
-        ``rows``/``cols``.  Pattern-pure, so the sort is paid once per plan
-        lifetime and the per-call CSR extraction is a gather + mask (see
-        ``spgemm.block_result_to_csr``).  Memoized as a plain attribute —
-        not a dataclass field, so serialization skips it.
+        Built by running the plan's executor once on indicator operands:
+        ``execute(a_data, b_data)`` returns the (n_out_blocks, block, block)
+        output tiles for CSR value arrays of A and B, and gets 1.0 at every
+        stored position of both.  Every product term is then positive, so a
+        nonzero tile entry is exactly a structural entry of A·B: the pattern
+        comes from the pattern alone, never from a product's values.
+
+        Pattern-pure, so it is paid once per plan lifetime and
+        ``execute`` is called only on a miss; the per-call CSR extraction
+        is one gather over it (``spgemm.block_result_to_csr``).  Memoized
+        as a plain attribute — not a dataclass field, so serialization
+        skips it and a loaded plan rebuilds it on first use.
         """
-        cached = getattr(self, "_entry_order", None)
-        if cached is None:
-            bs = self.block
-            t = np.repeat(np.arange(self.n_out_blocks), bs * bs)
-            rr = np.tile(np.repeat(np.arange(bs), bs), self.n_out_blocks)
-            cc = np.tile(np.arange(bs), self.n_out_blocks * bs)
-            rows = self.out_brow[t] * bs + rr
-            cols = self.out_bcol[t] * bs + cc
-            perm = np.lexsort((cols, rows))
-            cached = (perm, rows[perm], cols[perm])
-            self._entry_order = cached
-        return cached
+        cached = getattr(self, "_out_csr_index", None)
+        if cached is not None:
+            return cached
+        tiles = np.asarray(execute(np.ones(self.a_pat.src_nnz, np.float32),
+                                   np.ones(self.b_pat.src_nnz, np.float32)))
+        bs = self.block
+        nz = np.flatnonzero(tiles.reshape(-1))       # tile-major order
+        t, local = np.divmod(nz, bs * bs)
+        r, c = np.divmod(local, bs)
+        rows = self.out_brow[t].astype(np.int64) * bs + r
+        # tiles run in (block row, block col) order, so a stable sort on the
+        # row alone leaves each row's entries in column order
+        order = np.argsort(rows, kind="stable")
+        n_rows = self.a_pat.src_n_rows
+        indptr = np.zeros(n_rows + 1, np.int64)
+        np.cumsum(np.bincount(rows, minlength=n_rows), out=indptr[1:])
+        index = BlockOutIndex(
+            nz[order], indptr,
+            (self.out_bcol[t].astype(np.int64) * bs + c)[order])
+        for arr in index:
+            arr.setflags(write=False)
+        self._out_csr_index = index
+        return index
+
+
+class BlockOutIndex(NamedTuple):
+    """CSR extraction index of a block plan's output tiles.
+
+    ``sel[i]`` is the offset, in ``c_blocks.reshape(-1)``, of the i-th
+    structural entry of A·B in CSR (row-major) order; ``indptr`` and
+    ``indices`` are that pattern's row pointer and column indices.  All
+    three are int64: ``sel`` is numpy's own index type, so the per-call
+    gather converts no index array.  Read-only: every product of the plan
+    shares them.
+    """
+
+    sel: np.ndarray
+    indptr: np.ndarray
+    indices: np.ndarray
 
 
 def inspect_spgemm_block(a: CSR, b: CSR, block: int = 128,

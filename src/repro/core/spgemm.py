@@ -18,6 +18,7 @@ The numpy reference ``spgemm_ref_numpy`` doubles as the CPU-library baseline
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import numpy as np
@@ -189,21 +190,32 @@ def block_result_to_dense(plan: SpGemmBlockPlan, c_blocks: np.ndarray
 
 def block_result_to_csr(plan: SpGemmBlockPlan, c_blocks: np.ndarray,
                         n_rows: int, n_cols: int) -> CSR:
-    """Output tiles → CSR, without materializing the dense matrix.
+    """Output tiles → CSR: one gather over A·B's structural entries.
 
-    Equivalent to ``CSR.from_dense(block_result_to_dense(...))`` (exact
-    zeros dropped, entries row-major) but the extraction cost scales with
-    the stored *block* pattern, not n² — and the ordering permutation is
-    pattern-pure (``plan.out_entry_order``), so the per-call tail of the
-    planned block path is a gather + mask + bincount, no sort.
+    Bit-for-bit ``CSR.from_dense(block_result_to_dense(...))`` for finite
+    values: the same entries in row-major order, the same dtypes, exact
+    zeros dropped.  Entries outside the structural pattern are exact zeros
+    in every tile, so only the pattern's entries are read.
+
+    Pattern-pure, built once per plan (``plan.out_csr_index``, through the
+    synchronous executor unless the caller built it already): which tile
+    entries are read, in what order, and ``indptr``/``indices``, which every
+    result of the plan shares read-only.  Per call: the gather of the
+    values and a check for exact zeros.  Only zeros inside the pattern
+    (cancellation, stored zeros in A or B) cost more: they are dropped,
+    ``indices`` compacted, ``indptr`` recounted, and their number is
+    counted as ``extract_zeros_dropped``.
     """
-    perm, rows, cols = plan.out_entry_order()
-    flat = c_blocks.reshape(-1)[perm]
-    keep = (flat != 0) & (rows < n_rows) & (cols < n_cols)
-    r, vals = rows[keep], flat[keep]
-    indptr = np.zeros(n_rows + 1, dtype=np.int64)
-    indptr[1:] = np.cumsum(np.bincount(r, minlength=n_rows))
-    return CSR(n_rows, n_cols, indptr, cols[keep], vals)
+    index = plan.out_csr_index(functools.partial(spgemm_block_execute, plan))
+    vals = c_blocks.reshape(-1)[index.sel]
+    zeros = np.flatnonzero(vals == 0)
+    spans.count("extract_zeros_dropped", zeros.size)
+    if not zeros.size:
+        return CSR(n_rows, n_cols, index.indptr, index.indices, vals)
+    keep = vals != 0
+    # each row start moves back by the zeros dropped before it
+    indptr = index.indptr - np.searchsorted(zeros, index.indptr)
+    return CSR(n_rows, n_cols, indptr, index.indices[keep], vals[keep])
 
 
 # ---------------------------------------------------------------------------
@@ -251,6 +263,8 @@ def spgemm(a: CSR, b: CSR, method: str = "auto", block: int = 128,
             c_blocks = spgemm_block_execute(plan, a.data, b.data,
                                             use_pallas=use_pallas)
         with spans.span("reap.extract"):
+            plan.out_csr_index(functools.partial(
+                spgemm_block_execute, plan, use_pallas=use_pallas))
             c = block_result_to_csr(plan, c_blocks, a.n_rows, b.n_cols)
         stats = dict(method="block", inspect_s=inspect_s,
                      execute_s=ex.seconds, flops=plan.flops(),

@@ -211,6 +211,18 @@ def test_block_chunked_keys_derive_from_spans():
     assert warm["plan_s"] == 0.0 and "reap.inspect" not in warm.spans
 
 
+def test_block_chunked_product_counts_extraction_zeros():
+    rt = ReapRuntime(n_chunks=3, block=32)
+    a, b = _pair(11)
+    rt.run("spgemm", a, b, method="block")       # plan miss: index built
+    spans.clear()
+    c, st = rt.run("spgemm", a, b, method="block")
+    rec = spans.recent(1)[0]
+    assert st["method"] == "block_chunked"
+    # random values: no exact zero inside A·B's structural pattern
+    assert rec.counters["extract_zeros_dropped"] == 0
+    assert rec.calls["reap.extract"] == 1 and c.nnz > 0
+
 def test_cholesky_keys_derive_from_spans():
     rt = ReapRuntime()
     a = random_spd_csr(80, 0.08, np.random.default_rng(6))

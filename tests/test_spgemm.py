@@ -18,6 +18,55 @@ def _dense_oracle(a: CSR, b: CSR):
     return a.to_dense().astype(np.float64) @ b.to_dense().astype(np.float64)
 
 
+def _with_values(a: CSR, data) -> CSR:
+    return CSR(a.n_rows, a.n_cols, a.indptr, a.indices,
+               np.asarray(data, np.float32))
+
+
+def _case_banded():
+    return (_rand(90, 70, 0.07, 21, "banded"),
+            _rand(70, 50, 0.07, 22, "banded"), 16, False)
+
+
+def _case_blocky():
+    return (_rand(128, 96, 0.08, 30, "blocky"),
+            _rand(96, 64, 0.08, 31, "blocky"), 32, False)
+
+
+def _case_ragged_dims():
+    # no dimension a multiple of the block: padded tile rows/cols stay out
+    return _rand(45, 37, 0.1, 32), _rand(37, 29, 0.1, 33), 16, False
+
+
+def _case_empty_product():
+    a = CSR.from_dense(np.zeros((40, 30), np.float32))
+    return a, _rand(30, 20, 0.2, 34), 16, False
+
+
+def _case_cancellation():
+    # C[0, 0] = 1·1 + 1·(−1) = 0 exactly, inside the structural pattern
+    dense_a = _rand(40, 40, 0.1, 35).to_dense()
+    dense_a[0] = 0.0
+    dense_a[0, :2] = 1.0
+    dense_b = _rand(40, 40, 0.1, 36).to_dense()
+    dense_b[:2, 0] = (1.0, -1.0)
+    return CSR.from_dense(dense_a), CSR.from_dense(dense_b), 16, True
+
+
+def _case_stored_zeros():
+    # explicitly stored zeros in A: their products are structural zeros
+    a = _rand(60, 50, 0.1, 37)
+    data = a.data.copy()
+    data[::3] = 0.0
+    return _with_values(a, data), _rand(50, 40, 0.1, 38), 16, True
+
+
+_EXTRACTION_CASES = {
+    "banded": _case_banded, "blocky": _case_blocky,
+    "ragged_dims": _case_ragged_dims, "empty_product": _case_empty_product,
+    "cancellation": _case_cancellation, "stored_zeros": _case_stored_zeros}
+
+
 class TestGatherPath:
     @given(st.integers(5, 120), st.integers(5, 120), st.integers(5, 120),
            st.floats(0.01, 0.3), st.integers(0, 5))
@@ -136,16 +185,82 @@ class TestPlannedExecution:
         with pytest.raises(TypeError):
             spgemm(a, a, plan=object())
 
-    def test_block_csr_extraction_matches_dense_roundtrip(self):
+    @pytest.mark.parametrize("case", sorted(_EXTRACTION_CASES))
+    def test_block_csr_extraction_matches_dense_roundtrip(self, case):
         from repro.core import block_result_to_csr
-        a = _rand(90, 70, 0.07, 21, "banded")
-        b = _rand(70, 50, 0.07, 22, "banded")
-        plan = inspect_spgemm_block(a, b, 16)
+        from repro.runtime import spans
+        a, b, block, must_drop = _EXTRACTION_CASES[case]()
+        plan = inspect_spgemm_block(a, b, block)
         c_blocks = np.asarray(spgemm_block_execute(plan, a.data, b.data,
                                                    use_pallas=False))
         via_dense = CSR.from_dense(
-            block_result_to_dense(plan, c_blocks)[:90, :50])
-        direct = block_result_to_csr(plan, c_blocks, 90, 50)
-        np.testing.assert_array_equal(direct.indptr, via_dense.indptr)
-        np.testing.assert_array_equal(direct.indices, via_dense.indices)
-        np.testing.assert_array_equal(direct.data, via_dense.data)
+            block_result_to_dense(plan, c_blocks)[:a.n_rows, :b.n_cols])
+        with spans.record("reap.run") as rec:
+            direct = block_result_to_csr(plan, c_blocks, a.n_rows, b.n_cols)
+        for name in ("indptr", "indices", "data"):
+            got, want = getattr(direct, name), getattr(via_dense, name)
+            assert got.dtype == want.dtype, name
+            np.testing.assert_array_equal(got, want)
+        # exact zeros inside A·B's structural pattern are dropped and counted
+        n_structural = plan.out_csr_index(None).sel.size
+        dropped = rec.counters["extract_zeros_dropped"]
+        assert dropped == n_structural - direct.nnz
+        assert (dropped > 0) == must_drop
+
+    def test_block_extraction_index_is_pattern_pure(self):
+        from repro.core import block_result_to_csr
+        a, b = _rand(96, 80, 0.08, 23, "blocky"), _rand(80, 64, 0.08, 24)
+        plan = inspect_spgemm_block(a, b, 32)
+        outs = []
+        for seed in (25, 26):
+            vals = np.random.default_rng(seed).standard_normal
+            a2 = CSR(a.n_rows, a.n_cols, a.indptr, a.indices,
+                     vals(a.nnz).astype(np.float32))
+            b2 = CSR(b.n_rows, b.n_cols, b.indptr, b.indices,
+                     vals(b.nnz).astype(np.float32))
+            c_blocks = spgemm_block_execute(plan, a2.data, b2.data,
+                                            use_pallas=False)
+            outs.append(block_result_to_csr(plan, c_blocks, 96, 64))
+            np.testing.assert_allclose(outs[-1].to_dense(),
+                                       _dense_oracle(a2, b2),
+                                       rtol=1e-4, atol=1e-4)
+        index = plan.out_csr_index(None)     # memoized: no executor needed
+        assert not np.array_equal(outs[0].data, outs[1].data)
+        np.testing.assert_array_equal(outs[0].indices, outs[1].indices)
+        for c in outs:
+            assert c.indices is index.indices and c.indptr is index.indptr
+
+    def test_block_extraction_after_plan_store_round_trip(self, tmp_path):
+        from repro.core import block_result_to_csr
+        from repro.core.inspector import fingerprint_pattern
+        from repro.runtime import PlanStore
+        a, b = _rand(70, 90, 0.08, 27, "banded"), _rand(90, 60, 0.08, 28)
+        plan = inspect_spgemm_block(a, b, 16)
+        c_blocks = spgemm_block_execute(plan, a.data, b.data,
+                                        use_pallas=False)
+        before = block_result_to_csr(plan, c_blocks, 70, 60)
+        fp = fingerprint_pattern("spgemm_block", (a, b), block=16)
+        PlanStore(tmp_path).put(fp, plan)
+        back = PlanStore(tmp_path).get(fp)
+        assert getattr(back, "_out_csr_index", None) is None   # not stored
+        after = block_result_to_csr(back, c_blocks, 70, 60)
+        for name in ("indptr", "indices", "data"):
+            np.testing.assert_array_equal(getattr(after, name),
+                                          getattr(before, name))
+        for got, want in zip(back.out_csr_index(None),
+                             plan.out_csr_index(None)):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+
+    def test_block_extraction_shares_read_only_structure(self):
+        from repro.core import block_result_to_csr
+        a = _rand(64, 64, 0.1, 29, "blocky")
+        plan = inspect_spgemm_block(a, a, 16)
+        c = block_result_to_csr(
+            plan, spgemm_block_execute(plan, a.data, a.data,
+                                       use_pallas=False), 64, 64)
+        for arr in (c.indptr, c.indices):
+            assert not arr.flags.writeable
+            with pytest.raises(ValueError):
+                arr[0] = 1
+        c.data[0] = 1.0                      # a product's own values
