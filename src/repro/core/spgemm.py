@@ -116,20 +116,37 @@ def _gather_execute_capped(a_data, b_data, a_idx, b_idx, out_idx, c_cap: int):
 
 def spgemm_gather_execute_chunk(plan: SpGemmGatherPlan, a_data: np.ndarray,
                                 b_data: np.ndarray) -> np.ndarray:
-    """Execute one chunk plan with bucketed shapes; returns (c_nnz,) values."""
+    """Execute one chunk plan with bucketed shapes; returns (c_nnz,) values.
+
+    Spans: ``reap.values`` (padding the plan's index arrays to the bucketed
+    length on the host), ``reap.h2d`` (values and indices to the device,
+    counted as ``h2d_bytes``), ``reap.launch`` and ``reap.fetch``.
+    Counters: ``gather_products``, the plan's live partial products, and
+    ``gather_slots``, the padded length sent.
+    """
     c_cap = next_pow2(plan.c_nnz)
     n = plan.a_idx.shape[0]
     cap = next_pow2(max(1, n // max(1, plan.tile))) * plan.tile
     pad = cap - n
-    a_idx = np.concatenate([plan.a_idx, np.full(pad, len(a_data), np.int64)])
-    b_idx = np.concatenate([plan.b_idx, np.full(pad, len(b_data), np.int64)])
-    # dead slots (pad + the plan's own tile padding) map to the c_cap segment
-    out_idx = np.concatenate([plan.out_idx, np.full(pad, plan.c_nnz, np.int64)])
-    out_idx = np.where(out_idx >= plan.c_nnz, c_cap, out_idx)
-    c = _gather_execute_capped(jnp.asarray(a_data), jnp.asarray(b_data),
-                               jnp.asarray(a_idx), jnp.asarray(b_idx),
-                               jnp.asarray(out_idx), c_cap=c_cap)
-    return np.asarray(c[:plan.c_nnz])
+    with spans.span("reap.values"):
+        a_idx = np.concatenate([plan.a_idx,
+                                np.full(pad, len(a_data), np.int64)])
+        b_idx = np.concatenate([plan.b_idx,
+                                np.full(pad, len(b_data), np.int64)])
+        # dead slots (pad + the plan's own tile padding) map to the c_cap
+        # segment
+        out_idx = np.concatenate([plan.out_idx,
+                                  np.full(pad, plan.c_nnz, np.int64)])
+        out_idx = np.where(out_idx >= plan.c_nnz, c_cap, out_idx)
+    with spans.span("reap.h2d"):
+        args = [jnp.asarray(x) for x in (a_data, b_data, a_idx, b_idx,
+                                         out_idx)]
+        spans.count("h2d_bytes", sum(x.nbytes for x in args))
+    spans.count("gather_products", plan.n_pp)
+    spans.count("gather_slots", cap)
+    with spans.span("reap.launch"):
+        c = _gather_execute_capped(*args, c_cap=c_cap)[:plan.c_nnz]
+    return spans.to_host(c)
 
 
 # ---------------------------------------------------------------------------

@@ -187,17 +187,27 @@ def spgemm_gather_chunked(a: CSR, b: CSR, n_chunks: int = 4,
     With a warm ``chunkset`` (plan-cache hit) inspection degenerates to a
     list lookup and the pipeline is pure execution.  Returns
     (C, stats, chunkset) so callers can cache the chunk set.
+
+    Spans, besides ``run_overlapped``'s: ``reap.inspect`` around each chunk
+    plan built on a miss (inside that chunk's ``reap.emit``; their sum is
+    ``stats["plan_s"]``, 0 on a warm call), the executor's own
+    (``spgemm_gather_execute_chunk``), and ``reap.extract`` around the
+    stitch of the chunk results into one CSR.
     """
     bounds = (chunkset.row_bounds if chunkset is not None
               else chunk_row_bounds(a, n_chunks))
     nk = len(bounds) - 1
     plans: List[Optional[SpGemmGatherPlan]] = (
         list(chunkset.plans) if chunkset is not None else [None] * nk)
+    plan_s = [0.0] * nk
 
     def inspect_fn(k: int) -> SpGemmGatherPlan:
         if plans[k] is None:
-            plans[k] = inspect_spgemm_gather(
-                a.row_slice(int(bounds[k]), int(bounds[k + 1])), b, tile)
+            with spans.span("reap.inspect") as ins:
+                plans[k] = inspect_spgemm_gather(
+                    a.row_slice(int(bounds[k]), int(bounds[k + 1])), b,
+                    tile)
+            plan_s[k] = ins.seconds
         return plans[k]
 
     def execute_fn(k: int, plan: SpGemmGatherPlan) -> np.ndarray:
@@ -207,18 +217,19 @@ def spgemm_gather_chunked(a: CSR, b: CSR, n_chunks: int = 4,
     chunks, ostats = run_overlapped(nk, inspect_fn, execute_fn, overlap)
 
     # stitch: chunk output rows are disjoint, contiguous, and ordered
-    c_indptr = np.zeros(a.n_rows + 1, dtype=np.int64)
-    row_nnz = np.concatenate([np.diff(p.c_indptr) for p in plans]) \
-        if nk else np.zeros(0, np.int64)
-    c_indptr[1:] = np.cumsum(row_nnz)
-    c_indices = (np.concatenate([p.c_indices for p in plans])
-                 if nk else np.zeros(0, np.int64))
-    c_data = (np.concatenate(chunks) if nk
-              else np.zeros(0, a.data.dtype))
-    c = CSR(a.n_rows, b.n_cols, c_indptr, c_indices, c_data)
+    with spans.span("reap.extract"):
+        c_indptr = np.zeros(a.n_rows + 1, dtype=np.int64)
+        row_nnz = np.concatenate([np.diff(p.c_indptr) for p in plans]) \
+            if nk else np.zeros(0, np.int64)
+        c_indptr[1:] = np.cumsum(row_nnz)
+        c_indices = (np.concatenate([p.c_indices for p in plans])
+                     if nk else np.zeros(0, np.int64))
+        c_data = (np.concatenate(chunks) if nk
+                  else np.zeros(0, a.data.dtype))
+        c = CSR(a.n_rows, b.n_cols, c_indptr, c_indices, c_data)
     out_set = chunkset if chunkset is not None else GatherChunkSet(
         a.n_rows, b.n_cols, tile, bounds, plans)  # type: ignore[arg-type]
-    stats = dict(method="gather_chunked", n_chunks=nk,
+    stats = dict(method="gather_chunked", n_chunks=nk, plan_s=sum(plan_s),
                  overlap=ostats.overlap, inspect_s=ostats.inspect_s,
                  execute_s=ostats.execute_s, wall_s=ostats.wall_s,
                  hidden_s=ostats.hidden_s,

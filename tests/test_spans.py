@@ -6,10 +6,12 @@ from __future__ import annotations
 import sys
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import random_csr, random_spd_csr
+from repro.core.inspector import next_pow2
 from repro.core.solver import cg_solve
 from repro.runtime import ReapRuntime, spans
 
@@ -241,10 +243,51 @@ def test_cholesky_keys_derive_from_spans():
 def test_gather_chunked_keys_derive_from_spans():
     rt = ReapRuntime(n_chunks=3, tile=64)
     a, b = _pair(8)
-    _, st = rt.run("spgemm", a, b, method="gather")
-    assert st["inspect_s"] == pytest.approx(st.spans["reap.emit"], rel=1e-12)
-    assert st["execute_s"] == pytest.approx(st.spans["reap.execute"],
-                                            rel=1e-12)
+    _, cold = rt.run("spgemm", a, b, method="gather")
+    _, warm = rt.run("spgemm", a, b, method="gather")
+    for st in (cold, warm):
+        assert st["method"] == "gather_chunked" and st["n_chunks"] == 3
+        assert st["inspect_s"] == pytest.approx(st.spans["reap.emit"],
+                                                rel=1e-12)
+        assert st["execute_s"] == pytest.approx(st.spans["reap.execute"],
+                                                rel=1e-12)
+    # each chunk plan of the miss is built under its own reap.inspect
+    assert cold["plan_s"] > 0
+    assert cold["plan_s"] == pytest.approx(cold.spans["reap.inspect"],
+                                           rel=1e-12)
+    assert warm["plan_s"] == 0.0 and "reap.inspect" not in warm.spans
+
+
+def test_gather_chunked_record_times_and_counts_each_stage():
+    from repro.runtime.pipeline import spgemm_gather_chunked
+    rt = ReapRuntime(n_chunks=3, tile=64)
+    a, b = _pair(12)
+    rt.run("spgemm", a, b, method="gather")                 # plan miss
+    spans.clear()
+    rt.run("spgemm", a, b, method="gather")
+    rec = spans.recent(1)[0]
+    assert rec.op == "spgemm_gather"
+    for name in ("reap.values", "reap.h2d", "reap.launch", "reap.fetch"):
+        assert rec.calls[name] == 3, name
+    assert rec.calls["reap.extract"] == 1
+    # the same plans, built outside the runtime: what each chunk sends
+    _, _, chunkset = spgemm_gather_chunked(a, b, n_chunks=3, tile=64)
+    index_bytes = jnp.asarray(np.zeros(1, np.int64)).dtype.itemsize
+    sent = products = slots = 0
+    for k, plan in enumerate(chunkset.plans):
+        rows = slice(chunkset.row_bounds[k], chunkset.row_bounds[k + 1] + 1)
+        cap = next_pow2(plan.a_idx.shape[0] // plan.tile) * plan.tile
+        nnz = int(np.diff(a.indptr[rows][[0, -1]])[0])
+        sent += (nnz + b.nnz) * a.data.itemsize + 3 * cap * index_bytes
+        products += plan.n_pp
+        slots += cap
+    c = rec.counters
+    assert c["h2d_bytes"] == sent
+    # the plans' live products are A·B's scalar products
+    assert c["gather_products"] == products == int(
+        np.diff(b.indptr)[a.indices].sum()) > 0
+    assert c["gather_slots"] == slots >= products
+    assert c["d2h_bytes"] == sum(p.c_nnz for p in chunkset.plans) * 4
 
 
 def test_sync_spgemm_execute_s_is_its_span():

@@ -264,3 +264,28 @@ class TestPlannedExecution:
             with pytest.raises(ValueError):
                 arr[0] = 1
         c.data[0] = 1.0                      # a product's own values
+
+
+class TestChunkedGatherOnMesh:
+    """The runtime's default path on a shrunk ``bench/meshes.py`` pattern
+    (the ``cop20K`` stand-in's generator): ``auto`` → chunked gather."""
+
+    def test_matches_reference(self):
+        import json
+        from pathlib import Path
+
+        from bench import meshes
+        from repro.runtime import ReapRuntime
+        path = Path(__file__).resolve().parents[1] / "bench" / "configs"
+        config = json.loads((path / "cop20K.json").read_text())
+        config.update(rows=3000, nnz=64961)
+        indptr, indices = meshes.pattern_of(config)
+        n = config["rows"]
+        a = CSR(n, n, indptr, indices, np.random.default_rng(40)
+                .standard_normal(indices.shape[0]).astype(np.float32))
+        c, stats = ReapRuntime().run("spgemm", a, a)
+        assert stats["method"] == "gather_chunked" and stats["n_chunks"] > 1
+        ref = spgemm_ref_numpy(a, a)
+        np.testing.assert_array_equal(c.indptr, ref.indptr)
+        np.testing.assert_array_equal(c.indices, ref.indices)
+        np.testing.assert_allclose(c.data, ref.data, rtol=1e-5, atol=1e-5)

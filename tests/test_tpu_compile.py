@@ -88,6 +88,16 @@ def test_gather_executor(one_chip):
         idx, idx, idx, c_cap=c_cap).compile()
 
 
+def test_gather_executor_cop20k_chunk(one_chip):
+    """One of four chunks of the cop20K stand-in's A² (about 20M partial
+    products, bucketed to 2**25 slots; about 4.7M outputs, to 2**23)."""
+    idx = one_chip((1 << 25,), jnp.int64)
+    compiled = _gather_execute_capped.lower(
+        one_chip((656_083,), jnp.float32), one_chip((2_624_331,), jnp.float32),
+        idx, idx, idx, c_cap=1 << 23).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def test_sharded_gather_executor(topo):
     """The sharded gather-SpGEMM program over the four chips of a v5e:2x2."""
     from repro.runtime.shard import _gather_shard_fn
