@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -133,6 +133,9 @@ class SpGemmGatherPlan:
 
     The plan is *pure*: it depends only on the operands' sparsity patterns,
     never their values — same pattern ⇒ bit-identical plan (cacheable).
+
+    The chunked executor keeps the index arrays padded to their bucket on
+    the device (``device_indices``), so a warm product sends only values.
     """
 
     n_rows: int
@@ -154,6 +157,66 @@ class SpGemmGatherPlan:
 
     def flops(self) -> int:
         return 2 * self.n_pp
+
+    def device_indices(self, a_len: int, b_len: int,
+                       put: Callable) -> "GatherDeviceIndex":
+        """The index arrays padded to their bucket, on the device (memoized).
+
+        ``a_idx``/``b_idx``/``out_idx`` are padded to ``cap`` slots (a
+        power-of-two tile count): dead gathers index the zero slot the
+        executor appends to each value array (``a_len``/``b_len``, the
+        lengths of the value arrays the plan is run on), and every dead
+        output (the padding and the plan's own tile padding) goes to
+        segment ``c_cap``, the power of two ≥ ``c_nnz`` that the executor
+        slices off.  ``put`` uploads the three host arrays and returns them
+        on the device; it is called only on a miss.
+
+        Pattern-pure, so it is built once per plan and every product of the
+        plan reuses it.  It holds ``3 * cap`` int64 slots on the device
+        (3.22 GB over cop20k_A's four chunk plans) for as long as the plan
+        lives: evicting the plan from its cache frees it.  Memoized as a plain
+        attribute — not a dataclass field, so serialization skips it, the
+        plan cache's size estimate leaves it out, and a loaded plan
+        rebuilds it on first use.  Value arrays of other lengths than the
+        memo was built for are a caller's bug and raise ``ValueError``.
+        """
+        cached = getattr(self, "_device_indices", None)
+        if cached is not None:
+            if (cached.a_len, cached.b_len) != (a_len, b_len):
+                raise ValueError(
+                    f"value lengths ({a_len}, {b_len}) differ from the "
+                    f"({cached.a_len}, {cached.b_len}) the plan's device "
+                    f"indices were built for")
+            return cached
+        c_cap = next_pow2(self.c_nnz)
+        n = self.a_idx.shape[0]
+        cap = next_pow2(max(1, n // max(1, self.tile))) * self.tile
+        pad = cap - n
+        a_idx = np.concatenate([self.a_idx, np.full(pad, a_len, np.int64)])
+        b_idx = np.concatenate([self.b_idx, np.full(pad, b_len, np.int64)])
+        out_idx = np.concatenate([self.out_idx,
+                                  np.full(pad, self.c_nnz, np.int64)])
+        out_idx = np.where(out_idx >= self.c_nnz, c_cap, out_idx)
+        self._device_indices = GatherDeviceIndex(
+            *put((a_idx, b_idx, out_idx)), cap=cap, c_cap=c_cap,
+            a_len=a_len, b_len=b_len)
+        return self._device_indices
+
+
+class GatherDeviceIndex(NamedTuple):
+    """A gather plan's index arrays padded to ``cap`` slots, on the device.
+
+    ``out_idx`` sends dead slots to segment ``c_cap``; ``a_len``/``b_len``
+    are the value lengths the padding was built for.
+    """
+
+    a_idx: Any
+    b_idx: Any
+    out_idx: Any
+    cap: int
+    c_cap: int
+    a_len: int
+    b_len: int
 
 
 def inspect_spgemm_gather(a: CSR, b: CSR, tile: int = 1024,

@@ -32,7 +32,7 @@ from repro.runtime.exec_store import persistent_jit
 from .formats import BsrPattern, CSR
 from .inspector import (SpGemmBlockPlan, SpGemmGatherPlan, choose_spgemm_path,
                         csr_pattern_digest, fingerprint_pattern,
-                        inspect_spgemm_block, inspect_spgemm_gather, next_pow2)
+                        inspect_spgemm_block, inspect_spgemm_gather)
 
 
 # ---------------------------------------------------------------------------
@@ -114,38 +114,47 @@ def _gather_execute_capped(a_data, b_data, a_idx, b_idx, out_idx, c_cap: int):
     return _gather_math(a_data, b_data, a_idx, b_idx, out_idx, c_cap)
 
 
+def _put_gather_indices(host):
+    """Uploads a chunk plan's padded index arrays (a memo miss): under
+    ``reap.h2d``, their bytes counted as ``h2d_bytes``, and one
+    ``gather_index_builds``."""
+    with spans.span("reap.h2d"):
+        dev = [jnp.asarray(x) for x in host]
+        spans.count("h2d_bytes", sum(x.nbytes for x in dev))
+    spans.count("gather_index_builds")
+    return dev
+
+
 def spgemm_gather_execute_chunk(plan: SpGemmGatherPlan, a_data: np.ndarray,
                                 b_data: np.ndarray) -> np.ndarray:
     """Execute one chunk plan with bucketed shapes; returns (c_nnz,) values.
 
-    Spans: ``reap.values`` (padding the plan's index arrays to the bucketed
-    length on the host), ``reap.h2d`` (values and indices to the device,
-    counted as ``h2d_bytes``), ``reap.launch`` and ``reap.fetch``.
-    Counters: ``gather_products``, the plan's live partial products, and
-    ``gather_slots``, the padded length sent.
+    The plan's index arrays, padded to the bucketed length, stay on the
+    device (``plan.device_indices``: pattern-pure, built on the plan's
+    first product and freed with the plan; 3.22 GB over cop20k_A's four
+    chunk plans), so a product sends only ``a_data`` and ``b_data``.
+
+    Spans: ``reap.values`` (the index memo: a lookup on a warm plan; on the
+    plan's first product the host padding, with the indices' upload nested
+    in it under ``reap.h2d``), ``reap.h2d`` (the values to the device; with
+    the indices' upload, counted as ``h2d_bytes``), ``reap.launch`` and
+    ``reap.fetch``.  Counters: ``gather_index_builds``, the memos built
+    (1 on a plan's first product, else 0); ``gather_products``, the plan's
+    live partial products, and ``gather_slots``, the padded length run.
     """
-    c_cap = next_pow2(plan.c_nnz)
-    n = plan.a_idx.shape[0]
-    cap = next_pow2(max(1, n // max(1, plan.tile))) * plan.tile
-    pad = cap - n
+    spans.count("gather_index_builds", 0)
     with spans.span("reap.values"):
-        a_idx = np.concatenate([plan.a_idx,
-                                np.full(pad, len(a_data), np.int64)])
-        b_idx = np.concatenate([plan.b_idx,
-                                np.full(pad, len(b_data), np.int64)])
-        # dead slots (pad + the plan's own tile padding) map to the c_cap
-        # segment
-        out_idx = np.concatenate([plan.out_idx,
-                                  np.full(pad, plan.c_nnz, np.int64)])
-        out_idx = np.where(out_idx >= plan.c_nnz, c_cap, out_idx)
+        index = plan.device_indices(len(a_data), len(b_data),
+                                    _put_gather_indices)
     with spans.span("reap.h2d"):
-        args = [jnp.asarray(x) for x in (a_data, b_data, a_idx, b_idx,
-                                         out_idx)]
-        spans.count("h2d_bytes", sum(x.nbytes for x in args))
+        values = [jnp.asarray(a_data), jnp.asarray(b_data)]
+        spans.count("h2d_bytes", sum(x.nbytes for x in values))
     spans.count("gather_products", plan.n_pp)
-    spans.count("gather_slots", cap)
+    spans.count("gather_slots", index.cap)
     with spans.span("reap.launch"):
-        c = _gather_execute_capped(*args, c_cap=c_cap)[:plan.c_nnz]
+        c = _gather_execute_capped(*values, index.a_idx, index.b_idx,
+                                   index.out_idx,
+                                   c_cap=index.c_cap)[:plan.c_nnz]
     return spans.to_host(c)
 
 

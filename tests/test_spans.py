@@ -262,31 +262,39 @@ def test_gather_chunked_record_times_and_counts_each_stage():
     from repro.runtime.pipeline import spgemm_gather_chunked
     rt = ReapRuntime(n_chunks=3, tile=64)
     a, b = _pair(12)
-    rt.run("spgemm", a, b, method="gather")                 # plan miss
     spans.clear()
+    rt.run("spgemm", a, b, method="gather")                 # plan miss
     rt.run("spgemm", a, b, method="gather")
-    rec = spans.recent(1)[0]
-    assert rec.op == "spgemm_gather"
+    miss, rec = spans.recent(2)
+    assert rec.op == miss.op == "spgemm_gather"
     for name in ("reap.values", "reap.h2d", "reap.launch", "reap.fetch"):
         assert rec.calls[name] == 3, name
     assert rec.calls["reap.extract"] == 1
+    # the miss uploads each chunk's padded indices once, nested in its
+    # reap.values; a warm product only looks them up
+    assert miss.calls["reap.h2d"] == 6 and miss.calls["reap.values"] == 3
+    assert miss.counters["gather_index_builds"] == 3
+    assert rec.counters["gather_index_builds"] == 0
     # the same plans, built outside the runtime: what each chunk sends
     _, _, chunkset = spgemm_gather_chunked(a, b, n_chunks=3, tile=64)
     index_bytes = jnp.asarray(np.zeros(1, np.int64)).dtype.itemsize
-    sent = products = slots = 0
+    values = indices = products = slots = 0
     for k, plan in enumerate(chunkset.plans):
         rows = slice(chunkset.row_bounds[k], chunkset.row_bounds[k + 1] + 1)
         cap = next_pow2(plan.a_idx.shape[0] // plan.tile) * plan.tile
         nnz = int(np.diff(a.indptr[rows][[0, -1]])[0])
-        sent += (nnz + b.nnz) * a.data.itemsize + 3 * cap * index_bytes
+        values += (nnz + b.nnz) * a.data.itemsize
+        indices += 3 * cap * index_bytes
         products += plan.n_pp
         slots += cap
     c = rec.counters
-    assert c["h2d_bytes"] == sent
+    assert c["h2d_bytes"] == values
+    assert miss.counters["h2d_bytes"] == values + indices
     # the plans' live products are A·B's scalar products
     assert c["gather_products"] == products == int(
         np.diff(b.indptr)[a.indices].sum()) > 0
     assert c["gather_slots"] == slots >= products
+    assert miss.counters["gather_slots"] == slots
     assert c["d2h_bytes"] == sum(p.c_nnz for p in chunkset.plans) * 4
 
 

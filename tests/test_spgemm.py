@@ -6,7 +6,7 @@ from _hypothesis_compat import given, settings, st
 from repro.core import (CSR, choose_spgemm_path, inspect_spgemm_block,
                         inspect_spgemm_gather, random_csr, spgemm,
                         spgemm_block_execute, spgemm_gather_execute,
-                        spgemm_ref_numpy)
+                        spgemm_gather_execute_chunk, spgemm_ref_numpy)
 from repro.core.spgemm import block_result_to_dense
 
 
@@ -264,6 +264,83 @@ class TestPlannedExecution:
             with pytest.raises(ValueError):
                 arr[0] = 1
         c.data[0] = 1.0                      # a product's own values
+
+
+def _gather_plan(seed, pattern="uniform"):
+    a, b = _rand(70, 60, 0.1, seed, pattern), _rand(60, 50, 0.1, seed + 1)
+    return a, b, inspect_spgemm_gather(a, b, tile=64)
+
+
+def _fresh_values(m: CSR, seed) -> CSR:
+    return _with_values(m, np.random.default_rng(seed).standard_normal(m.nnz))
+
+
+class TestGatherDeviceIndices:
+    """The chunked gather executor's per-plan memo of the padded index
+    arrays on the device (``SpGemmGatherPlan.device_indices``)."""
+
+    @pytest.mark.parametrize("pattern", ["uniform", "banded", "powerlaw"])
+    def test_products_on_one_plan_match_reference_and_share_the_memo(
+            self, pattern):
+        from repro.runtime import spans
+        a, b, plan = _gather_plan(41, pattern)
+        memos, builds = [], []
+        for seed in (42, 43):
+            a2, b2 = _fresh_values(a, seed), _fresh_values(b, seed + 10)
+            with spans.record("reap.run") as rec:
+                c = spgemm_gather_execute_chunk(plan, a2.data, b2.data)
+            builds.append(rec.counters["gather_index_builds"])
+            ref = spgemm_ref_numpy(a2, b2)
+            np.testing.assert_array_equal(plan.c_indices, ref.indices)
+            np.testing.assert_allclose(c, ref.data, rtol=1e-5, atol=1e-5)
+            memos.append(plan.device_indices(a.nnz, b.nnz, put=None))
+        assert builds == [1, 0]
+        assert memos[0] is memos[1]
+        assert memos[0].cap >= plan.a_idx.shape[0] and memos[0].c_cap >= \
+            plan.c_nnz
+        for first, second in zip(memos[0][:3], memos[1][:3]):
+            assert second is first
+
+    @pytest.mark.parametrize("via", ["serialize", "plan_store"])
+    def test_round_trip_carries_no_memo_and_rebuilds_it(self, via,
+                                                        tmp_path):
+        from repro.core.inspector import fingerprint_pattern
+        from repro.runtime import PlanStore
+        from repro.runtime.plan_cache import (_entry_nbytes,
+                                              deserialize_plan,
+                                              serialize_plan)
+        a, b, plan = _gather_plan(44)
+        nbytes = _entry_nbytes(plan)
+        before = spgemm_gather_execute_chunk(plan, a.data, b.data)
+        assert _entry_nbytes(plan) == nbytes      # the memo is not counted
+        if via == "serialize":
+            back = deserialize_plan(serialize_plan(plan))
+        else:
+            fp = fingerprint_pattern("spgemm_gather", (a, b), tile=64)
+            PlanStore(tmp_path).put(fp, plan)
+            back = PlanStore(tmp_path).get(fp)
+        assert getattr(back, "_device_indices", None) is None  # not stored
+        after = spgemm_gather_execute_chunk(back, a.data, b.data)
+        np.testing.assert_array_equal(after, before)
+        rebuilt = back.device_indices(a.nnz, b.nnz, put=None)
+        original = plan.device_indices(a.nnz, b.nnz, put=None)
+        assert rebuilt is not original
+        assert rebuilt[3:] == original[3:]
+        for got, want in zip(rebuilt[:3], original[:3]):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("operand", ["a", "b"])
+    def test_other_value_lengths_raise(self, operand):
+        a, b, plan = _gather_plan(45)
+        spgemm_gather_execute_chunk(plan, a.data, b.data)
+        a_data, b_data = a.data, b.data
+        if operand == "a":
+            a_data = np.append(a_data, np.float32(0))
+        else:
+            b_data = b_data[:-1]
+        with pytest.raises(ValueError, match="device indices"):
+            spgemm_gather_execute_chunk(plan, a_data, b_data)
 
 
 class TestChunkedGatherOnMesh:
