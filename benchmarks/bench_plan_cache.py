@@ -1,4 +1,4 @@
-"""Plan-cache + overlap benchmark: the claims of repro.runtime.
+"""Plan-cache benchmark: the claims of repro.runtime.
 
 1. **cold vs warm** — on a repeated-pattern workload (same sparsity,
    fresh values each call: iterative solvers, MoE dispatch, the Fig-10
@@ -6,13 +6,7 @@
    paying the inspector every call; the registry-admitted ``spmm`` and
    ``block_attention`` ops (whose inspectors are intrinsically lighter)
    must be ≥ 1.4× warm.
-2. **sync vs overlapped** — running the chunked schedule with the worker
-   thread prefetching chunk k+1 must be no slower than the same chunked
-   schedule run synchronously (and hides host work when the device is busy).
-   Modes are timed in back-to-back pairs and judged on the best pair: on a
-   CPU-only container the "device" shares cores with the host, so this is
-   the claim that overlap costs no wall time, not that it wins here.
-3. **per-op coverage** — every tag in ``runtime.ops.list_ops()`` with an
+2. **per-op coverage** — every tag in ``runtime.ops.list_ops()`` with an
    example problem (the shared ``repro.analysis.op_examples`` table, also
    replayed by the purity harness) is run miss-then-hit through one
    runtime and its
@@ -20,11 +14,8 @@
    enumerates coverage from the op registry instead of a hard-coded list.
 
 Prints ``plan_cache,...`` CSV lines and a PASS/FAIL verdict per claim, and
-exits non-zero when a gated claim fails (the bench.yml CI gate).  In
-``--reduced`` (CI) mode problem sizes shrink and the sync-vs-overlap rows
-are reported but **not** gated: shared CI runners make two-thread wall-time
-comparisons unreliable, while the cold-vs-warm amortization claim — the one
-the plan cache exists for — stays robust and is always enforced.
+exits non-zero when a claim fails (the bench.yml CI gate).  In
+``--reduced`` (CI) mode problem sizes shrink.
 
     PYTHONPATH=src python -m benchmarks.bench_plan_cache [--reduced]
         [--json OUT]
@@ -51,7 +42,7 @@ from repro.runtime import ReapRuntime, RuntimeConfig, add_runtime_args
 from .op_coverage import per_op_breakdown  # noqa: F401  (re-export)
 
 # CLI-derived base config (main() replaces it via RuntimeConfig.from_args);
-# each bench overrides only the knobs it is *about* (n_chunks, overlap, …)
+# each bench overrides only the knobs it is *about* (n_chunks, …)
 _BASE_CFG = RuntimeConfig()
 
 
@@ -61,11 +52,11 @@ def _revalue(a: CSR, rng: np.random.Generator) -> CSR:
                rng.standard_normal(a.nnz).astype(a.data.dtype))
 
 
-def _bench_runtime(method: str, n_chunks: int, overlap: bool) -> ReapRuntime:
+def _bench_runtime(method: str, n_chunks: int) -> ReapRuntime:
     # block path: jnp executor (Pallas interpret mode on this container would
     # time the Python interpreter, not the schedule), modest MXU tile
     kw = dict(use_pallas=False, block=64) if method == "block" else {}
-    return ReapRuntime(_BASE_CFG, n_chunks=n_chunks, overlap=overlap, **kw)
+    return ReapRuntime(_BASE_CFG, n_chunks=n_chunks, **kw)
 
 
 def _matrices(method: str, n: int, density: float, seed: int):
@@ -84,13 +75,13 @@ def bench_spgemm_cache(n: int = 2000, density: float = 0.01,
     cold_s: List[float] = []
     for _ in range(repeats):
         a, b = _revalue(a, rng), _revalue(b, rng)
-        rt = _bench_runtime(method, n_chunks=1, overlap=False)
+        rt = _bench_runtime(method, n_chunks=1)
         t0 = time.perf_counter()
         rt.spgemm(a, b, method=method)
         cold_s.append(time.perf_counter() - t0)
 
     # warm: one runtime; first call populates, the rest hit
-    rt = _bench_runtime(method, n_chunks=1, overlap=False)
+    rt = _bench_runtime(method, n_chunks=1)
     rt.spgemm(a, b, method=method)              # populate
     warm_s: List[float] = []
     for _ in range(repeats):
@@ -115,59 +106,6 @@ def bench_spgemm_cache(n: int = 2000, density: float = 0.01,
     return row
 
 
-def bench_spgemm_overlap(n: int = 2000, density: float = 0.01,
-                         n_chunks: int = 8, repeats: int = 5,
-                         method: str = "gather", tolerance: float = 1.05,
-                         verbose: bool = True) -> dict:
-    """``tolerance`` is the accepted overlapped/sync wall ratio.  Gather uses
-    the strict 1.05 ("no slower"); the block path's executor is a short
-    burst of core-saturating einsums, so on a CPU-only container overlap is
-    parity at best and the check carries the container's thread-scheduling
-    jitter — callers pass a looser bound there (the claim stays: overlap
-    must not cost meaningful wall time)."""
-    _, a, b = _matrices(method, n, density, 1)
-
-    def one(overlap: bool) -> float:
-        # fresh runtime each repeat ⇒ cold inspection actually overlaps
-        rt = _bench_runtime(method, n_chunks=n_chunks, overlap=overlap)
-        t0 = time.perf_counter()
-        rt.spgemm(a, b, method=method)
-        return time.perf_counter() - t0
-
-    # prime the bucketed executor compilation cache for both modes
-    _bench_runtime(method, n_chunks, True).spgemm(a, b, method=method)
-    # paired measurement: each repeat times both modes back to back (order
-    # alternating) so both see the same machine state, and the verdict is
-    # the median of per-pair ratios — load drift cancels within a pair,
-    # and a consistent slowdown still fails (unlike a best-pair verdict).
-    # One retry if the first attempt fails: overlap runs two threads, so a
-    # sustained co-tenant load spike punishes it asymmetrically; a genuine
-    # regression fails both attempts.
-    for _attempt in range(2):
-        sync_t, over_t, ratios = [], [], []
-        for r in range(repeats):
-            if r % 2 == 0:
-                s, o = one(False), one(True)
-            else:
-                o, s = one(True), one(False)
-            sync_t.append(s)
-            over_t.append(o)
-            ratios.append(o / max(s, 1e-9))
-        sync, over = float(np.median(sync_t)), float(np.median(over_t))
-        ratio = float(np.median(ratios))
-        if ratio <= tolerance:
-            break
-    row = dict(bench=f"spgemm_{method}_sync_vs_overlap", n=n,
-               n_chunks=n_chunks, sync_s=sync, overlapped_s=over,
-               ratio=ratio, tolerance=tolerance, ok=ratio <= tolerance)
-    if verbose:
-        print(f"plan_cache,spgemm_{method}_overlap,n={n},chunks={n_chunks},"
-              f"sync_ms={sync * 1e3:.1f},overlapped_ms={over * 1e3:.1f},"
-              f"ratio={ratio:.2f},{'PASS' if row['ok'] else 'FAIL'}"
-              f"(<= {tolerance:.2f}x)")
-    return row
-
-
 def bench_spmm_cache(n: int = 4096, density: float = 0.02, t: int = 32,
                      repeats: int = 5, verbose: bool = True) -> dict:
     """Cold vs warm for the registry-admitted ``spmm`` op (Y = X @ W_sparse).
@@ -187,12 +125,12 @@ def bench_spmm_cache(n: int = 4096, density: float = 0.02, t: int = 32,
     cold_s: List[float] = []
     for _ in range(repeats):
         w = _revalue(w, rng)
-        rt = _bench_runtime("block", n_chunks=1, overlap=False)
+        rt = _bench_runtime("block", n_chunks=1)
         t0 = time.perf_counter()
         rt.run("spmm", fresh_x(), w)
         cold_s.append(time.perf_counter() - t0)
 
-    rt = _bench_runtime("block", n_chunks=1, overlap=False)
+    rt = _bench_runtime("block", n_chunks=1)
     rt.run("spmm", fresh_x(), w)                # populate
     warm_s: List[float] = []
     for _ in range(repeats):
@@ -239,12 +177,12 @@ def bench_block_attention(seq: int = 4096, density: float = 0.05,
     for _ in range(repeats):
         mask = _revalue(mask, rng)              # same pattern, fresh bytes
         q, k, v = fresh_qkv()
-        rt = _bench_runtime("block", n_chunks=1, overlap=False)
+        rt = _bench_runtime("block", n_chunks=1)
         t0 = time.perf_counter()
         rt.run("block_attention", q, k, v, mask)
         cold_s.append(time.perf_counter() - t0)
 
-    rt = _bench_runtime("block", n_chunks=1, overlap=False)
+    rt = _bench_runtime("block", n_chunks=1)
     rt.run("block_attention", *fresh_qkv(), mask)   # populate
     warm_s: List[float] = []
     for _ in range(repeats):
@@ -276,34 +214,28 @@ def bench_cholesky(n: int = 900, density: float = 0.01, repeats: int = 3,
 
     cold_s = []
     for _ in range(repeats):
-        rt = ReapRuntime(_BASE_CFG, overlap=False)
+        rt = ReapRuntime(_BASE_CFG)
         t0 = time.perf_counter()
         rt.cholesky(a, dtype=jnp.float32)
         cold_s.append(time.perf_counter() - t0)
 
-    rt = ReapRuntime(_BASE_CFG, overlap=False)
+    rt = ReapRuntime(_BASE_CFG)
     rt.cholesky(a, dtype=jnp.float32)
-    warm_s, over_s = [], []
+    warm_s = []
     for _ in range(repeats):
         scaled = CSR(a.n_rows, a.n_cols, a.indptr, a.indices, a.data * 1.01)
         t0 = time.perf_counter()
-        rt.cholesky(scaled, dtype=jnp.float32, overlap=False)
+        _, _, st = rt.cholesky(scaled, dtype=jnp.float32)
         warm_s.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        _, _, st = rt.cholesky(scaled, dtype=jnp.float32, overlap=True)
-        over_s.append(time.perf_counter() - t0)
         assert st["cache_hit"]
 
     cold, warm = float(np.median(cold_s)), float(np.median(warm_s))
-    over = float(np.median(over_s))
     row = dict(bench="cholesky", n=n, cold_s=cold, warm_s=warm,
-               overlapped_s=over, speedup=cold / max(warm, 1e-9),
-               overlap_ratio=over / max(warm, 1e-9))
+               speedup=cold / max(warm, 1e-9))
     if verbose:
         print(f"plan_cache,cholesky,n={n},cold_ms={cold * 1e3:.1f},"
-              f"warm_ms={warm * 1e3:.1f},overlapped_ms={over * 1e3:.1f},"
-              f"warm_speedup={row['speedup']:.2f},"
-              f"overlap_ratio={row['overlap_ratio']:.2f}")
+              f"warm_ms={warm * 1e3:.1f},"
+              f"warm_speedup={row['speedup']:.2f}")
     return row
 
 
@@ -312,10 +244,6 @@ def run(verbose: bool = True, reduced: bool = False) -> List[dict]:
         rows = [bench_spgemm_cache(n=1200, verbose=verbose),
                 bench_spgemm_cache(method="block", n=1200, density=0.02,
                                    repeats=7, verbose=verbose),
-                bench_spgemm_overlap(n=1200, verbose=verbose),
-                bench_spgemm_overlap(method="block", n=2000, density=0.02,
-                                     n_chunks=8, repeats=5, tolerance=1.15,
-                                     verbose=verbose),
                 bench_cholesky(n=600, verbose=verbose),
                 # spmm and block_attention keep their full sizes even in
                 # reduced mode: their gates need the inspector/executor
@@ -323,25 +251,16 @@ def run(verbose: bool = True, reduced: bool = False) -> List[dict]:
                 bench_spmm_cache(verbose=verbose),
                 bench_block_attention(verbose=verbose),
                 per_op_breakdown(reduced=True, verbose=verbose)]
-        # overlap walls are not gated on shared runners (see module doc)
-        for r in rows:
-            r["gate"] = "overlap" not in r["bench"]
     else:
         rows = [bench_spgemm_cache(verbose=verbose),
                 bench_spgemm_cache(method="block", density=0.02, repeats=9,
                                    verbose=verbose),
-                bench_spgemm_overlap(verbose=verbose),
-                bench_spgemm_overlap(method="block", n=4000, density=0.02,
-                                     n_chunks=8, repeats=7, tolerance=1.15,
-                                     verbose=verbose),
                 bench_cholesky(verbose=verbose),
                 bench_spmm_cache(verbose=verbose),
                 bench_block_attention(verbose=verbose),
                 per_op_breakdown(verbose=verbose)]
-        for r in rows:
-            r["gate"] = True
     if verbose:
-        ok = all(r.get("ok", True) for r in rows if r["gate"])
+        ok = all(r.get("ok", True) for r in rows)
         print(f"plan_cache,verdict,{'PASS' if ok else 'FAIL'}")
     return rows
 
@@ -349,8 +268,7 @@ def run(verbose: bool = True, reduced: bool = False) -> List[dict]:
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--reduced", action="store_true",
-                    help="smaller problem sizes; overlap rows ungated "
-                         "(CI mode)")
+                    help="smaller problem sizes (CI mode)")
     ap.add_argument("--json", default=None, metavar="OUT",
                     help="write result rows to this JSON file")
     add_runtime_args(ap)
@@ -363,7 +281,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         Path(args.json).write_text(json.dumps(
             dict(bench="plan_cache", reduced=args.reduced, rows=rows),
             indent=1))
-    return 0 if all(r.get("ok", True) for r in rows if r["gate"]) else 1
+    return 0 if all(r.get("ok", True) for r in rows) else 1
 
 
 if __name__ == "__main__":

@@ -101,8 +101,7 @@ class _Workload:
     #: the benchmark's fixed non-store knobs; store directories vary per
     #: phase via dataclasses.replace (the one RuntimeConfig construction
     #: path — see runtime.api.RuntimeConfig)
-    BASE_CFG = RuntimeConfig(use_pallas=False, block=64, n_chunks=4,
-                             overlap=False)
+    BASE_CFG = RuntimeConfig(use_pallas=False, block=64, n_chunks=4)
 
     @classmethod
     def runtime(cls, store_dir: Optional[str],
@@ -228,7 +227,7 @@ def bench_bucketing(reduced: bool, verbose: bool = True) -> dict:
     sizes = [368, 400, 432, 464] if reduced else [368, 400, 432, 464, 528,
                                                   592, 656, 720]
     rng = np.random.default_rng(11)
-    rt = ReapRuntime(use_pallas=False, block=32, n_chunks=4, overlap=False)
+    rt = ReapRuntime(use_pallas=False, block=32, n_chunks=4)
     before = _block_execute_jnp._cache_size()
     for i, n in enumerate(sizes):
         a = random_csr(n, n, 0.02, rng, "blocky")

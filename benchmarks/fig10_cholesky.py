@@ -10,10 +10,10 @@ from typing import List
 import numpy as np
 
 from repro.core import cholesky_baseline_numpy, cholesky_values, inspect_cholesky
-from repro.core.cholesky import cholesky_execute
 from repro.core.simulator import (REAP_32C, REAP_64C,
                                   simulate_cholesky_cpu,
                                   simulate_cholesky_reap)
+from repro.runtime import cholesky_execute_overlapped
 
 from .op_coverage import per_op_warm_rows
 from .table1 import CHOLESKY_SET, make_chol_matrix
@@ -32,7 +32,7 @@ def run(verbose: bool = True) -> List[dict]:
         # measured: numpy numeric baseline vs jitted level executor
         a_vals = cholesky_values(a)
         base_vals, t_base = cholesky_baseline_numpy(plan, a_vals)
-        _, st = cholesky_execute(plan, a_vals)
+        _, st = cholesky_execute_overlapped(plan, a_vals)
         t_reap = st["execute_s"]
 
         row = dict(id=spec.chol_id, name=spec.name, scale=scale,

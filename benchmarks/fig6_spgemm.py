@@ -38,7 +38,7 @@ def run(verbose: bool = True) -> List[dict]:
     geo = {"REAP-32": [], "REAP-64": [], "REAP-128": [], "CPU-16": [],
            "measured": [], "warm": []}
     rng = np.random.default_rng(0)
-    rt = ReapRuntime(n_chunks=1, overlap=False)
+    rt = ReapRuntime(n_chunks=1)
     for spec in SPGEMM_SET:
         a, scale = make_spgemm_matrix(spec)
         stats = spgemm_workload(a, a)

@@ -36,7 +36,7 @@ def per_op_breakdown(reduced: bool = False, verbose: bool = True) -> dict:
     ``cache_stats()["per_op"]``."""
     n = 512 if reduced else 1024
     examples = builtin_examples(n)
-    rt = ReapRuntime(n_chunks=1, overlap=False, use_pallas=False, block=64)
+    rt = ReapRuntime(n_chunks=1, use_pallas=False, block=64)
 
     covered, skipped = [], []
     for tag in concrete_ops():
@@ -82,7 +82,7 @@ def per_op_warm_rows(n: int = 384, repeats: int = 3, verbose: bool = True,
             if verbose:
                 print(f"{prefix}_per_op,{tag},SKIPPED(no example problem)")
             continue
-        rt = ReapRuntime(n_chunks=1, overlap=False, **ex.runtime_kw)
+        rt = ReapRuntime(n_chunks=1, **ex.runtime_kw)
         t0 = time.perf_counter()
         rt.run(tag, *ex.operands(0), **ex.kw)
         cold_s = time.perf_counter() - t0

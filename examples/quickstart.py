@@ -35,11 +35,11 @@ print(f"block path: {stats2['n_pairs']} tile-pair jobs, "
       f"fill={stats2['fill']:.2%} (Pallas kernel, interpret mode on CPU)")
 
 # 5. repeated-pattern workloads go through the runtime: the plan cache pays
-#    the inspector once per pattern, then spgemm(plan=...) replays it
+#    the inspector once per pattern, then replays the plan on fresh values
 from repro.core import CSR
 from repro.runtime import ReapRuntime
 
-rt = ReapRuntime(n_chunks=1, overlap=False)
+rt = ReapRuntime(n_chunks=1)
 rt.spgemm(a, a)                                # miss: builds + caches plan
 a2 = CSR(a.n_rows, a.n_cols, a.indptr, a.indices,
          rng.standard_normal(a.nnz).astype(a.data.dtype))

@@ -35,7 +35,7 @@ n = 1200
 a = random_spd_csr(n, density=0.01, rng=rng)
 # the shared flag set + this script's own picks, via the one sanctioned path
 runtime = ReapRuntime(RuntimeConfig.from_args(
-    args, n_chunks=1, overlap=False, use_pallas=False, block=64))
+    args, n_chunks=1, use_pallas=False, block=64))
 
 # Repeated-pattern workload: same sparsity, three different value/rhs sets
 # (e.g. a time-stepping PDE re-assembling coefficients each step).

@@ -64,7 +64,7 @@ def check_op_purity(tag: str, n: int = 384) -> Dict:
     if example is None:
         return dict(ok=False, detail="no example problem registered "
                                      "(coverage gap in op_examples)")
-    cfg = RuntimeConfig(n_chunks=1, overlap=False, **example.runtime_kw)
+    cfg = RuntimeConfig(n_chunks=1, **example.runtime_kw)
     try:
         fp0, payload0 = _plan_payload(spec, example.operands(0), cfg,
                                       example.kw)

@@ -21,8 +21,9 @@ REAP001  plan purity: inspector scope must not read value buffers
          (``.data`` / ``.values``), coerce operands with ``float()``, or
          take magnitudes (``abs``) — pattern attributes only.
 REAP002  registry completeness: every non-router ``OpSpec`` declares the
-         required hooks; ``plan_types`` entries are dataclasses the
-         generic serializer can round-trip; the generic runtime modules
+         required hooks and exactly one executor hook; ``plan_types``
+         entries are dataclasses the generic serializer can round-trip;
+         the generic runtime modules
          (``runtime/{api,plan_cache,plan_store,exec_store,shard,
          shared_store}.py``) contain no op-tag string branches;
          run-stats keys used in those modules (``RunStats(key=...)``
@@ -252,12 +253,23 @@ def rule_registry(pf, facts, meta) -> List[Finding]:
         tag = const_str(kwargs.get("tag")) or "<dynamic>"
         if meta.ROUTER_HOOK not in names:
             missing = [h for h in meta.REQUIRED_HOOKS if h not in names]
+            executors = [h for h in meta.SINGLE_EXECUTOR_HOOKS
+                         if h in names]
+            if not executors:
+                missing.append(
+                    "one of " + "/".join(meta.SINGLE_EXECUTOR_HOOKS))
             if missing:
                 out.append((
                     "REAP002", node,
                     f"OpSpec for op {tag!r} missing required hooks: "
                     f"{', '.join(missing)} (or declare "
                     f"{meta.ROUTER_HOOK}= to be a pure router)"))
+            elif len(executors) > 1:
+                out.append((
+                    "REAP002", node,
+                    f"OpSpec for op {tag!r} registers "
+                    f"{' and '.join(executors)}; an op has exactly one "
+                    f"executor"))
         plan_types = kwargs.get("plan_types")
         if isinstance(plan_types, ast.Dict) \
                 and not set(meta.SERIALIZER_HOOKS) <= names:

@@ -2,7 +2,8 @@
 
 Host inspector (CPU pass): formats, rir, inspector, etree.
 Device executors: spgemm, cholesky (+ Pallas kernels in repro.kernels).
-Plan caching + inspector/executor overlap live one layer up in repro.runtime.
+Plan caching and the pipelined executors (runtime.pipeline) live one layer
+up in repro.runtime.
 """
 from .formats import BSR, COO, CSR, random_csr, random_spd_csr  # noqa: F401
 from .rir import (DEFAULT_CAPACITY, ElementBundles, ScheduleBundle,  # noqa: F401
@@ -17,7 +18,7 @@ from .inspector import (BsrPattern, MoeDispatchPlan,  # noqa: F401
 from .etree import (CholeskyPlan, cholesky_values, etree, etree_levels,  # noqa: F401
                     inspect_cholesky, symbolic)
 from .spgemm import (block_result_to_csr, block_result_to_dense,  # noqa: F401
-                     spgemm, spgemm_block_execute, spgemm_gather_execute,
-                     spgemm_gather_execute_chunk, spgemm_ref_numpy)
-from .cholesky import (cholesky, cholesky_baseline_numpy, cholesky_execute,  # noqa: F401
+                     spgemm, spgemm_gather_execute_chunk,
+                     spgemm_ref_numpy)
+from .cholesky import (cholesky, cholesky_baseline_numpy,  # noqa: F401
                        emit_level_bundle, init_values, plan_to_dense_l)

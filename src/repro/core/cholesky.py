@@ -16,9 +16,10 @@ paper.  Matching the paper, the numeric phase is all fp32/fp64 FLOPs with no
 symbolic work on the device.
 
 The per-level host work (bundle-emit: building the padded cmod/cdiv index
-arrays) is factored into ``emit_level_bundle`` so runtime.pipeline can
-prepare level ℓ+1 on a worker thread while the device executes level ℓ —
-the software analogue of the paper's CPU/FPGA overlap.
+arrays) is factored into ``emit_level_bundle`` so the one executor,
+``runtime.pipeline.cholesky_execute_overlapped``, can prepare level group
+g+1 on a worker thread while the device executes group g — the software
+analogue of the paper's CPU/FPGA overlap.
 """
 from __future__ import annotations
 
@@ -92,46 +93,21 @@ def init_values(plan: CholeskyPlan, a_vals: np.ndarray, dtype=jnp.float64):
     return jnp.asarray(vals, dtype=dtype)
 
 
-def cholesky_execute(plan: CholeskyPlan, a_vals: np.ndarray,
-                     dtype=jnp.float64) -> Tuple[np.ndarray, dict]:
-    """Run the numeric phase synchronously.
+def cholesky(a: CSR, dtype=jnp.float64):
+    """Full REAP sparse Cholesky, planned and run once: A = L L^T.
+    Returns (plan, L values, stats).
 
-    Returns (L values in CSC order, stats).  ``a_vals`` comes from
-    ``cholesky_values(a)`` — the plan itself is value-free.
+    The plan is built here and run through the op's one executor,
+    ``runtime.pipeline.cholesky_execute_overlapped`` (looked up at call
+    time); ``ReapRuntime.run("cholesky", a)`` runs the same executor with
+    the plan cache in front, for same-pattern refactors.
     """
-    with spans.span("reap.values"):
-        vals = init_values(plan, a_vals, dtype)
-    with spans.span("reap.execute") as ex:
-        for ell in range(plan.n_levels):
-            bundle = emit_level_bundle(plan, ell)
-            vals = _level_step(vals, *bundle)
-        # reaplint: disable=REAP003 deliberate timed drain: execute_s must
-        # measure device completion so sync/overlapped stats stay comparable
-        vals.block_until_ready()
-    stats = dict(execute_s=ex.seconds, n_levels=plan.n_levels,
-                 nnz_l=plan.nnz, flops=plan.flops())
-    return spans.to_host(vals[:plan.nnz]), stats
-
-
-def cholesky(a: CSR, dtype=jnp.float64, plan: CholeskyPlan = None):
-    """Full REAP sparse Cholesky: A = L L^T. Returns (plan, L values, stats).
-
-    With a pre-built ``plan`` (same pattern as ``a``, e.g. from the runtime
-    plan cache) inspection is skipped and the value pass uses the plan's
-    precomputed lower-triangle selection — the warm planned-execution path
-    ``runtime.ReapRuntime`` routes through.
-    """
-    inspect_s = 0.0
-    if plan is None:
-        with spans.span("reap.inspect") as ins:
-            plan = inspect_cholesky(a)
-        inspect_s = ins.seconds
-        a_vals = cholesky_values(a)
-    else:
-        with spans.span("reap.values"):
-            a_vals = plan.a_values(a)
-    vals, stats = cholesky_execute(plan, a_vals, dtype)
-    stats["inspect_s"] = inspect_s
+    from repro.runtime import pipeline
+    with spans.span("reap.inspect") as ins:
+        plan = inspect_cholesky(a)
+    vals, stats = pipeline.cholesky_execute_overlapped(
+        plan, cholesky_values(a), dtype)
+    stats["inspect_s"] = ins.seconds
     return plan, vals, stats
 
 
@@ -172,9 +148,10 @@ def cholesky_baseline_numpy(plan: CholeskyPlan, a_vals: np.ndarray
 # ---------------------------------------------------------------------------
 #
 # One fingerprint per pattern (dtype is a value-level choice and stays out
-# of the key); `overlap` picks the executor — the etree level schedule is
-# the chunk stream, so overlapping lives inside execute_sync rather than a
-# separate chunked hook.
+# of the key).  The runtime builds the plan, then execute_sync runs it
+# through the level-group pipeline: the etree level schedule is the chunk
+# stream, so the overlap lives inside the executor rather than in a
+# chunked hook.
 
 from .inspector import fingerprint_pattern  # noqa: E402
 from repro.runtime.ops import (OpCapabilities, OpSpec,  # noqa: E402
@@ -191,17 +168,12 @@ def _inspect_cholesky(operands, cfg, fp, **kw):
     return inspect_cholesky(a, fp)
 
 
-def _exec_cholesky(plan, operands, cfg, *, overlap, dtype=jnp.float64, **kw):
+def _exec_cholesky(plan, operands, cfg, *, dtype=jnp.float64, **kw):
+    from repro.runtime import pipeline
     (a,) = operands
-    if overlap:
-        from repro.runtime.pipeline import cholesky_execute_overlapped
-        with spans.span("reap.values"):
-            a_vals = plan.a_values(a)
-        vals, stats = cholesky_execute_overlapped(plan, a_vals, dtype,
-                                                  overlap=True)
-    else:
-        _, vals, stats = cholesky(a, dtype, plan=plan)
-        stats["overlap"] = False
+    with spans.span("reap.values"):
+        a_vals = plan.a_values(a)
+    vals, stats = pipeline.cholesky_execute_overlapped(plan, a_vals, dtype)
     return (plan, vals), stats
 
 

@@ -22,8 +22,8 @@ Inspection is split into three stages (runtime.plan_cache exploits this):
      plan, so plans are cacheable and serializable artifacts.
   3. **bundle-emit** — ``plan.schedule`` (and the per-level emitters in
      core.cholesky) turn the plan into the schedule bundles the executor
-     streams.  This is the cheap per-call stage that the overlapped runtime
-     performs on a worker thread while the device executes.
+     streams.  This is the cheap per-call stage that the pipelined executors
+     perform on a worker thread while the device executes.
 """
 from __future__ import annotations
 
@@ -275,7 +275,8 @@ class SpGemmBlockPlan:
     one output tile in flight per grid lane, operands streamed.
 
     Like the gather plan, this is pattern-pure: the operand tiles are
-    re-materialized per call via ``a_pat.scatter(a.data)``.
+    re-materialized per call from the CSR values (the block pipeline's
+    emit stage, through each chunk's scatter maps).
     """
 
     block: int
@@ -308,7 +309,8 @@ class SpGemmBlockPlan:
         """FLOPs a perfectly element-sparse executor would do (fill metric)."""
         return int(2 * self.a_pat.src_nnz * self.block)
 
-    def out_csr_index(self, execute: Callable) -> "BlockOutIndex":
+    def out_csr_index(self, execute: Optional[Callable]
+                      ) -> "BlockOutIndex":
         """Where A·B's structural entries sit in the output tiles (memoized).
 
         Built by running the plan's executor once on indicator operands:
@@ -327,6 +329,10 @@ class SpGemmBlockPlan:
         cached = getattr(self, "_out_csr_index", None)
         if cached is not None:
             return cached
+        if execute is None:
+            raise ValueError(
+                "the plan's CSR extraction index is not built yet: run the "
+                "plan through runtime.pipeline.spgemm_block_chunked first")
         tiles = np.asarray(execute(np.ones(self.a_pat.src_nnz, np.float32),
                                    np.ones(self.b_pat.src_nnz, np.float32)))
         bs = self.block
@@ -564,8 +570,7 @@ def _inspect_moe_dispatch(operands, cfg, fp, *, routing, capacity, **kw):
     return inspect_moe_dispatch(routing, capacity, fp)
 
 
-def _exec_moe_dispatch(plan: MoeDispatchPlan, operands, cfg, *, overlap,
-                       **kw):
+def _exec_moe_dispatch(plan: MoeDispatchPlan, operands, cfg, **kw):
     from repro.runtime import spans
     tokens = np.asarray(operands[0])
     with spans.span("reap.bundle"):

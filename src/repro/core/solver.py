@@ -158,7 +158,7 @@ def cg_solve(a: CSR, b: np.ndarray, runtime=None, *, tol: float = 1e-8,
     """
     from repro.runtime.api import ReapRuntime   # runtime imports core: lazy
     if runtime is None:
-        runtime = ReapRuntime(n_chunks=1, overlap=False)
+        runtime = ReapRuntime(n_chunks=1)
     n = a.n_rows
     if a.n_cols != n:
         raise ValueError("cg_solve needs a square (SPD) matrix")
@@ -241,12 +241,12 @@ def _inspect_spmv(operands, cfg, fp, **kw):
     return inspect_spmv(operands[0], cfg.block, fp)
 
 
-def _exec_spmv(plan, operands, cfg, *, overlap, dtype=np.float32, **kw):
+def _exec_spmv(plan, operands, cfg, *, dtype=np.float32, **kw):
     a, x = operands
     with spans.span("reap.execute") as ex:
         y = spmv_execute(plan, a.data, x, use_pallas=cfg.use_pallas,
                          dtype=dtype)
-    stats = dict(method="spmv", execute_s=ex.seconds, overlap=False,
+    stats = dict(method="spmv", execute_s=ex.seconds,
                  n_jobs=plan.inner.n_jobs, flops=2 * a.nnz)
     return y, stats
 

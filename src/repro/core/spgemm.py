@@ -1,13 +1,15 @@
 """SpGEMM: row-by-row (Gustavson) formulation, REAP-split into
 host inspection (core.inspector) + device execution (this module).
 
-Two executors mirror the DESIGN.md adaptation:
+Two routes mirror the DESIGN.md adaptation, each with one executor — a
+pipeline in ``runtime.pipeline`` over the device programs defined here:
 
 * ``gather`` (VPU path)  — element bundles; device does gather → multiply →
-  segment-sum.  Matches the paper's element pipelines most literally.
+  segment-sum (``spgemm_gather_execute_chunk``).  Matches the paper's
+  element pipelines most literally.
 * ``block`` (MXU path)   — BSR bundles; device streams 128×128 tile dots
   driven by the inspector's schedule (Pallas kernel in kernels/bsr_spgemm.py,
-  jnp fallback here).
+  jnp fallback ``_block_execute_jnp`` here).
 
 Plans are pattern-pure (core.inspector); executors take the numeric values
 separately, so a cached plan serves any number of same-pattern calls
@@ -18,7 +20,6 @@ The numpy reference ``spgemm_ref_numpy`` doubles as the CPU-library baseline
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import numpy as np
@@ -65,30 +66,8 @@ def spgemm_ref_numpy(a: CSR, b: CSR) -> CSR:
 # Gather (VPU) executor
 # ---------------------------------------------------------------------------
 
-@persistent_jit(static_argnames=("c_nnz",))
-def _gather_execute(a_data, b_data, a_idx, b_idx, out_idx, c_nnz: int):
-    # trailing zero slot keeps padded (dead) gathers in bounds
-    a_data = jnp.concatenate([a_data, jnp.zeros(1, a_data.dtype)])
-    b_data = jnp.concatenate([b_data, jnp.zeros(1, b_data.dtype)])
-    pp = a_data[a_idx] * b_data[b_idx]          # multiply units
-    c = jax.ops.segment_sum(pp, out_idx, num_segments=c_nnz + 1,
-                            indices_are_sorted=True)  # merge units
-    return c[:c_nnz]
-
-
-def spgemm_gather_execute(plan: SpGemmGatherPlan, a_data: np.ndarray,
-                          b_data: np.ndarray) -> np.ndarray:
-    return np.asarray(_gather_execute(
-        jnp.asarray(a_data), jnp.asarray(b_data),
-        jnp.asarray(plan.a_idx), jnp.asarray(plan.b_idx),
-        # reaplint: disable=REAP004 plan-static shape: the sync path
-        # compiles once per cached plan; bucketing lives on the chunked
-        # path (_gather_execute_capped)
-        jnp.asarray(plan.out_idx), c_nnz=plan.c_nnz))
-
-
 def _gather_math(a_data, b_data, a_idx, b_idx, out_idx, c_cap: int):
-    """Capped gather→multiply→merge math, shared by the chunked executor
+    """Capped gather→multiply→merge math, shared by the gather executor
     and the sharded (shard_map) executor in ``runtime/shard.py`` — one
     definition keeps the two paths bit-for-bit interchangeable.
 
@@ -105,7 +84,7 @@ def _gather_math(a_data, b_data, a_idx, b_idx, out_idx, c_cap: int):
 
 @persistent_jit(static_argnames=("c_cap",))
 def _gather_execute_capped(a_data, b_data, a_idx, b_idx, out_idx, c_cap: int):
-    """Shape-bucketed gather executor for the chunked/overlapped runtime.
+    """The gather executor's device program, at bucketed shapes.
 
     ``c_cap`` is a power-of-two ≥ the chunk's c_nnz, and the index arrays
     are padded to power-of-two tile counts, so streaming many differently
@@ -127,7 +106,11 @@ def _put_gather_indices(host):
 
 def spgemm_gather_execute_chunk(plan: SpGemmGatherPlan, a_data: np.ndarray,
                                 b_data: np.ndarray) -> np.ndarray:
-    """Execute one chunk plan with bucketed shapes; returns (c_nnz,) values.
+    """Execute one gather plan with bucketed shapes; returns (c_nnz,) values.
+
+    The plan is a chunk's (``runtime.pipeline.spgemm_gather_chunked``) or
+    a whole matrix's; ``a_data``/``b_data`` are the CSR values it was built
+    for.
 
     The plan's index arrays, padded to the bucketed length, stay on the
     device (``plan.device_indices``: pattern-pure, built on the plan's
@@ -171,39 +154,6 @@ def _block_execute_jnp(a_blocks, b_blocks, a_id, b_id, out_id, n_out: int):
                                indices_are_sorted=True)
 
 
-def spgemm_block_execute(plan: SpGemmBlockPlan, a_data: np.ndarray,
-                         b_data: np.ndarray, use_pallas: bool = True
-                         ) -> np.ndarray:
-    """Returns the dense (n_out_blocks, block, block) output tiles.
-
-    ``a_data``/``b_data`` are the operands' CSR value arrays; the plan's
-    BsrPattern scatters them into MXU tiles (the per-call value pass).
-    """
-    if plan.n_pairs == 0:
-        return np.zeros((plan.n_out_blocks, plan.block, plan.block), np.float32)
-    a_blocks = plan.a_pat.scatter(a_data)
-    b_blocks = plan.b_pat.scatter(b_data)
-    if use_pallas:
-        # replay the emitted schedule bundle through the Pallas kernel —
-        # the single entry point runtime.api also uses
-        from repro.kernels import ops as kops
-        return np.asarray(kops.bsr_spgemm_schedule(
-            plan.schedule,
-            jnp.asarray(a_blocks, jnp.float32),
-            jnp.asarray(b_blocks, jnp.float32),
-            # reaplint: disable=REAP004 plan-static shape: one compile
-            # per cached plan; the chunked path buckets via
-            # bucket_block_schedule
-            n_out_blocks=plan.n_out_blocks))
-    return np.asarray(_block_execute_jnp(
-        jnp.asarray(a_blocks, jnp.float32),
-        jnp.asarray(b_blocks, jnp.float32),
-        jnp.asarray(plan.a_id), jnp.asarray(plan.b_id),
-        # reaplint: disable=REAP004 plan-static shape: one compile per
-        # cached plan (sync fallback path)
-        jnp.asarray(plan.out_id), n_out=plan.n_out_blocks))
-
-
 def block_result_to_dense(plan: SpGemmBlockPlan, c_blocks: np.ndarray
                           ) -> np.ndarray:
     bs = plan.block
@@ -223,16 +173,17 @@ def block_result_to_csr(plan: SpGemmBlockPlan, c_blocks: np.ndarray,
     zeros dropped.  Entries outside the structural pattern are exact zeros
     in every tile, so only the pattern's entries are read.
 
-    Pattern-pure, built once per plan (``plan.out_csr_index``, through the
-    synchronous executor unless the caller built it already): which tile
-    entries are read, in what order, and ``indptr``/``indices``, which every
-    result of the plan shares read-only.  Per call: the gather of the
+    Pattern-pure, built once per plan by the block pipeline
+    (``plan.out_csr_index``, which ``runtime.pipeline.spgemm_block_chunked``
+    fills on the plan's first product): which tile entries are read, in
+    what order, and ``indptr``/``indices``, which every result of the plan
+    shares read-only.  Per call: the gather of the
     values and a check for exact zeros.  Only zeros inside the pattern
     (cancellation, stored zeros in A or B) cost more: they are dropped,
     ``indices`` compacted, ``indptr`` recounted, and their number is
     counted as ``extract_zeros_dropped``.
     """
-    index = plan.out_csr_index(functools.partial(spgemm_block_execute, plan))
+    index = plan.out_csr_index(None)
     vals = c_blocks.reshape(-1)[index.sel]
     zeros = np.flatnonzero(vals == 0)
     spans.count("extract_zeros_dropped", zeros.size)
@@ -249,54 +200,29 @@ def block_result_to_csr(plan: SpGemmBlockPlan, c_blocks: np.ndarray,
 # ---------------------------------------------------------------------------
 
 def spgemm(a: CSR, b: CSR, method: str = "auto", block: int = 128,
-           use_pallas: bool = True, tile: int = 1024,
-           plan=None) -> Tuple[CSR, dict]:
-    """C = A @ B with the REAP split. Returns (C, stats).
+           use_pallas: bool = True, tile: int = 1024) -> Tuple[CSR, dict]:
+    """C = A @ B with the REAP split, planned and run once. Returns (C, stats).
 
-    stats records the inspector/executor time split (paper Fig 7).  This is
-    the plain synchronous path; runtime.api.ReapRuntime adds plan caching
-    and inspector/executor overlap on top of the same stages.
-
-    ``plan`` accepts a pre-built ``SpGemmGatherPlan`` or ``SpGemmBlockPlan``
-    (e.g. from ``runtime.PlanCache``): inspection is skipped, the executor
-    path is chosen by the plan's type, and ``method``/``block``/``tile`` are
-    ignored — the plan already fixed them.  This is the single planned-
-    execution entry point every layer (runtime, benchmarks, examples) shares.
+    One chunk through the route's pipelined executor
+    (``runtime.pipeline.spgemm_gather_chunked`` or ``spgemm_block_chunked``),
+    the same executor ``ReapRuntime.run("spgemm", ...)`` runs; the plan is
+    dropped with the call, so same-pattern calls belong on the runtime,
+    whose plan cache keeps it.  ``stats`` are the executor's: ``plan_s``
+    the plan build, ``inspect_s`` the emit stage (the plan build too on the
+    gather route), ``execute_s`` the device stage.
     """
-    inspect_s = 0.0
-    if plan is None:
-        if method == "auto":
-            method = choose_spgemm_path(a, b, block)
-        if method not in ("gather", "block"):
-            raise ValueError(f"unknown method {method!r}")
-        with spans.span("reap.inspect") as ins:
-            if method == "gather":
-                plan = inspect_spgemm_gather(a, b, tile)
-            else:
-                plan = inspect_spgemm_block(a, b, block)
-        inspect_s = ins.seconds
-
-    if isinstance(plan, SpGemmGatherPlan):
-        with spans.span("reap.execute") as ex:
-            c_data = spgemm_gather_execute(plan, a.data, b.data)
-        c = CSR(a.n_rows, b.n_cols, plan.c_indptr, plan.c_indices, c_data)
-        stats = dict(method="gather", inspect_s=inspect_s,
-                     execute_s=ex.seconds, flops=plan.flops(),
-                     n_pp=plan.n_pp)
-        return c, stats
-    if isinstance(plan, SpGemmBlockPlan):
-        with spans.span("reap.execute") as ex:
-            c_blocks = spgemm_block_execute(plan, a.data, b.data,
-                                            use_pallas=use_pallas)
-        with spans.span("reap.extract"):
-            plan.out_csr_index(functools.partial(
-                spgemm_block_execute, plan, use_pallas=use_pallas))
-            c = block_result_to_csr(plan, c_blocks, a.n_rows, b.n_cols)
-        stats = dict(method="block", inspect_s=inspect_s,
-                     execute_s=ex.seconds, flops=plan.flops(),
-                     n_pairs=plan.n_pairs, fill=plan.a_pat.fill)
-        return c, stats
-    raise TypeError(f"unsupported plan type {type(plan).__name__}")
+    from repro.runtime import pipeline
+    if method == "auto":
+        method = choose_spgemm_path(a, b, block)
+    if method == "gather":
+        c, stats, _ = pipeline.spgemm_gather_chunked(a, b, n_chunks=1,
+                                                     tile=tile)
+    elif method == "block":
+        c, stats, _ = pipeline.spgemm_block_chunked(
+            a, b, block=block, n_chunks=1, use_pallas=use_pallas)
+    else:
+        raise ValueError(f"unknown method {method!r}")
+    return c, stats
 
 
 # ---------------------------------------------------------------------------
@@ -343,6 +269,7 @@ def _fp_spgemm_gather(operands, cfg, *, chunked, digests=None, **kw):
     if chunked:
         return fingerprint_pattern("spgemm_gather_chunked", (a, b), digests,
                                    tile=cfg.tile, n_chunks=cfg.n_chunks)
+    # the sharded path's whole-matrix plan
     return fingerprint_pattern("spgemm_gather", (a, b), digests,
                                tile=cfg.tile)
 
@@ -352,19 +279,11 @@ def _inspect_spgemm_gather(operands, cfg, fp, **kw):
     return inspect_spgemm_gather(a, b, cfg.tile, fp)
 
 
-def _exec_spgemm_gather(plan, operands, cfg, *, overlap, **kw):
-    a, b = operands
-    c, stats = spgemm(a, b, plan=plan)
-    stats["overlap"] = False
-    return c, stats
-
-
-def _exec_spgemm_gather_chunked(cached, operands, cfg, *, overlap, **kw):
+def _exec_spgemm_gather_chunked(cached, operands, cfg, **kw):
     from repro.runtime.pipeline import spgemm_gather_chunked
     a, b = operands
     c, stats, chunkset = spgemm_gather_chunked(
-        a, b, n_chunks=cfg.n_chunks, tile=cfg.tile, overlap=overlap,
-        chunkset=cached)
+        a, b, n_chunks=cfg.n_chunks, tile=cfg.tile, chunkset=cached)
     return c, stats, chunkset
 
 
@@ -377,11 +296,8 @@ def _shard_spgemm_gather(cached, operands, cfg, *, mesh, **kw):
 def _fp_spgemm_block(operands, cfg, *, chunked, digests=None, **kw):
     a, b = operands
     digests = _spgemm_digests(a, b, digests)
-    if chunked:
-        return fingerprint_pattern("spgemm_block_chunked", (a, b), digests,
-                                   block=cfg.block, n_chunks=cfg.n_chunks)
-    return fingerprint_pattern("spgemm_block", (a, b), digests,
-                               block=cfg.block)
+    return fingerprint_pattern("spgemm_block_chunked", (a, b), digests,
+                               block=cfg.block, n_chunks=cfg.n_chunks)
 
 
 def _inspect_spgemm_block(operands, cfg, fp, **kw):
@@ -389,18 +305,11 @@ def _inspect_spgemm_block(operands, cfg, fp, **kw):
     return inspect_spgemm_block(a, b, cfg.block, fp)
 
 
-def _exec_spgemm_block(plan, operands, cfg, *, overlap, **kw):
-    a, b = operands
-    c, stats = spgemm(a, b, plan=plan, use_pallas=cfg.use_pallas)
-    stats["overlap"] = False
-    return c, stats
-
-
-def _exec_spgemm_block_chunked(cached, operands, cfg, *, overlap, **kw):
+def _exec_spgemm_block_chunked(cached, operands, cfg, **kw):
     from repro.runtime.pipeline import spgemm_block_chunked
     a, b = operands
     c, stats, chunkset = spgemm_block_chunked(
-        a, b, block=cfg.block, n_chunks=cfg.n_chunks, overlap=overlap,
+        a, b, block=cfg.block, n_chunks=cfg.n_chunks,
         use_pallas=cfg.use_pallas, chunkset=cached)
     return c, stats, chunkset
 
@@ -411,7 +320,6 @@ register_op(OpSpec(
     tag="spgemm_gather",
     fingerprint=_fp_spgemm_gather,
     inspect=_inspect_spgemm_gather,
-    execute_sync=_exec_spgemm_gather,
     execute_chunked=_exec_spgemm_gather_chunked,
     shard_plan=_shard_spgemm_gather,
     plan_types={"spgemm_gather": SpGemmGatherPlan},
@@ -424,9 +332,8 @@ register_op(OpSpec(
     tag="spgemm_block",
     fingerprint=_fp_spgemm_block,
     inspect=_inspect_spgemm_block,
-    execute_sync=_exec_spgemm_block,
     execute_chunked=_exec_spgemm_block_chunked,
     plan_types={"spgemm_block": SpGemmBlockPlan, "bsr_pattern": BsrPattern},
-    fingerprint_ops=("spgemm_block", "spgemm_block_chunked"),
+    fingerprint_ops=("spgemm_block_chunked",),
     allowed_kw=("digests",),
 ))

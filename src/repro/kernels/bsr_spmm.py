@@ -15,7 +15,7 @@ Two entry points:
 * ``inspect_spmm`` / ``SpmmPlan`` — the planned-op form for an already
   *sparse* CSR operand: ``Y = X @ W`` with W's sparsity pattern
   fingerprinted under the ``spmm`` op tag.  This op is admitted to the
-  plan cache, the overlap-era runtime, and the persistent store purely
+  plan cache, the runtime, and the persistent store purely
   through ``runtime.ops.register_op`` at the bottom of this file — no
   edits to ``runtime/{api,plan_cache,plan_store}.py`` — which is the
   registry's worked "admit your own op" example (docs/architecture.md).
@@ -327,14 +327,13 @@ def _inspect_spmm(operands, cfg, fp, **kw):
     return inspect_spmm(operands[1], cfg.block, fp)
 
 
-def _exec_spmm(plan, operands, cfg, *, overlap, dtype=np.float32, **kw):
+def _exec_spmm(plan, operands, cfg, *, dtype=np.float32, **kw):
     x, w = operands
     with spans.span("reap.execute") as ex:
         y = spmm_execute(plan, x, w.data, use_pallas=cfg.use_pallas,
                          dtype=dtype)
-    stats = dict(method="spmm", execute_s=ex.seconds, overlap=False,
-                 n_jobs=plan.n_jobs, fill=plan.pat.fill,
-                 flops=plan.flops(np.asarray(x).shape[0]))
+    stats = dict(method="spmm", execute_s=ex.seconds, n_jobs=plan.n_jobs,
+                 fill=plan.pat.fill, flops=plan.flops(np.asarray(x).shape[0]))
     return y, stats
 
 

@@ -20,7 +20,7 @@ Two schedule sources:
   ``bsr_pattern_from_csr`` (the same ``BsrPattern`` machinery the SpMM
   plan uses) turns the mask into a per-q-block list of visible kv block
   ids, fingerprinted under the ``block_attention`` op tag.  Admitted to
-  the plan cache / overlap runtime / persistent store purely through
+  the plan cache / runtime / persistent store purely through
   ``runtime.ops.register_op`` at the bottom of this file — the second
   worked example (after SpMM) that ``runtime/{api,plan_cache,
   plan_store}.py`` need zero edits per op.
@@ -455,15 +455,14 @@ def _inspect_block_attention(operands, cfg, fp, **kw):
     return inspect_block_attention(operands[3], cfg.block, fp)
 
 
-def _exec_block_attention(plan, operands, cfg, *, overlap, softcap=0.0,
-                          scale=None, **kw):
+def _exec_block_attention(plan, operands, cfg, *, softcap=0.0, scale=None,
+                          **kw):
     q, k, v = operands[0], operands[1], operands[2]
     with spans.span("reap.execute") as ex:
         o = block_attention_execute(plan, q, k, v,
                                     use_pallas=cfg.use_pallas,
                                     softcap=softcap, scale=scale)
     stats = dict(method="block_attention", execute_s=ex.seconds,
-                 overlap=False,
                  n_visible_blocks=plan.n_visible, nk_cap=plan.nk_cap,
                  flops=plan.flops(np.asarray(q).shape[0],
                                   np.asarray(q).shape[1],

@@ -1,4 +1,4 @@
-"""REAP runtime layer: op registry, plan caching, persistence, overlap.
+"""REAP runtime layer: op registry, plan caching, persistence, pipelines.
 
 ``ReapRuntime`` (api.py) is a generic dispatcher over the registered
 planned-op protocol (ops.py); plan_cache.py, plan_store.py and pipeline.py
@@ -6,8 +6,8 @@ are its mechanisms; elastic.py carries the fault-tolerance posture for the
 training/serving side of the repo.
 """
 from .api import (ReapRuntime, RunStats, RuntimeConfig,  # noqa: F401
-                  add_runtime_args, configure_default_runtime,
-                  default_runtime, set_default_runtime)
+                  add_runtime_args, default_runtime,
+                  set_default_runtime)
 from .exec_store import (ExecCache, ExecStore,  # noqa: F401
                          current_exec_cache, persistent_jit,
                          set_default_exec_cache, use_exec_cache)

@@ -1,25 +1,26 @@
 """ReapRuntime: a generic dispatcher over the registered planned-op protocol.
 
 Every sparse operation in this repo factors into the same stages — pattern
-fingerprint, plan build (cache miss only), bundle emit + execution, with
-host/device overlap when the schedule is chunkable.  The runtime no longer
-hand-writes that choreography once per op: each op is an ``OpSpec``
-registered in ``runtime.ops`` (next to its kernel), and
+fingerprint, plan build (cache miss only), bundle emit + execution.  Each
+op is an ``OpSpec`` registered in ``runtime.ops`` (next to its kernel), and
 
     result, stats = ReapRuntime().run(op_tag, *operands, **kw)
 
 drives *any* registered op through one fingerprint → cache-lookup →
-inspect → execute → stats path.  ``spgemm`` / ``cholesky`` /
-``moe_dispatch`` remain as thin back-compat wrappers over ``run(...)``;
-admitting a brand-new op (see ``kernels/bsr_spmm.py`` for SpMM) touches no
-code here.
+execute → stats path.  ``spgemm`` / ``cholesky`` / ``moe_dispatch`` remain
+as thin wrappers over ``run(...)``; admitting a brand-new op (see
+``kernels/bsr_spmm.py`` for SpMM) touches no code here.
+
+Each op has exactly one executor.  An op with an ``execute_chunked`` hook
+(both SpGEMM routes) always runs it, at any ``n_chunks``: its pipeline in
+``runtime.pipeline`` builds the chunk plans, and one chunk runs inline.
+Any other op builds its plan through ``inspect`` on a miss and runs
+``execute_sync`` (Cholesky's runs the level-group pipeline).  The one
+exception is the sharded path: with a mesh, shardable ops run their
+``shard_plan`` hook.
 
 Same pattern + different values ⇒ cache hit ⇒ the inspector cost from the
-paper's Fig 7 split drops out of the steady state entirely.  The runtime
-owns no executor of its own: specs hand cached plans to the same planned
-entry points the library exposes (``core.spgemm.spgemm(plan=...)``,
-``core.cholesky.cholesky(plan=...)``, ``runtime.pipeline``), so the
-"library" and "runtime" halves share one execute+stats path (see
+paper's Fig 7 split drops out of the steady state entirely (see
 docs/architecture.md "Op registry").
 """
 from __future__ import annotations
@@ -27,7 +28,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import threading
-import warnings
 from typing import Any, Dict, Iterator, Optional, Tuple
 
 import jax.numpy as jnp
@@ -71,7 +71,6 @@ class RuntimeConfig:
     """
 
     cache_entries: int = 64
-    overlap: bool = True
     n_chunks: int = 4
     tile: int = 1024
     block: int = 128
@@ -120,8 +119,6 @@ class RuntimeConfig:
         n_chunks = getattr(args, "n_chunks", None)
         if n_chunks is not None:
             kw["n_chunks"] = n_chunks
-        if getattr(args, "no_overlap", False):
-            kw["overlap"] = False
         if getattr(args, "no_pallas", False):
             kw["use_pallas"] = False
         kw.update(overrides)
@@ -175,10 +172,8 @@ def add_runtime_args(parser) -> None:
     g.add_argument("--cache-entries", type=int, default=None,
                    help="in-memory plan cache capacity")
     g.add_argument("--n-chunks", type=int, default=None,
-                   help="inspector/executor overlap chunk count "
-                        "(1 disables chunking)")
-    g.add_argument("--no-overlap", action="store_true",
-                   help="run chunked ops synchronously")
+                   help="chunk count of the pipelined executors "
+                        "(1 runs one chunk inline)")
     g.add_argument("--no-pallas", action="store_true",
                    help="force jnp fallback executors (no Pallas kernels)")
 
@@ -190,7 +185,7 @@ class RunStats:
     The declared fields mirror ``ops.RUNSTATS_FIELDS`` (reaplint REAP002
     rejects ad-hoc stats-key writes in the runtime that are not declared
     here).  Op executors still report their own measurements (``method``,
-    ``execute_s``, overlap counters, ...) — those ride in ``extra`` and
+    ``execute_s``, pipeline timings, ...) — those ride in ``extra`` and
     stay reachable through the dict-style interface, so pre-existing
     ``stats["method"]`` / ``stats.get("plan_s", 0.0)`` consumers are
     unaffected.  A None field means "not applicable to this run" (e.g.
@@ -355,16 +350,16 @@ class ReapRuntime:
 
     # -- Generic dispatch --------------------------------------------------
 
-    def run(self, op_tag: str, *operands, overlap: Optional[bool] = None,
-            mesh: Optional[object] = None,
+    def run(self, op_tag: str, *operands, mesh: Optional[object] = None,
             **kw) -> Tuple[object, "RunStats"]:
         """Execute a registered planned op through the cache/pipeline.
 
         Returns ``(result, stats)``; ``result`` is op-defined (the
         back-compat wrappers unpack it).  ``stats`` is a ``RunStats``
         (dict-compatible): always ``cache_hit``, ``fingerprint``, and the
-        call's ``spans``/``counters``; synchronous calls also get
-        ``inspect_s`` (the plan build of a miss, 0.0 on a hit); with an
+        call's ``spans``/``counters``; ops run through ``execute_sync``
+        also get ``inspect_s`` (the plan build of a miss, 0.0 on a hit);
+        pipelined ops report their plan build as ``plan_s``; with an
         exec store configured, ``exec_cache_hit`` reports whether
         execution needed zero new XLA compilations.
 
@@ -375,16 +370,15 @@ class ReapRuntime:
 
         The call is one run record (``runtime.spans``) with root span
         ``reap.run``: ``reap.acquire`` (route, prepare, fingerprint, cache
-        lookup), ``reap.inspect`` (plan build of a synchronous miss), then
-        the executor's own spans.
+        lookup), ``reap.inspect`` (plan build of a miss), then the
+        executor's own spans.
         """
         with spans.record("reap.run") as rec:
-            result, stats = self._run(rec, op_tag, operands, overlap, mesh,
-                                      kw)
+            result, stats = self._run(rec, op_tag, operands, mesh, kw)
         return result, dataclasses.replace(
             stats, spans=dict(rec.seconds), counters=dict(rec.counters))
 
-    def _run(self, rec, op_tag, operands, overlap, mesh, kw):
+    def _run(self, rec, op_tag, operands, mesh, kw):
         with spans.span("reap.acquire"):
             spec = _ops.get_op(op_tag)
             hops = 0
@@ -405,12 +399,10 @@ class ReapRuntime:
                         f"op {op_tag!r} got unexpected keyword arguments "
                         f"{sorted(unknown)}; accepts "
                         f"{sorted(spec.allowed_kw)}")
-            overlap = cfg.overlap if overlap is None else overlap
             mesh = mesh if mesh is not None else self._default_mesh()
             sharded = (mesh is not None and spec.shard_plan is not None
                        and spec.capabilities.shardable)
-            chunked = (not sharded and spec.execute_chunked is not None
-                       and cfg.n_chunks > 1)
+            chunked = not sharded and spec.execute_chunked is not None
             if spec.prepare is not None:    # derive once what fingerprint +
                 kw = spec.prepare(operands, cfg, **kw)  # inspect both need
             fp = spec.fingerprint(operands, cfg, chunked=chunked, **kw)
@@ -430,7 +422,7 @@ class ReapRuntime:
         with self._exec_scope() as exec_probe:
             if sharded or chunked:
                 hook = spec.shard_plan if sharded else spec.execute_chunked
-                extra = dict(mesh=mesh) if sharded else dict(overlap=overlap)
+                extra = dict(mesh=mesh) if sharded else {}
                 result, op_stats, artifact = hook(cached, operands, cfg,
                                                   **extra, **kw)
                 if cached is None and artifact is not None:
@@ -447,7 +439,7 @@ class ReapRuntime:
                         self.cache.put(fp, cached)
                     inspect_s = ins.seconds
                 result, op_stats = spec.execute_sync(cached, operands, cfg,
-                                                     overlap=overlap, **kw)
+                                                     **kw)
         return result, RunStats(
             cache_hit=hit,
             store_hit=source == "store",
@@ -466,20 +458,17 @@ class ReapRuntime:
             rec["hits" if source == "memory"
                 else "store_hits" if source == "store" else "misses"] += 1
 
-    # -- Back-compat wrappers (thin adapters over run) ---------------------
+    # -- Wrappers (thin adapters over run) ---------------------------------
 
-    def spgemm(self, a, b, method: str = "auto",
-               overlap: Optional[bool] = None) -> Tuple[object, dict]:
-        """C = A @ B through the plan cache, overlapped when chunkable."""
-        return self.run("spgemm", a, b, method=method, overlap=overlap)
+    def spgemm(self, a, b, method: str = "auto") -> Tuple[object, dict]:
+        """C = A @ B through the plan cache and the route's pipeline."""
+        return self.run("spgemm", a, b, method=method)
 
-    def cholesky(self, a, dtype=jnp.float64,
-                 overlap: Optional[bool] = None):
+    def cholesky(self, a, dtype=jnp.float64):
         """A = L Lᵀ through the plan cache; level-bundle emission overlaps
         device execution (the etree schedule is the chunk stream).
         Returns (plan, L values, stats)."""
-        (plan, vals), stats = self.run("cholesky", a, dtype=dtype,
-                                       overlap=overlap)
+        (plan, vals), stats = self.run("cholesky", a, dtype=dtype)
         return plan, vals, stats
 
     def moe_dispatch(self, tokens: np.ndarray, expert_ids: np.ndarray,
@@ -556,15 +545,3 @@ def set_default_runtime(rt: Optional[ReapRuntime]) -> Optional[ReapRuntime]:
     from .exec_store import set_default_exec_cache
     set_default_exec_cache(None if rt is None else rt.exec)
     return rt
-
-
-def configure_default_runtime(config: Optional[RuntimeConfig] = None,
-                              **overrides) -> ReapRuntime:
-    """Deprecated: build via ``RuntimeConfig`` (or ``from_args``) and
-    install with ``set_default_runtime`` instead."""
-    warnings.warn(
-        "configure_default_runtime is deprecated; build a RuntimeConfig "
-        "(RuntimeConfig.from_args for CLI entry points) and install it "
-        "with set_default_runtime(ReapRuntime(cfg))",
-        DeprecationWarning, stacklevel=2)
-    return set_default_runtime(ReapRuntime(config, **overrides))

@@ -9,7 +9,7 @@ process-wide registry lets every generic layer (`ReapRuntime.run`, the
 plan cache's serializer, the persistent store, `serve.py`, benchmarks)
 enumerate ops instead of hard-coding tag lists.
 
-Admitting a new op to the whole stack — plan cache, overlap pipeline,
+Admitting a new op to the whole stack — plan cache, pipelined executor,
 persistent store, serve warm-restart, benchmark breakdown — is one
 :func:`register_op` call next to the kernel (see ``kernels/bsr_spmm.py``
 for the worked example, and docs/architecture.md "Op registry").
@@ -30,7 +30,8 @@ __all__ = [
     "register_op", "unregister_op", "get_op", "list_ops",
     "register_plan_type", "plan_type", "plan_type_name",
     "serializer_for", "deserializer_for",
-    "REQUIRED_HOOKS", "ROUTER_HOOK", "EXECUTOR_HOOKS", "INSPECTOR_HOOKS",
+    "REQUIRED_HOOKS", "SINGLE_EXECUTOR_HOOKS", "ROUTER_HOOK",
+    "EXECUTOR_HOOKS", "INSPECTOR_HOOKS",
     "SERIALIZER_HOOKS", "VALUE_ATTRS", "PATTERN_ATTRS",
     "CAPABILITY_ROUTINGS",
 ]
@@ -41,7 +42,9 @@ __all__ = [
 # rule REAP002) so the enforced contract and the linted contract cannot
 # drift apart.  ``repro.analysis`` loads this module standalone via its
 # file path, so ops.py must keep importing nothing beyond the stdlib.
-REQUIRED_HOOKS: Tuple[str, ...] = ("fingerprint", "inspect", "execute_sync")
+REQUIRED_HOOKS: Tuple[str, ...] = ("fingerprint", "inspect")
+# a non-router op registers exactly one of these: the executor that runs it
+SINGLE_EXECUTOR_HOOKS: Tuple[str, ...] = ("execute_sync", "execute_chunked")
 ROUTER_HOOK: str = "route"
 EXECUTOR_HOOKS: Tuple[str, ...] = ("execute_sync", "execute_chunked",
                                    "shard_plan")
@@ -135,27 +138,35 @@ class OpSpec:
     ``fingerprint(operands, cfg, *, chunked, **kw)``
         Stage-1 inspection: digest the operand *patterns* (never values)
         plus every plan-shaping parameter into a ``PatternFingerprint``.
-        ``chunked`` tells the spec whether the chunked executor will run,
-        so it can key chunked plans separately (chunk count shapes them).
+        ``chunked`` tells the spec whether ``execute_chunked`` will run
+        (false on the sharded path), so it can key chunked plans
+        separately (chunk count shapes them).
 
     ``inspect(operands, cfg, fp, **kw)``
-        Stage-2 plan-build on a cache miss: a *pure* plan (pattern-derived
-        index arrays only — the purity is what makes plans cacheable and
-        persistable).  ``fp`` is the fingerprint to stamp on the plan.
+        Stage-2 plan-build: a *pure* plan (pattern-derived index arrays
+        only — the purity is what makes plans cacheable and persistable).
+        ``fp`` is the fingerprint to stamp on the plan.  The runtime calls
+        it on a miss before ``execute_sync``; the purity harness
+        (``analysis/purity_check.py``) drives it for every op.
 
-    ``execute_sync(plan, operands, cfg, *, overlap, **kw)``
-        Stage-3+4 for the synchronous path: bundle-emit + execute.
-        Returns ``(result, stats)``; ``result`` is op-defined (may be a
-        tuple), ``stats`` a flat dict.  The dispatcher adds ``cache_hit``,
-        ``inspect_s`` and ``fingerprint`` afterwards.
+    Every non-router op registers exactly one executor hook, the only way
+    the runtime runs it off a mesh:
 
-    ``execute_chunked(cached, operands, cfg, *, overlap, **kw)`` (optional)
-        Overlapped path, used when the runtime's ``n_chunks > 1``.
-        ``cached`` is the warm plan artifact or ``None``; returns
-        ``(result, stats, artifact)`` where ``artifact`` is admitted to the
-        cache on a cold call (chunked executors build their chunk sets
-        lazily *inside* the pipeline so cold inspection overlaps device
-        execution — that is why build is not forced through ``inspect``).
+    ``execute_sync(plan, operands, cfg, **kw)``
+        The runtime builds the plan (``inspect``, on a miss), then this
+        emits bundles and executes.  Returns ``(result, stats)``;
+        ``result`` is op-defined (may be a tuple), ``stats`` a flat dict.
+        The dispatcher adds ``cache_hit``, ``inspect_s`` and
+        ``fingerprint`` afterwards.
+
+    ``execute_chunked(cached, operands, cfg, **kw)``
+        The pipelined executor builds its own chunk plans, at any
+        ``n_chunks`` (one chunk runs inline).  ``cached`` is the warm plan
+        artifact or ``None``; returns ``(result, stats, artifact)`` where
+        ``artifact`` is admitted to the cache on a cold call (chunk sets
+        are built lazily *inside* the pipeline so cold inspection overlaps
+        device execution — that is why build is not forced through
+        ``inspect``).
 
     ``shard_plan(cached, operands, cfg, *, mesh, **kw)`` (optional)
         Sharded path, used when ``ReapRuntime.run`` receives a ``mesh``
@@ -235,6 +246,13 @@ class OpSpec:
                     f"{'+'.join(REQUIRED_HOOKS)} (missing: "
                     f"{', '.join(missing)}), or be a pure router "
                     f"({ROUTER_HOOK}=...)")
+            executors = [h for h in SINGLE_EXECUTOR_HOOKS
+                         if getattr(self, h) is not None]
+            if len(executors) != 1:
+                raise ValueError(
+                    f"op {self.tag!r} must define exactly one of "
+                    f"{'/'.join(SINGLE_EXECUTOR_HOOKS)} (got "
+                    f"{', '.join(executors) or 'none'})")
         if (self.shard_plan is not None) != self.capabilities.shardable:
             raise ValueError(
                 f"op {self.tag!r}: shard_plan hook and "

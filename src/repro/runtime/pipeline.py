@@ -1,19 +1,22 @@
-"""Overlapped inspector/executor pipeline (the paper's CPU/FPGA overlap).
+"""Pipelined inspector/executor: the one executor of each chunked op.
 
 REAP's input controller keeps the FPGA pipelines busy while the CPU keeps
 producing RIR bundles; here the same overlap is software: the schedule-bundle
 stream is chunked, and while the device executes chunk *k* a worker thread
-inspects chunk *k+1* (double-buffering).  Two concrete pipelines:
+emits chunk *k+1* (double-buffering).  Three pipelines, each the only
+executor of its op at any chunk count (one chunk runs inline):
 
   * ``spgemm_gather_chunked`` — A's rows are partitioned into nnz-balanced
     chunks; each chunk is an independent Gustavson sub-problem whose output
     rows are disjoint, so results concatenate exactly.
+  * ``spgemm_block_chunked`` — the block plan's pair schedule is cut at
+    output-group starts; each chunk's operand tiles are scattered on the
+    worker while the device runs the previous chunk's tile dots.
   * ``cholesky_execute_overlapped`` — the etree level schedule is the chunk
-    stream: the padded cmod/cdiv index bundles of level ℓ+1 are emitted on
-    the worker thread while the device runs level ℓ.
+    stream: the padded cmod/cdiv index bundles of level group g+1 are
+    emitted on the worker thread while the device runs group g.
 
-``run_overlapped`` is the shared engine; ``overlap=False`` runs the same
-chunked schedule synchronously (the baseline the benchmarks compare against).
+``run_overlapped`` is the shared engine.
 """
 from __future__ import annotations
 
@@ -43,15 +46,20 @@ class OverlapStats:
     """Timing split of one pipelined run.
 
     ``inspect_s``/``execute_s`` are summed per-chunk stage times;
-    ``wall_s`` is end-to-end.  With overlap on, wall_s < inspect_s +
-    execute_s measures how much host work the device time hid.
+    ``wall_s`` is end-to-end.  With more than one chunk the emit stage runs
+    on the worker, and wall_s < inspect_s + execute_s measures how much
+    host work the device time hid.
     """
 
     n_chunks: int
-    overlap: bool
     inspect_s: float
     execute_s: float
     wall_s: float
+
+    @property
+    def overlap(self) -> bool:
+        """Whether the emit stage ran on the worker (more than one chunk)."""
+        return self.n_chunks > 1
 
     @property
     def hidden_s(self) -> float:
@@ -82,14 +90,14 @@ def _emit_pool() -> ThreadPoolExecutor:
 def run_overlapped(n_chunks: int,
                    inspect_fn: Callable[[int], object],
                    execute_fn: Callable[[int, object], object],
-                   overlap: bool = True,
                    execute_span: str = "reap.execute"
                    ) -> Tuple[List[object], OverlapStats]:
     """Double-buffered inspector/executor driver.
 
     ``inspect_fn(k)`` must be independent of execution results (pure host
     pattern work); ``execute_fn(k, artifact)`` may carry sequential state.
-    While chunk *k* executes, chunk *k+1* is inspected on a worker thread.
+    While chunk *k* executes, chunk *k+1* is inspected on a worker thread;
+    a single chunk runs inline.
 
     Spans: ``reap.pipeline`` (the whole call: ``wall_s``), ``reap.emit``
     (each ``inspect_fn`` call, on the worker when overlapped, counted into
@@ -113,7 +121,7 @@ def run_overlapped(n_chunks: int,
         return ex.seconds
 
     with spans.span("reap.pipeline") as wall:
-        if not overlap or n_chunks <= 1:
+        if n_chunks <= 1:
             for k in range(n_chunks):
                 art, dt = timed_inspect(k)
                 inspect_s += dt
@@ -139,8 +147,7 @@ def run_overlapped(n_chunks: int,
                     fut.exception()
                 except BaseException:   # CancelledError is a BaseException
                     pass
-    stats = OverlapStats(n_chunks, overlap and n_chunks > 1, inspect_s,
-                         execute_s, wall.seconds)
+    stats = OverlapStats(n_chunks, inspect_s, execute_s, wall.seconds)
     return results, stats
 
 
@@ -179,7 +186,7 @@ class GatherChunkSet:
 
 
 def spgemm_gather_chunked(a: CSR, b: CSR, n_chunks: int = 4,
-                          tile: int = 1024, overlap: bool = True,
+                          tile: int = 1024,
                           chunkset: Optional[GatherChunkSet] = None
                           ) -> Tuple[CSR, dict, GatherChunkSet]:
     """C = A @ B, chunked over A's rows with inspect/execute overlap.
@@ -214,7 +221,7 @@ def spgemm_gather_chunked(a: CSR, b: CSR, n_chunks: int = 4,
         s, e = int(a.indptr[bounds[k]]), int(a.indptr[bounds[k + 1]])
         return spgemm_gather_execute_chunk(plan, a.data[s:e], b.data)
 
-    chunks, ostats = run_overlapped(nk, inspect_fn, execute_fn, overlap)
+    chunks, ostats = run_overlapped(nk, inspect_fn, execute_fn)
 
     # stitch: chunk output rows are disjoint, contiguous, and ordered
     with spans.span("reap.extract"):
@@ -413,45 +420,30 @@ def build_block_chunkset(plan: SpGemmBlockPlan, n_chunks: int,
     return chunkset
 
 
-def spgemm_block_chunked(a: CSR, b: CSR, block: int = 128, n_chunks: int = 4,
-                         overlap: bool = True, use_pallas: bool = True,
-                         chunkset: Optional[BlockChunkSet] = None
-                         ) -> Tuple[CSR, dict, BlockChunkSet]:
-    """C = A @ B on the MXU path with per-chunk emit/execute overlap.
-
-    The bundle-emit stage per chunk — scattering the chunk's operand CSR
-    values into compact MXU tiles — runs on the worker thread while the
-    device executes the previous chunk's tile dots (the gather path's
-    pipeline, applied to the block executor).  Returns (C, stats, chunkset)
-    so callers can cache the chunk set; a warm chunkset skips plan-build
-    entirely and the pipeline is scatter+execute only.
-    """
-    plan_s = 0.0
-    if chunkset is None:
-        with spans.span("reap.inspect") as ins:
-            plan = inspect_spgemm_block(a, b, block)
-            # bounds only: chunk slices materialize inside the emit stage,
-            # one chunk ahead of the device (hidden under execution when
-            # overlapped)
-            chunkset = build_block_chunkset(plan, n_chunks, lazy=True)
-        plan_s = ins.seconds
-    plan = chunkset.plan
-
-    base = dict(method="block_chunked", n_chunks=chunkset.n_chunks,
-                plan_s=plan_s, flops=plan.flops(), n_pairs=plan.n_pairs,
-                fill=plan.a_pat.fill)
-    if not chunkset.chunks:
-        zero = np.zeros((plan.n_out_blocks, plan.block, plan.block),
-                        np.float32)
-        c = block_result_to_csr(plan, zero, a.n_rows, b.n_cols)
-        base.update(overlap=False, inspect_s=0.0, execute_s=0.0,
-                    wall_s=plan_s, hidden_s=0.0)
-        return c, base, chunkset
-
-    bs = plan.block
+def _run_block_chunks(chunkset: BlockChunkSet, a_data: np.ndarray,
+                      b_data: np.ndarray, use_pallas: bool
+                      ) -> Tuple[List[np.ndarray], OverlapStats]:
+    """Each chunk of a block chunk set through the emit stage (the host
+    scatter into bucketed tiles, on the worker) and the execute stage
+    (transfer, launch, fetch); returns each chunk's output tiles and the
+    pipeline's stats."""
+    bs = chunkset.plan.block
     # the schedule arrays each executor takes (int32 already: bucketed)
     sched_keys = (("a_id", "b_id", "out_id", "is_first", "is_last")
                   if use_pallas else ("a_id", "b_id", "out_id"))
+
+    def emit_fn(k: int):
+        # host-side *emit* stage (not inspection — it scatters operand
+        # values into RIR tiles, so it must not carry an inspect_* name):
+        # pow-2-bucketed tile arrays (bucket_block_schedule) keep the
+        # executor at O(log) distinct shapes across a chunk stream
+        ch = chunkset.chunk(k)
+        sched = bucket_block_schedule(ch)
+        a_blocks = np.zeros((sched["a_cap"], bs, bs), np.float32)
+        a_blocks[ch.a_eblk, ch.a_erow, ch.a_ecol] = a_data[ch.a_sel]
+        b_blocks = np.zeros((sched["b_cap"], bs, bs), np.float32)
+        b_blocks[ch.b_eblk, ch.b_erow, ch.b_ecol] = b_data[ch.b_sel]
+        return ch, sched, a_blocks, b_blocks
 
     def execute_fn(k: int, emitted) -> np.ndarray:
         ch, sched, a_blocks, b_blocks = emitted
@@ -472,30 +464,70 @@ def spgemm_block_chunked(a: CSR, b: CSR, block: int = 128, n_chunks: int = 4,
                                          n_out=n_out_cap)
         return spans.to_host(out)[:ch.n_out_blocks]
 
-    def run_chunks(a_data: np.ndarray, b_data: np.ndarray):
-        def emit_fn(k: int):
-            # host-side *emit* stage (not inspection — it scatters operand
-            # values into RIR tiles, so it must not carry an inspect_*
-            # name): pow-2-bucketed tile arrays (bucket_block_schedule) keep
-            # the executor at O(log) distinct shapes across a chunk stream
-            ch = chunkset.chunk(k)
-            sched = bucket_block_schedule(ch)
-            a_blocks = np.zeros((sched["a_cap"], bs, bs), np.float32)
-            a_blocks[ch.a_eblk, ch.a_erow, ch.a_ecol] = a_data[ch.a_sel]
-            b_blocks = np.zeros((sched["b_cap"], bs, bs), np.float32)
-            b_blocks[ch.b_eblk, ch.b_erow, ch.b_ecol] = b_data[ch.b_sel]
-            return ch, sched, a_blocks, b_blocks
+    return run_overlapped(chunkset.n_chunks, emit_fn, execute_fn)
 
-        return run_overlapped(chunkset.n_chunks, emit_fn, execute_fn,
-                              overlap)
+
+def block_chunk_tiles(chunkset: BlockChunkSet, a_data: np.ndarray,
+                      b_data: np.ndarray, use_pallas: bool = True
+                      ) -> np.ndarray:
+    """The (n_out_blocks, block, block) output tiles of a block chunk set
+    for CSR value arrays of A and B, through the pipeline's stages (zero
+    tiles for an empty schedule)."""
+    plan = chunkset.plan
+    if not chunkset.chunks:
+        return np.zeros((plan.n_out_blocks, plan.block, plan.block),
+                        np.float32)
+    tiles, _ = _run_block_chunks(chunkset, a_data, b_data, use_pallas)
+    return np.concatenate(tiles, axis=0)
+
+
+def spgemm_block_chunked(a: CSR, b: CSR, block: int = 128, n_chunks: int = 4,
+                         use_pallas: bool = True,
+                         chunkset: Optional[BlockChunkSet] = None
+                         ) -> Tuple[CSR, dict, BlockChunkSet]:
+    """C = A @ B on the MXU path with per-chunk emit/execute overlap.
+
+    The bundle-emit stage per chunk — scattering the chunk's operand CSR
+    values into compact MXU tiles — runs on the worker thread while the
+    device executes the previous chunk's tile dots (the gather path's
+    pipeline, applied to the block executor).  Returns (C, stats, chunkset)
+    so callers can cache the chunk set; a warm chunkset skips plan-build
+    entirely and the pipeline is scatter+execute only.
+
+    The output tiles go to CSR through ``block_result_to_csr``, whose
+    extraction index (``plan.out_csr_index``) this pipeline builds on the
+    plan's first product by running its own chunk programs on indicator
+    values.
+    """
+    plan_s = 0.0
+    if chunkset is None:
+        with spans.span("reap.inspect") as ins:
+            plan = inspect_spgemm_block(a, b, block)
+            # bounds only: chunk slices materialize inside the emit stage,
+            # one chunk ahead of the device (hidden under execution when
+            # overlapped)
+            chunkset = build_block_chunkset(plan, n_chunks, lazy=True)
+        plan_s = ins.seconds
+    plan = chunkset.plan
 
     def indicator_tiles(a_data: np.ndarray, b_data: np.ndarray):
         # the plan's extraction index, on a miss: the same chunk programs
         # (no compile), kept out of this product's run record
         with spans.bind(None):
-            return np.concatenate(run_chunks(a_data, b_data)[0], axis=0)
+            return block_chunk_tiles(chunkset, a_data, b_data, use_pallas)
 
-    results, ostats = run_chunks(a.data, b.data)
+    base = dict(method="block_chunked", n_chunks=chunkset.n_chunks,
+                plan_s=plan_s, flops=plan.flops(), n_pairs=plan.n_pairs,
+                fill=plan.a_pat.fill)
+    if not chunkset.chunks:
+        zero = block_chunk_tiles(chunkset, a.data, b.data)
+        plan.out_csr_index(indicator_tiles)
+        c = block_result_to_csr(plan, zero, a.n_rows, b.n_cols)
+        base.update(overlap=False, inspect_s=0.0, execute_s=0.0,
+                    wall_s=plan_s, hidden_s=0.0)
+        return c, base, chunkset
+
+    results, ostats = _run_block_chunks(chunkset, a.data, b.data, use_pallas)
     with spans.span("reap.extract"):
         c_blocks = np.concatenate(results, axis=0)
         plan.out_csr_index(indicator_tiles)
@@ -530,8 +562,7 @@ def _level_groups(plan: CholeskyPlan, max_chunks: int) -> List[np.ndarray]:
 
 
 def cholesky_execute_overlapped(plan: CholeskyPlan, a_vals: np.ndarray,
-                                dtype=jnp.float64, overlap: bool = True,
-                                max_chunks: int = 16
+                                dtype=jnp.float64, max_chunks: int = 16
                                 ) -> Tuple[np.ndarray, dict]:
     """Numeric phase with bundle emission one level-group ahead.
 
@@ -539,7 +570,9 @@ def cholesky_execute_overlapped(plan: CholeskyPlan, a_vals: np.ndarray,
     on numeric results, so emission overlaps the device's level-ℓ step.
     Levels are batched into ≤ ``max_chunks`` work-balanced groups so the
     per-handoff thread overhead is amortized (etree schedules routinely have
-    hundreds of tiny levels).
+    hundreds of tiny levels).  ``a_vals`` are A's lower-triangle values in
+    the plan's order (``plan.a_values(a)`` or ``cholesky_values(a)``);
+    returns (L values in CSC order, stats).
     """
     with spans.span("reap.values"):
         state = [init_values(plan, a_vals, dtype)]
@@ -561,13 +594,13 @@ def cholesky_execute_overlapped(plan: CholeskyPlan, a_vals: np.ndarray,
         spans.count("h2d_puts", sum(len(b) for b in bundles))
         spans.count("h2d_bytes", nbytes)
 
-    _, ostats = run_overlapped(len(groups), inspect_fn, execute_fn, overlap,
+    _, ostats = run_overlapped(len(groups), inspect_fn, execute_fn,
                                execute_span="reap.dispatch")
     vals = state[0]
     with spans.span("reap.drain") as drain:
         # reaplint: disable=REAP003 deliberate drain: queued device work is
-        # blocked on inside the timed region so the stats stay comparable
-        # with the sync path (which blocks before stamping)
+        # blocked on inside the timed region so execute_s measures device
+        # completion, not dispatch
         vals.block_until_ready()
     execute_s = ostats.execute_s + drain.seconds
     wall_s = ostats.wall_s + drain.seconds

@@ -23,7 +23,6 @@ import sys
 from pathlib import Path
 
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -344,12 +343,12 @@ class TestRunStatsAndFromArgs:
             ["--plan-store", "/p", "--plan-store-budget-mb", "2",
              "--exec-store", "/e", "--exec-store-budget-mb", "3",
              "--cache-entries", "9", "--n-chunks", "2",
-             "--no-overlap", "--no-pallas"]))
+             "--no-pallas"]))
         assert cfg.store_dir == "/p" and cfg.store_budget_bytes == 2_000_000
         assert cfg.exec_store_dir == "/e"
         assert cfg.exec_budget_bytes == 3_000_000
         assert cfg.cache_entries == 9 and cfg.n_chunks == 2
-        assert cfg.overlap is False and cfg.use_pallas is False
+        assert cfg.use_pallas is False
 
     def test_overrides_win_last(self):
         cfg = RuntimeConfig.from_args(self._args(["--n-chunks", "2"]),
@@ -361,15 +360,16 @@ class TestRunStatsAndFromArgs:
         cfg = RuntimeConfig.from_args(argparse.Namespace())
         assert cfg == RuntimeConfig()
 
-    def test_configure_default_runtime_deprecated(self):
+    def test_set_default_runtime_installs_and_clears(self):
         from repro.runtime import api
-        with pytest.warns(DeprecationWarning):
-            rt = api.configure_default_runtime(
-                RuntimeConfig(overlap=False))
+        rt = api.set_default_runtime(
+            api.ReapRuntime(RuntimeConfig(n_chunks=1)))
         assert api.default_runtime() is rt
-        assert rt.config.overlap is False
+        assert rt.config.n_chunks == 1
         api.set_default_runtime(None)
         assert api.default_runtime() is not rt     # cleared → fresh lazy
+        api.set_default_runtime(None)
+
 
 
 class TestRuntimeIntegration:
@@ -380,7 +380,6 @@ class TestRuntimeIntegration:
         a = random_csr(96, 96, 0.05, rng, "blocky")
         b = random_csr(96, 96, 0.05, rng, "blocky")
         cfg = RuntimeConfig(use_pallas=False, block=32, n_chunks=1,
-                            overlap=False,
                             exec_store_dir=str(tmp_path / "exec"))
         rt = ReapRuntime(cfg)
         _, st1 = rt.spgemm(a, b, method="gather")
@@ -389,7 +388,7 @@ class TestRuntimeIntegration:
         assert st2["exec_cache_hit"] is True            # fully warm
         # a runtime with no exec store reports None (absent from mapping)
         rt2 = ReapRuntime(RuntimeConfig(use_pallas=False, block=32,
-                                        n_chunks=1, overlap=False))
+                                        n_chunks=1))
         _, st3 = rt2.spgemm(a, b, method="gather")
         assert st3.exec_cache_hit is None
         assert "exec_cache_hit" not in st3
@@ -401,7 +400,6 @@ class TestRuntimeIntegration:
         a = random_csr(96, 96, 0.05, rng, "blocky")
         b = random_csr(96, 96, 0.05, rng, "blocky")
         base = RuntimeConfig(use_pallas=False, block=32, n_chunks=1,
-                             overlap=False,
                              exec_store_dir=str(tmp_path / "exec"))
         c1, _ = ReapRuntime(base).spgemm(a, b, method="gather")
         rt2 = ReapRuntime(base)                         # fresh caches

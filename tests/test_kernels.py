@@ -319,7 +319,7 @@ class TestBlockAttention:
         from repro.kernels.flash_attention import block_attention_ref
         from repro.runtime import ReapRuntime
         mask, q, k, v = self._problem(s=256)
-        rt = ReapRuntime(n_chunks=1, overlap=False, use_pallas=False,
+        rt = ReapRuntime(n_chunks=1, use_pallas=False,
                          block=64)
         o1, s1 = rt.run("block_attention", q, k, v, mask)
         o2, s2 = rt.run("block_attention", q, k, v, mask)
@@ -354,7 +354,7 @@ class TestPlannedSpmv:
         n = 300
         a = random_spd_csr(n, 0.02, rng)
         b = rng.standard_normal(n)
-        rt = ReapRuntime(n_chunks=1, overlap=False, use_pallas=False,
+        rt = ReapRuntime(n_chunks=1, use_pallas=False,
                          block=64)
         # float32 matvecs (x64 is off in the test process)
         x, info = cg_solve(a, b, rt, tol=1e-5, dtype=np.float32,

@@ -12,8 +12,8 @@ same battery, parametrized from the shared example table
 * **cache + store round-trip** — a second same-pattern call hits the
   in-memory cache; a *fresh runtime* sharing the store_dir answers from
   disk (per-op ``store_hits``) and computes the same result;
-* **chunked-vs-sync equivalence** — where ``execute_chunked`` exists,
-  the overlapped chunked path matches the synchronous one numerically;
+* **one chunk vs four** — where ``execute_chunked`` exists, the pipeline
+  at one chunk (run inline) matches it at four (overlapped) numerically;
 * **capability honesty** — declared capability metadata is well-formed
   and the derived ``chunked`` flag matches the registered hooks.
 """
@@ -49,7 +49,7 @@ def _example(tag):
 
 def _runtime(tag, **extra):
     ex = _example(tag)
-    kw = dict(n_chunks=1, overlap=False)
+    kw = dict(n_chunks=1)
     kw.update(ex.runtime_kw)
     kw.update(extra)
     return ReapRuntime(**kw)
@@ -104,7 +104,7 @@ def test_serialize_round_trip(tag):
     """plan → payload → plan → payload is bit-stable (store contract)."""
     spec = _ops.get_op(tag)
     ex = _example(tag)
-    cfg = RuntimeConfig(n_chunks=1, overlap=False, **ex.runtime_kw)
+    cfg = RuntimeConfig(n_chunks=1, **ex.runtime_kw)
     fp, payload0 = _plan_payload(spec, ex.operands(0), cfg, ex.kw)
     plan1 = _ops.deserializer_for(fp.op)(payload0)
     assert dataclasses.is_dataclass(plan1), type(plan1)
@@ -207,7 +207,7 @@ def test_sharded_store_round_trip(tag, tmp_path):
 def test_chunked_vs_sync_equivalence(tag):
     ex = _example(tag)
     sync_rt = _runtime(tag, n_chunks=1)
-    chunk_rt = _runtime(tag, n_chunks=4, overlap=True)
+    chunk_rt = _runtime(tag, n_chunks=4)
     r_sync, s_sync = sync_rt.run(tag, *ex.operands(3), **ex.kw)
     r_chunk, s_chunk = chunk_rt.run(tag, *ex.operands(3), **ex.kw)
     a_sync, a_chunk = _arrays(r_sync), _arrays(r_chunk)

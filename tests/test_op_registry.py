@@ -68,7 +68,7 @@ class TestRegistry:
         def inspect_hook(operands, cfg, fp, **kw):
             return {"fp": fp}
 
-        def exec_hook(plan, operands, cfg, *, overlap, **kw):
+        def exec_hook(plan, operands, cfg, **kw):
             return operands[0].nnz, dict(method="test_noop")
 
         spec = OpSpec(tag="test_noop", fingerprint=fp_hook,
@@ -89,6 +89,14 @@ class TestRegistry:
     def test_incomplete_spec_rejected(self):
         with pytest.raises(ValueError, match="must define"):
             OpSpec(tag="broken", fingerprint=lambda *a, **k: None)
+
+    @pytest.mark.parametrize("executors", [(), ("execute_sync",
+                                                "execute_chunked")])
+    def test_spec_needs_exactly_one_executor(self, executors):
+        hook = lambda *a, **k: None                      # noqa: E731
+        with pytest.raises(ValueError, match="exactly one"):
+            OpSpec(tag="broken", fingerprint=hook, inspect=hook,
+                   **{name: hook for name in executors})
 
     def test_plan_type_name_collision_errors(self):
         class Impostor:

@@ -251,9 +251,9 @@ class TestSerialization:
         a = random_spd_csr(30, 0.1, np.random.default_rng(6))
         plan = inspect_cholesky(a)
         back = deserialize_plan(serialize_plan(plan))
-        from repro.core import cholesky_execute
-        v1, _ = cholesky_execute(plan, cholesky_values(a))
-        v2, _ = cholesky_execute(back, cholesky_values(a))
+        from repro.runtime import cholesky_execute_overlapped
+        v1, _ = cholesky_execute_overlapped(plan, cholesky_values(a))
+        v2, _ = cholesky_execute_overlapped(back, cholesky_values(a))
         np.testing.assert_array_equal(v1, v2)
 
 
@@ -273,7 +273,7 @@ class TestChunkSetSerialization:
         for p, q in zip(back.plans, chunkset.plans):
             np.testing.assert_array_equal(p.a_idx, q.a_idx)
             np.testing.assert_array_equal(p.out_idx, q.out_idx)
-        # the deserialized chunk set drives a warm overlapped run exactly
+        # the deserialized chunk set drives a warm pipelined run exactly
         c, stats, _ = spgemm_gather_chunked(a, b, n_chunks=3, chunkset=back)
         np.testing.assert_array_equal(c.to_dense(), c_ref.to_dense())
 

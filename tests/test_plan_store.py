@@ -10,10 +10,10 @@ import jax.numpy as jnp
 from repro.core import (CSR, cholesky_values, inspect_cholesky,
                         inspect_spgemm_block, inspect_spgemm_gather,
                         random_csr, random_spd_csr, spgemm_ref_numpy)
-from repro.core.cholesky import cholesky_execute
 from repro.core.inspector import (fingerprint_pattern, inspect_moe_dispatch,
                                   routing_csr)
 from repro.runtime import (PlanCache, PlanStore, ReapRuntime,
+                           cholesky_execute_overlapped,
                            fingerprint_from_json, fingerprint_to_json,
                            spgemm_block_chunked, spgemm_gather_chunked,
                            store_key)
@@ -77,8 +77,8 @@ class TestRoundTripPerOpTag:
         fp = fingerprint_pattern("cholesky", (a,))
         PlanStore(tmp_path).put(fp, plan)
         back = PlanStore(tmp_path).get(fp)
-        v1, _ = cholesky_execute(plan, cholesky_values(a))
-        v2, _ = cholesky_execute(back, cholesky_values(a))
+        v1, _ = cholesky_execute_overlapped(plan, cholesky_values(a))
+        v2, _ = cholesky_execute_overlapped(back, cholesky_values(a))
         np.testing.assert_array_equal(v1, v2)
 
     def test_moe_dispatch(self, tmp_path):
@@ -273,7 +273,7 @@ class TestCrossProcessLocking:
                 fingerprint_pattern("dict_plan_op", operands),
             inspect=lambda operands, cfg, fp, **kw:
                 {"ids": operands[0].indices.copy()},
-            execute_sync=lambda plan, operands, cfg, *, overlap, **kw:
+            execute_sync=lambda plan, operands, cfg, **kw:
                 (int(plan["ids"].sum()), dict(method="dict_plan_op")),
             serialize=ser, deserialize=deser)
         register_op(spec)

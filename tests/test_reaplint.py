@@ -61,6 +61,16 @@ class TestReap002Registry:
         msg = report.violations[0].message
         assert "inspect" in msg and "execute_sync" in msg
 
+    def test_two_executor_hooks_flagged(self):
+        src = (
+            "from repro.runtime.ops import OpSpec, register_op\n"
+            "register_op(OpSpec(tag='twice', fingerprint=f, inspect=g,\n"
+            "                   execute_sync=h, execute_chunked=k))\n")
+        report = check_source(src, "core/fixture.py")
+        assert [(d.code, d.line) for d in report.violations] == [
+            ("REAP002", 2)]
+        assert "exactly one executor" in report.violations[0].message
+
     def test_router_needs_no_other_hooks(self):
         src = (
             "from repro.runtime.ops import OpSpec, register_op\n"

@@ -111,7 +111,7 @@ class TestRuntimeSharedStore:
                 random_csr(160, 160, 0.04, rng))
 
     def _runtime(self, shared_root) -> ReapRuntime:
-        return ReapRuntime(RuntimeConfig(n_chunks=1, overlap=False,
+        return ReapRuntime(RuntimeConfig(n_chunks=1,
                                          shared_store_dir=str(shared_root)))
 
     def test_manifests_hold_blob_refs(self, tmp_path):
@@ -201,7 +201,7 @@ from repro.runtime.api import RuntimeConfig
 rng = np.random.default_rng(7)
 a = random_csr(160, 160, 0.04, rng)
 b = random_csr(160, 160, 0.04, rng)
-rt = ReapRuntime(RuntimeConfig(n_chunks=1, overlap=False,
+rt = ReapRuntime(RuntimeConfig(n_chunks=1,
                                shared_store_dir=sys.argv[1]))
 c, st = rt.spgemm(a, b, method="gather")
 cs = rt.cache_stats()

@@ -299,17 +299,42 @@ def test_gather_chunked_record_times_and_counts_each_stage():
 
 
 def test_sync_spgemm_execute_s_is_its_span():
+    # one chunk: execute_s is the sum of the pipeline's reap.execute spans
     rt = ReapRuntime(n_chunks=1, tile=64)
     a, b = _pair(9)
+    spans.clear()
     _, st = rt.run("spgemm", a, b, method="gather")
+    rec = spans.recent(1)[0]
+    assert st["method"] == "gather_chunked" and st["n_chunks"] == 1
+    assert rec.calls["reap.execute"] == 1
     assert st["execute_s"] == pytest.approx(st.spans["reap.execute"],
                                             rel=1e-12)
+
+
+@pytest.mark.parametrize("method", ["gather", "block"])
+def test_one_chunk_spgemm_runs_the_pipelined_executor(method):
+    rt = ReapRuntime(n_chunks=1, block=32, tile=64)
+    a, _ = _pair(13)
+    spans.clear()
+    rt.run("spgemm", a, a, method=method)                   # plan miss
+    rt.run("spgemm", a, a, method=method)
+    miss, warm = spans.recent(2)
+    for rec in (miss, warm):
+        assert rec.op == f"spgemm_{method}"
+        for name in ("reap.emit", "reap.h2d", "reap.launch", "reap.fetch",
+                     "reap.extract"):
+            assert rec.calls.get(name, 0) >= 1, name
+        assert rec.calls["reap.emit"] == rec.calls["reap.launch"] == 1
+    if method == "gather":
+        # the device index memo: built on the miss, looked up when warm
+        assert miss.counters["gather_index_builds"] == 1
+        assert warm.counters["gather_index_builds"] == 0
 
 
 def test_cg_solve_is_one_record_with_its_matvecs():
     a = random_spd_csr(64, 0.1, np.random.default_rng(11))
     b = np.random.default_rng(0).standard_normal(64)
-    rt = ReapRuntime(n_chunks=1, overlap=False)
+    rt = ReapRuntime(n_chunks=1)
     spans.clear()
     _, info = cg_solve(a, b, rt, tol=1e-6, dtype=np.float32)
     (rec,) = spans.recent(5)

@@ -5,9 +5,10 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core import (CSR, choose_spgemm_path, inspect_spgemm_block,
                         inspect_spgemm_gather, random_csr, spgemm,
-                        spgemm_block_execute, spgemm_gather_execute,
                         spgemm_gather_execute_chunk, spgemm_ref_numpy)
 from repro.core.spgemm import block_result_to_dense
+from repro.runtime import ReapRuntime
+from repro.runtime.pipeline import block_chunk_tiles, build_block_chunkset
 
 
 def _rand(n, m, density, seed=0, pattern="uniform"):
@@ -21,6 +22,18 @@ def _dense_oracle(a: CSR, b: CSR):
 def _with_values(a: CSR, data) -> CSR:
     return CSR(a.n_rows, a.n_cols, a.indptr, a.indices,
                np.asarray(data, np.float32))
+
+
+def _block_tiles(plan, a_data, b_data) -> np.ndarray:
+    """A block plan's output tiles from a one-chunk chunk set, through the
+    pipeline's emit and execute stages; builds the plan's extraction index
+    the way the pipeline does."""
+    chunkset = build_block_chunkset(plan, 1)
+
+    def tiles(a_vals, b_vals):
+        return block_chunk_tiles(chunkset, a_vals, b_vals, use_pallas=False)
+    plan.out_csr_index(tiles)
+    return tiles(a_data, b_data)
 
 
 def _case_banded():
@@ -74,7 +87,7 @@ class TestGatherPath:
     def test_matches_dense(self, n, k, m, density, seed):
         a, b = _rand(n, k, density, seed), _rand(k, m, density, seed + 100)
         plan = inspect_spgemm_gather(a, b)
-        c_data = spgemm_gather_execute(plan, a.data, b.data)
+        c_data = spgemm_gather_execute_chunk(plan, a.data, b.data)
         c = CSR(n, m, plan.c_indptr, plan.c_indices, c_data)
         np.testing.assert_allclose(c.to_dense(), _dense_oracle(a, b),
                                    rtol=1e-4, atol=1e-5)
@@ -84,7 +97,7 @@ class TestGatherPath:
         b = _rand(4, 4, 0.5)
         plan = inspect_spgemm_gather(a, b)
         assert plan.c_nnz == 0
-        c_data = spgemm_gather_execute(plan, a.data, b.data)
+        c_data = spgemm_gather_execute_chunk(plan, a.data, b.data)
         assert c_data.shape == (0,)
 
     def test_plan_partials_sorted(self):
@@ -106,8 +119,8 @@ class TestBlockPath:
         a = _rand(100, 80, 0.08, 7, pattern)
         b = _rand(80, 60, 0.08, 8, pattern)
         plan = inspect_spgemm_block(a, b, block)
-        c_blocks = spgemm_block_execute(plan, a.data, b.data, use_pallas=False)
-        dense = block_result_to_dense(plan, np.asarray(c_blocks))
+        c_blocks = _block_tiles(plan, a.data, b.data)
+        dense = block_result_to_dense(plan, c_blocks)
         np.testing.assert_allclose(dense[:100, :60], _dense_oracle(a, b),
                                    rtol=1e-4, atol=1e-4)
 
@@ -153,37 +166,37 @@ class TestPublicAPI:
 
 
 class TestPlannedExecution:
-    """spgemm(plan=...) — the unified planned entry point the runtime uses."""
+    """A cold call, then a warm call with fresh values, of the runtime's
+    planned path at one chunk: the plan cache replays the plan."""
 
     def test_gather_plan_reuse(self):
         a, b = _rand(80, 80, 0.08, 16), _rand(80, 80, 0.08, 17)
-        plan = inspect_spgemm_gather(a, b)
+        rt = ReapRuntime(n_chunks=1)
         c_plain, _ = spgemm(a, b, method="gather")
-        c_planned, stats = spgemm(a, b, plan=plan)
-        assert stats["method"] == "gather" and stats["inspect_s"] == 0.0
-        np.testing.assert_array_equal(c_planned.to_dense(),
-                                      c_plain.to_dense())
+        c_cold, cold = rt.run("spgemm", a, b, method="gather")
+        assert cold["method"] == "gather_chunked" and not cold["cache_hit"]
+        np.testing.assert_array_equal(c_cold.to_dense(), c_plain.to_dense())
         # same plan, fresh values (the cache-hit workload)
         rng = np.random.default_rng(18)
         a2 = CSR(a.n_rows, a.n_cols, a.indptr, a.indices,
                  rng.standard_normal(a.nnz).astype(np.float32))
-        c2, _ = spgemm(a2, b, plan=plan)
+        c2, warm = rt.run("spgemm", a2, b, method="gather")
+        assert warm["cache_hit"] and warm["plan_s"] == 0.0
         np.testing.assert_allclose(c2.to_dense(), _dense_oracle(a2, b),
                                    rtol=1e-4, atol=1e-5)
 
     def test_block_plan_reuse(self):
         a = _rand(96, 96, 0.08, 19, "blocky")
-        plan = inspect_spgemm_block(a, a, 32)
+        rt = ReapRuntime(n_chunks=1, block=32, use_pallas=False)
         c_plain, _ = spgemm(a, a, method="block", block=32, use_pallas=False)
-        c_planned, stats = spgemm(a, a, plan=plan, use_pallas=False)
-        assert stats["method"] == "block" and stats["inspect_s"] == 0.0
-        np.testing.assert_array_equal(c_planned.to_dense(),
-                                      c_plain.to_dense())
-
-    def test_bad_plan_type_raises(self):
-        a = _rand(20, 20, 0.2, 20)
-        with pytest.raises(TypeError):
-            spgemm(a, a, plan=object())
+        c_cold, cold = rt.run("spgemm", a, a, method="block")
+        assert cold["method"] == "block_chunked" and not cold["cache_hit"]
+        np.testing.assert_array_equal(c_cold.to_dense(), c_plain.to_dense())
+        a2 = _with_values(a, np.random.default_rng(20).standard_normal(a.nnz))
+        c2, warm = rt.run("spgemm", a2, a2, method="block")
+        assert warm["cache_hit"] and warm["plan_s"] == 0.0
+        np.testing.assert_allclose(c2.to_dense(), _dense_oracle(a2, a2),
+                                   rtol=1e-4, atol=1e-4)
 
     @pytest.mark.parametrize("case", sorted(_EXTRACTION_CASES))
     def test_block_csr_extraction_matches_dense_roundtrip(self, case):
@@ -191,8 +204,7 @@ class TestPlannedExecution:
         from repro.runtime import spans
         a, b, block, must_drop = _EXTRACTION_CASES[case]()
         plan = inspect_spgemm_block(a, b, block)
-        c_blocks = np.asarray(spgemm_block_execute(plan, a.data, b.data,
-                                                   use_pallas=False))
+        c_blocks = _block_tiles(plan, a.data, b.data)
         via_dense = CSR.from_dense(
             block_result_to_dense(plan, c_blocks)[:a.n_rows, :b.n_cols])
         with spans.record("reap.run") as rec:
@@ -218,8 +230,7 @@ class TestPlannedExecution:
                      vals(a.nnz).astype(np.float32))
             b2 = CSR(b.n_rows, b.n_cols, b.indptr, b.indices,
                      vals(b.nnz).astype(np.float32))
-            c_blocks = spgemm_block_execute(plan, a2.data, b2.data,
-                                            use_pallas=False)
+            c_blocks = _block_tiles(plan, a2.data, b2.data)
             outs.append(block_result_to_csr(plan, c_blocks, 96, 64))
             np.testing.assert_allclose(outs[-1].to_dense(),
                                        _dense_oracle(a2, b2),
@@ -236,13 +247,16 @@ class TestPlannedExecution:
         from repro.runtime import PlanStore
         a, b = _rand(70, 90, 0.08, 27, "banded"), _rand(90, 60, 0.08, 28)
         plan = inspect_spgemm_block(a, b, 16)
-        c_blocks = spgemm_block_execute(plan, a.data, b.data,
-                                        use_pallas=False)
+        c_blocks = _block_tiles(plan, a.data, b.data)
         before = block_result_to_csr(plan, c_blocks, 70, 60)
         fp = fingerprint_pattern("spgemm_block", (a, b), block=16)
         PlanStore(tmp_path).put(fp, plan)
         back = PlanStore(tmp_path).get(fp)
         assert getattr(back, "_out_csr_index", None) is None   # not stored
+        with pytest.raises(ValueError, match="not built"):
+            block_result_to_csr(back, c_blocks, 70, 60)
+        np.testing.assert_array_equal(_block_tiles(back, a.data, b.data),
+                                      c_blocks)
         after = block_result_to_csr(back, c_blocks, 70, 60)
         for name in ("indptr", "indices", "data"):
             np.testing.assert_array_equal(getattr(after, name),
@@ -257,8 +271,7 @@ class TestPlannedExecution:
         a = _rand(64, 64, 0.1, 29, "blocky")
         plan = inspect_spgemm_block(a, a, 16)
         c = block_result_to_csr(
-            plan, spgemm_block_execute(plan, a.data, a.data,
-                                       use_pallas=False), 64, 64)
+            plan, _block_tiles(plan, a.data, a.data), 64, 64)
         for arr in (c.indptr, c.indices):
             assert not arr.flags.writeable
             with pytest.raises(ValueError):
