@@ -221,8 +221,8 @@ def phase_cholesky(*, k: int = 1, seed: int = 0) -> dict:
     return dict(matrix=f"{spec.chol_id} {spec.name}", a=_csr_desc(a),
                 l=f"nnz={plan.nnz} levels={plan.n_levels} "
                   f"{vals.dtype.name}",
-                executor="etree level steps (XLA gather/scatter), "
-                         f"overlap={st.get('overlap')}",
+                executor="etree level steps (XLA gather/scatter) over "
+                         "the plan's packed bundles on the device",
                 inspect_s=st["inspect_s"], run_s=run_s,
                 execute_s=st.get("execute_s"), rel_max_err=err,
                 bound=CHOLESKY_TOL, ok=bool(err <= CHOLESKY_TOL))

@@ -21,4 +21,4 @@ from .spgemm import (block_result_to_csr, block_result_to_dense,  # noqa: F401
                      spgemm, spgemm_gather_execute_chunk,
                      spgemm_ref_numpy)
 from .cholesky import (cholesky, cholesky_baseline_numpy,  # noqa: F401
-                       emit_level_bundle, init_values, plan_to_dense_l)
+                       init_values, plan_to_dense_l)

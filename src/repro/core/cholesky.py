@@ -10,16 +10,17 @@ module is the device-side numeric executor:
                vals[offd] /= vals[diag of column]
 
 The level loop is the only host interaction; within a level everything is a
-single jitted step over padded (bucketed) index arrays — the RIR padding
+single jitted step over padded (bucketed) index vectors — the RIR padding
 discipline keeps compiled shapes static, exactly like bundle capacity in the
 paper.  Matching the paper, the numeric phase is all fp32/fp64 FLOPs with no
 symbolic work on the device.
 
-The per-level host work (bundle-emit: building the padded cmod/cdiv index
-arrays) is factored into ``emit_level_bundle`` so the one executor,
-``runtime.pipeline.cholesky_execute_overlapped``, can prepare level group
-g+1 on a worker thread while the device executes group g — the software
-analogue of the paper's CPU/FPGA overlap.
+The index bundles (bundle-emit) depend only on the pattern, so they are
+packed once per plan into six flat index arrays and kept on the device
+(``CholeskyPlan.device_bundles``); the one executor,
+``runtime.pipeline.cholesky_execute_overlapped``, then sends only the value
+array per factor, and each level's step cuts its vectors from the packed
+arrays on the device.
 """
 from __future__ import annotations
 
@@ -31,58 +32,53 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.runtime import spans
 
 from .etree import CholeskyPlan, cholesky_values, inspect_cholesky
 from .formats import CSR
-from .inspector import next_pow2
 
 
-def _pad(arr: np.ndarray, size: int, fill: int) -> np.ndarray:
-    # stays numpy: bundle-emit may run on a worker thread, and host→device
-    # transfer belongs to the executor step (avoids jax dispatch contention)
-    out = np.full(size, fill, dtype=np.int64)
-    out[:arr.shape[0]] = arr
-    return out
+@functools.partial(jax.jit, static_argnums=(8, 9, 10), donate_argnums=(0,))
+def _level_program(vals, at, src1, src2, dst, diag, off, off_diag,
+                   upd_n, diag_n, off_n):
+    def cut(flat, k, n):
+        return lax.dynamic_slice(flat, (at[k],), (n,))
 
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _level_step(vals, src1, src2, dst, diag_idx, off_idx, off_diag):
-    """One etree level: cmod (gather–multiply–scatter-sub) then cdiv."""
-    contrib = vals[src1] * vals[src2]
-    vals = vals.at[dst].add(-contrib)            # dead slots hit scratch
+    s1, s2 = cut(src1, 0, upd_n), cut(src2, 0, upd_n)
+    contrib = vals[s1] * vals[s2]
+    vals = vals.at[cut(dst, 0, upd_n)].add(-contrib)    # dead: scratch
+    diag_idx = cut(diag, 1, diag_n)
     d = jnp.sqrt(vals[diag_idx])
     vals = vals.at[diag_idx].set(d)
-    vals = vals.at[off_idx].set(vals[off_idx] / vals[off_diag])
-    return vals
+    off_idx, off_diag_idx = cut(off, 2, off_n), cut(off_diag, 2, off_n)
+    vals = vals.at[off_idx].set(vals[off_idx] / vals[off_diag_idx])
+    return vals, at + jnp.array((upd_n, diag_n, off_n), at.dtype)
 
 
-def emit_level_bundle(plan: CholeskyPlan, ell: int) -> tuple:
-    """Bundle-emit stage for level ``ell``: padded device index arrays.
+@functools.partial(jax.jit, static_argnums=(0,))
+def _first_starts(dtype):
+    """Level 0's three slice starts, made on the device: a launch and no
+    put (an eager ``jnp.zeros`` transfers its fill value)."""
+    return jnp.zeros(3, dtype)
 
-    Pure host work with no dependence on numeric values, so it can run on a
-    worker thread one level ahead of the executor.
+
+def _level_step(state, src1, src2, dst, diag, off, off_diag,
+                upd_n, diag_n, off_n):
+    """One etree level: cmod (gather–multiply–scatter-sub) then cdiv.
+
+    ``state`` is (L values, the level's three slice starts).  The six index
+    arrays are a plan's packed ``LevelBundles``; the level's vectors are
+    their slices at the starts (cmod, diagonal, off-diagonal), of the
+    static bucket lengths ``*_n``, so one program serves every level with
+    the same buckets.  Levels lie back to back, so the step returns the
+    next level's starts — its own plus its bucket lengths — and they never
+    leave the device.  The values are donated, the starts are not: the
+    first level's come from the plan's memo and serve every factor.
     """
-    scratch = plan.nnz                           # dead-op slot
-    col_of_slot = plan.col_of_slot()
-    s1, s2, d = plan.upd_src1[ell], plan.upd_src2[ell], plan.upd_dst[ell]
-    cols = plan.cols_per_level[ell]
-    diag = plan.diag_pos[cols]
-    # off-diagonal slots of this level's columns + their diag slot
-    seg_starts = plan.col_ptr[cols] + 1          # skip the diagonal
-    seg_ends = plan.col_ptr[cols + 1]
-    counts = seg_ends - seg_starts
-    from .inspector import _ranges
-    off = _ranges(seg_starts, counts)
-    off_diag = plan.diag_pos[col_of_slot[off]]
-
-    bu = next_pow2(max(1, s1.shape[0]))
-    bc = next_pow2(max(1, diag.shape[0]))
-    bo = next_pow2(max(1, off.shape[0]))
-    return (_pad(s1, bu, scratch), _pad(s2, bu, scratch),
-            _pad(d, bu, scratch), _pad(diag, bc, scratch),
-            _pad(off, bo, scratch), _pad(off_diag, bo, scratch))
+    return _level_program(*state, src1, src2, dst, diag, off, off_diag,
+                          upd_n, diag_n, off_n)
 
 
 def init_values(plan: CholeskyPlan, a_vals: np.ndarray, dtype=jnp.float64):
@@ -149,9 +145,9 @@ def cholesky_baseline_numpy(plan: CholeskyPlan, a_vals: np.ndarray
 #
 # One fingerprint per pattern (dtype is a value-level choice and stays out
 # of the key).  The runtime builds the plan, then execute_sync runs it
-# through the level-group pipeline: the etree level schedule is the chunk
-# stream, so the overlap lives inside the executor rather than in a
-# chunked hook.
+# through ``cholesky_execute_overlapped``: one launch per etree level over
+# the plan's packed bundles, kept on the device, so the host dispatches
+# ahead of the device with no chunked hook.
 
 from .inspector import fingerprint_pattern  # noqa: E402
 from repro.runtime.ops import (OpCapabilities, OpSpec,  # noqa: E402

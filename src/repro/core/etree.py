@@ -16,12 +16,12 @@ construction of the elimination tree" (§III-B).  Outputs:
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+from typing import Any, Callable, List, NamedTuple, Optional
 
 import numpy as np
 
 from .formats import CSR
-from .inspector import PatternFingerprint, _ranges
+from .inspector import PatternFingerprint, _ranges, next_pow2
 
 
 def etree(a_lower: CSR) -> np.ndarray:
@@ -97,7 +97,9 @@ class CholeskyPlan:
 
     The plan is pattern-pure (no values of A, no timing): the numeric
     executor takes ``a_vals`` separately (see ``cholesky_values``), so one
-    plan amortizes over any number of same-pattern factorizations.
+    plan amortizes over any number of same-pattern factorizations.  The
+    executor keeps the per-level index bundles, packed, on the device
+    (``device_bundles``), so a warm factor sends only the value array.
     """
 
     n: int
@@ -135,6 +137,101 @@ class CholeskyPlan:
             cached = np.repeat(np.arange(self.n), np.diff(self.col_ptr))
             self._col_of_slot = cached
         return cached
+
+    def level_bundles(self) -> "LevelBundles":
+        """Every level's cmod and cdiv index vectors, packed into six flat
+        host arrays in one pass (see ``LevelBundles`` for the layout), and
+        the first level's slice starts.
+
+        Each level's slice is padded to the power of two ≥ its length, and
+        dead slots hold ``nnz``, the scratch slot.  The arrays are int32
+        where every slot index fits (``nnz + 1 < 2**31``), else int64.
+        """
+        dtype = np.int32 if self.nnz + 1 < 2**31 else np.int64
+        cols = self.cols_per_level
+        col_seq = (np.concatenate(cols) if cols
+                   else np.zeros(0, np.int64))
+        # off-diagonal slots of every level's columns, in level order, and
+        # the diagonal slot of each one's column
+        seg_starts = self.col_ptr[col_seq] + 1   # skip the diagonal
+        counts = self.col_ptr[col_seq + 1] - seg_starts
+        level_ends = np.concatenate([[0], np.cumsum(counts)])[
+            np.cumsum([c.shape[0] for c in cols], dtype=np.int64)]
+        off = np.split(_ranges(seg_starts, counts), level_ends[:-1])
+        off_diag = np.split(np.repeat(self.diag_pos[col_seq], counts),
+                            level_ends[:-1])
+        diag = [self.diag_pos[c] for c in cols]
+        upd, upd_n = _pack_levels(
+            (self.upd_src1, self.upd_src2, self.upd_dst), self.nnz, dtype)
+        (diag,), diag_n = _pack_levels((diag,), self.nnz, dtype)
+        offs, off_n = _pack_levels((off, off_diag), self.nnz, dtype)
+        return LevelBundles(*upd, diag, *offs, start=np.zeros(3, dtype),
+                            buckets=np.stack([upd_n, diag_n, off_n], 1))
+
+    def device_bundles(self, put: Callable) -> "LevelBundles":
+        """``level_bundles`` with its arrays on the device (memoized).
+
+        ``put`` takes the six host index arrays and ``start`` and returns
+        them on the device; it is called only on a miss.  Pattern-pure,
+        so it is built on the plan's first factor and every later factor
+        reuses it.  It holds the packed slots on the device (548 MB of
+        int32 for the bcsstk17 stand-in's 10,972 levels) for as long as
+        the plan lives: evicting the plan from its cache frees it.
+        Memoized as a plain attribute, not a dataclass field, so
+        serialization skips it, the plan cache's size estimate leaves it
+        out, and a loaded plan rebuilds it on first use.
+        """
+        cached = getattr(self, "_device_bundles", None)
+        if cached is None:
+            host = self.level_bundles()
+            cached = LevelBundles(*put(host[:7]), host.buckets)
+            self._device_bundles = cached
+        return cached
+
+
+class LevelBundles(NamedTuple):
+    """A Cholesky plan's per-level index bundles, packed flat.
+
+    Each array holds one slice per level, back to back in level order:
+    level ℓ's cmod triples in ``src1``/``src2``/``dst`` (``buckets[ℓ, 0]``
+    slots), the diagonal slots of its columns in ``diag``
+    (``buckets[ℓ, 1]``), and their off-diagonal slots with the diagonal
+    slot of each one's column in ``off``/``off_diag`` (``buckets[ℓ, 2]``).
+    So level ℓ's slices start at the sum of the earlier levels' buckets;
+    ``start`` holds level 0's three starts (zeros), the index dtype.
+    ``buckets`` is an (n_levels, 3) host array; the other arrays are on
+    the host or, from ``CholeskyPlan.device_bundles``, on the device.
+    """
+
+    src1: Any
+    src2: Any
+    dst: Any
+    diag: Any
+    off: Any
+    off_diag: Any
+    start: Any
+    buckets: np.ndarray
+
+
+def _pack_levels(kinds, fill: int, dtype):
+    """Pack per-level arrays into one flat array per kind.
+
+    ``kinds`` are lists of per-level arrays whose lengths agree level by
+    level.  Level ℓ gets a slice of ``next_pow2`` of its length, right
+    after the slices before it; the flat arrays are allocated once, filled
+    with ``fill``, and written by slice copies.  Returns the flat arrays
+    and each level's slice length.
+    """
+    lengths = [p.shape[0] for p in kinds[0]]
+    buckets = np.array([next_pow2(n) for n in lengths], np.int64)
+    starts = np.cumsum(buckets) - buckets
+    flats = []
+    for parts in kinds:
+        flat = np.full(int(buckets.sum()), fill, dtype)
+        for at, p in zip(starts.tolist(), parts):
+            flat[at:at + p.shape[0]] = p
+        flats.append(flat)
+    return flats, buckets
 
 
 def cholesky_values(a: CSR) -> np.ndarray:

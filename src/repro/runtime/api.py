@@ -15,7 +15,7 @@ Each op has exactly one executor.  An op with an ``execute_chunked`` hook
 (both SpGEMM routes) always runs it, at any ``n_chunks``: its pipeline in
 ``runtime.pipeline`` builds the chunk plans, and one chunk runs inline.
 Any other op builds its plan through ``inspect`` on a miss and runs
-``execute_sync`` (Cholesky's runs the level-group pipeline).  The one
+``execute_sync`` (Cholesky's launches one step per etree level).  The one
 exception is the sharded path: with a mesh, shardable ops run their
 ``shard_plan`` hook.
 

@@ -1,10 +1,11 @@
-"""Pipelined inspector/executor: the one executor of each chunked op.
+"""Pipelined inspector/executor: the one executor of each planned op.
 
 REAP's input controller keeps the FPGA pipelines busy while the CPU keeps
 producing RIR bundles; here the same overlap is software: the schedule-bundle
 stream is chunked, and while the device executes chunk *k* a worker thread
-emits chunk *k+1* (double-buffering).  Three pipelines, each the only
-executor of its op at any chunk count (one chunk runs inline):
+emits chunk *k+1* (double-buffering).  Two pipelines, each the only
+executor of its op at any chunk count (one chunk runs inline), and the
+Cholesky executor:
 
   * ``spgemm_gather_chunked`` — A's rows are partitioned into nnz-balanced
     chunks; each chunk is an independent Gustavson sub-problem whose output
@@ -12,11 +13,12 @@ executor of its op at any chunk count (one chunk runs inline):
   * ``spgemm_block_chunked`` — the block plan's pair schedule is cut at
     output-group starts; each chunk's operand tiles are scattered on the
     worker while the device runs the previous chunk's tile dots.
-  * ``cholesky_execute_overlapped`` — the etree level schedule is the chunk
-    stream: the padded cmod/cdiv index bundles of level group g+1 are
-    emitted on the worker thread while the device runs group g.
+  * ``cholesky_execute_overlapped`` — one launch per etree level.  The
+    levels' index bundles are pattern-pure, packed once per plan and kept
+    on the device, so nothing is left to emit: the host dispatches level
+    ℓ+1 while the device runs level ℓ, and a factor sends only its values.
 
-``run_overlapped`` is the shared engine.
+``run_overlapped`` is the shared engine of the two SpGEMM pipelines.
 """
 from __future__ import annotations
 
@@ -29,7 +31,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from repro.core.cholesky import (emit_level_bundle, init_values, _level_step)
+from repro.core.cholesky import _first_starts, _level_step, init_values
 from repro.core.etree import CholeskyPlan
 from repro.core.formats import CSR
 from repro.core.inspector import (PatternFingerprint, SpGemmBlockPlan,
@@ -539,76 +541,65 @@ def spgemm_block_chunked(a: CSR, b: CSR, block: int = 128, n_chunks: int = 4,
 
 
 # ---------------------------------------------------------------------------
-# Overlapped Cholesky (level schedule as the chunk stream)
+# Cholesky (one launch per etree level, bundles kept on the device)
 # ---------------------------------------------------------------------------
 
-def _level_groups(plan: CholeskyPlan, max_chunks: int) -> List[np.ndarray]:
-    """Split the level schedule into ≤ max_chunks work-balanced groups.
-
-    Per-handoff overhead (future round-trip) is amortized over a group of
-    levels; balancing by cmod count keeps both sides of the pipeline busy.
-    """
-    n = plan.n_levels
-    if n == 0:
-        return []
-    work = np.array([1.0 + s.shape[0] for s in plan.upd_src1])
-    cum = np.cumsum(work)
-    targets = cum[-1] * np.arange(1, min(max_chunks, n)) / min(max_chunks, n)
-    cuts = np.unique(np.searchsorted(cum, targets))
-    bounds = np.concatenate([[0], cuts + 1, [n]])
-    bounds = np.unique(bounds)
-    return [np.arange(bounds[i], bounds[i + 1])
-            for i in range(len(bounds) - 1)]
+def _put_level_bundles(host):
+    """Uploads a plan's packed level bundles (a memo miss): under
+    ``reap.h2d``, the six index arrays, their puts and bytes counted, and
+    one ``cholesky_bundle_builds``; the first level's starts (zeros) are
+    made on the device, with no put."""
+    with spans.span("reap.h2d"):
+        dev = [jnp.asarray(x) for x in host[:6]]
+        spans.count("h2d_puts", len(dev))
+        spans.count("h2d_bytes", sum(x.nbytes for x in dev))
+    spans.count("cholesky_bundle_builds")
+    return dev + [_first_starts(host[6].dtype)]
 
 
 def cholesky_execute_overlapped(plan: CholeskyPlan, a_vals: np.ndarray,
-                                dtype=jnp.float64, max_chunks: int = 16
+                                dtype=jnp.float64
                                 ) -> Tuple[np.ndarray, dict]:
-    """Numeric phase with bundle emission one level-group ahead.
+    """Numeric phase: one ``_level_step`` launch per etree level.
 
-    Level ℓ+1's padded index bundles depend only on the plan (pattern), not
-    on numeric results, so emission overlaps the device's level-ℓ step.
-    Levels are batched into ≤ ``max_chunks`` work-balanced groups so the
-    per-handoff thread overhead is amortized (etree schedules routinely have
-    hundreds of tiny levels).  ``a_vals`` are A's lower-triangle values in
-    the plan's order (``plan.a_values(a)`` or ``cholesky_values(a)``);
-    returns (L values in CSC order, stats).
+    The levels' index bundles live on the device, packed into six flat
+    arrays (``plan.device_bundles``: pattern-pure, built on the plan's
+    first factor and freed with the plan), with the first level's slice
+    starts, so a factor sends only the value array; each step hands the
+    next level its starts on the device, so a launch sends nothing.
+    Dispatch is asynchronous, so the host issues level ℓ+1 while the
+    device runs level ℓ.  ``a_vals`` are A's lower-triangle values in the
+    plan's order (``plan.a_values(a)`` or ``cholesky_values(a)``); returns
+    (L values in CSC order, stats).
+
+    Spans: ``reap.values`` (the bundle memo — a lookup on a warm plan; on
+    the plan's first factor the packing, with the upload nested under
+    ``reap.h2d`` — and the put of the values), ``reap.dispatch`` (the
+    launch loop) and ``reap.drain`` (the wait for the device).  Counters:
+    ``launches``, ``h2d_puts``/``h2d_bytes`` (the values; the memo's
+    arrays too on the factor that builds it) and
+    ``cholesky_bundle_builds`` (1 on that factor, else 0).
     """
+    spans.count("cholesky_bundle_builds", 0)
     with spans.span("reap.values"):
-        state = [init_values(plan, a_vals, dtype)]
+        bundles = plan.device_bundles(_put_level_bundles)
+        vals = init_values(plan, a_vals, dtype)
         spans.count("h2d_puts", 1)
-        spans.count("h2d_bytes", state[0].nbytes)
-    groups = _level_groups(plan, max_chunks)
-
-    def inspect_fn(k: int):
-        bundles = [emit_level_bundle(plan, int(ell)) for ell in groups[k]]
-        return bundles, sum(arr.nbytes for b in bundles for arr in b)
-
-    def execute_fn(k: int, emitted) -> None:
-        # one launch per level; each of a bundle's host arrays is one
-        # host→device put when the step is called
-        bundles, nbytes = emitted
-        for bundle in bundles:
-            state[0] = _level_step(state[0], *bundle)
-        spans.count("launches", len(bundles))
-        spans.count("h2d_puts", sum(len(b) for b in bundles))
-        spans.count("h2d_bytes", nbytes)
-
-    _, ostats = run_overlapped(len(groups), inspect_fn, execute_fn,
-                               execute_span="reap.dispatch")
+        spans.count("h2d_bytes", vals.nbytes)
+    state = (vals, bundles.start)
+    index = bundles[:6]
+    with spans.span("reap.dispatch") as dispatch:
+        for n in bundles.buckets.tolist():
+            state = _level_step(state, *index, *n)
+        spans.count("launches", plan.n_levels)
     vals = state[0]
     with spans.span("reap.drain") as drain:
         # reaplint: disable=REAP003 deliberate drain: queued device work is
         # blocked on inside the timed region so execute_s measures device
         # completion, not dispatch
         vals.block_until_ready()
-    execute_s = ostats.execute_s + drain.seconds
-    wall_s = ostats.wall_s + drain.seconds
-    stats = dict(execute_s=execute_s, emit_s=ostats.inspect_s,
-                 wall_s=wall_s,
-                 hidden_s=max(0.0, ostats.inspect_s + execute_s - wall_s),
-                 overlap=ostats.overlap, n_levels=plan.n_levels,
-                 nnz_l=plan.nnz, flops=plan.flops())
+    stats = dict(execute_s=dispatch.seconds + drain.seconds,
+                 n_levels=plan.n_levels, nnz_l=plan.nnz, flops=plan.flops())
     return spans.to_host(vals[:plan.nnz]), stats
 
 

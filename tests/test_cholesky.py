@@ -1,5 +1,6 @@
 """Sparse Cholesky: symbolic analysis + level-scheduled numeric executor."""
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 import jax.numpy as jnp
@@ -8,6 +9,7 @@ from repro.core import (cholesky, cholesky_baseline_numpy, cholesky_values,
                         etree, etree_levels, inspect_cholesky, random_spd_csr,
                         plan_to_dense_l)
 from repro.core.formats import CSR
+from repro.runtime import ReapRuntime, spans
 
 
 def _spd(n, density, seed, pattern="banded"):
@@ -96,3 +98,135 @@ class TestSymbolicAndNumeric:
         _, _, stats = cholesky(a)
         assert stats["inspect_s"] > 0 and stats["execute_s"] > 0
         assert stats["n_levels"] >= 1
+
+
+def _fresh_spd_values(a: CSR, seed: int) -> CSR:
+    """Fresh SPD values on ``a``'s (symmetric) pattern: symmetric normal
+    off-diagonals, diagonal 1 + the row's off-diagonal magnitudes."""
+    rng = np.random.default_rng(seed)
+    d = a.to_dense()
+    r = rng.standard_normal(d.shape)
+    off = np.where((d != 0) & ~np.eye(a.n_rows, dtype=bool), (r + r.T) / 2,
+                   0.0)
+    return CSR.from_dense(off + np.diag(1.0 + np.abs(off).sum(1)))
+
+
+def _per_level_bundle(plan, ell: int):
+    """Level ``ell``'s six index vectors built plainly: the cmod triples,
+    the diagonal slots of its columns, their off-diagonal slots and the
+    diagonal slot of each one's column, each padded with ``nnz`` to the
+    power of two ≥ its length."""
+    cols = plan.cols_per_level[ell]
+    off = [np.arange(plan.col_ptr[c] + 1, plan.col_ptr[c + 1]) for c in cols]
+    off_diag = [np.full(o.shape[0], plan.diag_pos[c])
+                for o, c in zip(off, cols)]
+
+    def pad(x):
+        x = np.asarray(x, np.int64)
+        size = 1
+        while size < x.shape[0]:
+            size *= 2
+        out = np.full(size, plan.nnz, np.int64)
+        out[:x.shape[0]] = x
+        return out
+
+    return [pad(plan.upd_src1[ell]), pad(plan.upd_src2[ell]),
+            pad(plan.upd_dst[ell]), pad(plan.diag_pos[cols]),
+            pad(np.concatenate(off)), pad(np.concatenate(off_diag))]
+
+
+class TestLevelBundles:
+    """The executor's per-plan memo of the packed level bundles
+    (``CholeskyPlan.level_bundles`` / ``device_bundles``)."""
+
+    @pytest.mark.parametrize("pattern", ["banded", "uniform", "blocky"])
+    def test_layout_matches_per_level_construction(self, pattern):
+        plan = inspect_cholesky(_spd(60, 0.1, 19, pattern))
+        packed = plan.level_bundles()
+        kind_group = (0, 0, 0, 1, 2, 2)     # src1/src2/dst, diag, off pair
+        assert packed.buckets.shape == (plan.n_levels, 3)
+        for k, flat in enumerate(packed[:6]):
+            assert flat.dtype == np.int32
+            # the levels' slices tile the flat array, in level order
+            assert flat.shape[0] == packed.buckets[:, kind_group[k]].sum()
+        at = np.zeros(3, np.int64)
+        for ell in range(plan.n_levels):
+            n = packed.buckets[ell]
+            for k, want in enumerate(_per_level_bundle(plan, ell)):
+                g = kind_group[k]
+                np.testing.assert_array_equal(
+                    packed[k][at[g]:at[g] + n[g]], want)
+            at += n
+
+    @pytest.mark.parametrize("pattern", ["banded", "uniform"])
+    def test_cold_then_warm_factor_through_the_runtime(self, pattern):
+        rt = ReapRuntime()
+        a = _spd(70, 0.08, 17, pattern)
+        plans, builds, puts = [], [], []
+        for seed in (1, 2):
+            a2 = _fresh_spd_values(a, seed)
+            (plan, vals), st = rt.run("cholesky", a2)
+            base, _ = cholesky_baseline_numpy(plan, plan.a_values(a2))
+            np.testing.assert_allclose(vals, base, rtol=1e-12, atol=1e-13)
+            plans.append(plan)
+            builds.append(st.counters["cholesky_bundle_builds"])
+            puts.append(st.counters["h2d_puts"])
+            assert st.counters["launches"] == plan.n_levels
+        assert plans[0] is plans[1]
+        assert builds == [1, 0]
+        # the values; the memo's six index arrays cold
+        assert puts == [1 + 6, 1]
+
+    def test_level_launches_send_nothing_from_the_host(self, monkeypatch):
+        """Each level's step reads only device arrays: its slice starts
+        come from the step before, its bucket lengths are static."""
+        import jax
+        import repro.runtime.pipeline as pl
+        real = pl._level_step
+
+        def guarded(*args):
+            with jax.transfer_guard_host_to_device("disallow"):
+                return real(*args)
+        monkeypatch.setattr(pl, "_level_step", guarded)
+        a = _spd(60, 0.1, 29, "uniform")
+        plan = inspect_cholesky(a)
+        a_vals = cholesky_values(a)
+        for _ in range(2):                  # the build factor, then warm
+            vals, _ = pl.cholesky_execute_overlapped(plan, a_vals)
+            base, _ = cholesky_baseline_numpy(plan, a_vals)
+            np.testing.assert_allclose(vals, base, rtol=1e-12, atol=1e-13)
+
+    @pytest.mark.parametrize("via", ["serialize", "plan_store"])
+    def test_round_trip_carries_no_memo_and_rebuilds_it(self, via,
+                                                        tmp_path):
+        from repro.core.inspector import fingerprint_pattern
+        from repro.runtime import PlanStore, cholesky_execute_overlapped
+        from repro.runtime.plan_cache import (_entry_nbytes,
+                                              deserialize_plan,
+                                              serialize_plan)
+        a = _spd(50, 0.1, 23)
+        a_vals = cholesky_values(a)
+        plan = inspect_cholesky(a)
+        nbytes = _entry_nbytes(plan)
+        before, _ = cholesky_execute_overlapped(plan, a_vals)
+        assert _entry_nbytes(plan) == nbytes      # the memo is not counted
+        if via == "serialize":
+            back = deserialize_plan(serialize_plan(plan))
+        else:
+            fp = fingerprint_pattern("cholesky", (a,))
+            PlanStore(tmp_path).put(fp, plan)
+            back = PlanStore(tmp_path).get(fp)
+        assert getattr(back, "_device_bundles", None) is None  # not stored
+        builds = []
+        for _ in range(2):
+            with spans.record("reap.run") as rec:
+                after, _ = cholesky_execute_overlapped(back, a_vals)
+            builds.append(rec.counters["cholesky_bundle_builds"])
+            np.testing.assert_array_equal(after, before)
+        assert builds == [1, 0]
+        rebuilt = back.device_bundles(put=None)
+        original = plan.device_bundles(put=None)
+        assert rebuilt is not original
+        for got, want in zip(rebuilt, original):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

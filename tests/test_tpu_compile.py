@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.cholesky import _level_step
+from repro.core.cholesky import _level_program
 from repro.core.spgemm import _gather_execute_capped
 from repro.kernels.bsr_spgemm import LAUNCH_PAIRS, bsr_spgemm
 from repro.kernels.bsr_spmm import bsr_spmm
@@ -118,8 +118,11 @@ def test_sharded_gather_executor(topo):
 
 @pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
 def test_cholesky_level_step(one_chip, dtype):
-    """One etree level of the C3 factor (L nnz ~860k, 4096 cmod triples)."""
+    """One etree level of the C3 factor (L nnz ~860k, 4096 cmod triples),
+    cut from packed int32 bundles of the bcsstk17 stand-in's sizes."""
     n_vals, bu, bc, bo = 860_001, 4096, 1, 128
-    i = lambda n: one_chip((n,), jnp.int64)      # noqa: E731
-    _level_step.lower(one_chip((n_vals,), dtype), i(bu), i(bu), i(bu),
-                      i(bc), i(bo), i(bo)).compile()
+    n_upd, n_diag, n_off = 44_738_465, 10_975, 1_398_412
+    i = lambda n: one_chip((n,), jnp.int32)      # noqa: E731
+    _level_program.lower(one_chip((n_vals,), dtype), i(3), i(n_upd),
+                         i(n_upd), i(n_upd), i(n_diag), i(n_off), i(n_off),
+                         bu, bc, bo).compile()
