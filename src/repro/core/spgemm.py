@@ -108,9 +108,11 @@ def spgemm_gather_execute_chunk(plan: SpGemmGatherPlan, a_data: np.ndarray,
                                 b_data: np.ndarray) -> np.ndarray:
     """Execute one gather plan with bucketed shapes; returns (c_nnz,) values.
 
-    The plan is a chunk's (``runtime.pipeline.spgemm_gather_chunked``) or
-    a whole matrix's; ``a_data``/``b_data`` are the CSR values it was built
-    for.
+    The plan is a chunk's or a whole matrix's; ``a_data``/``b_data`` are
+    the CSR values it was built for.  ``runtime.pipeline.
+    spgemm_gather_chunked`` runs one call per chunk of A's rows, the chunks
+    nnz-balanced (``chunk_row_bounds``), and counts the largest chunk's
+    live products once per product as ``gather_chunk_products_max``.
 
     The plan's index arrays, padded to the bucketed length, stay on the
     device (``plan.device_indices``: pattern-pure, built on the plan's

@@ -158,7 +158,19 @@ def run_overlapped(n_chunks: int,
 # ---------------------------------------------------------------------------
 
 def chunk_row_bounds(a: CSR, n_chunks: int) -> np.ndarray:
-    """Partition A's rows into ≤ n_chunks contiguous, nnz-balanced ranges."""
+    """Partition A's rows into ≤ n_chunks contiguous, nnz-balanced ranges.
+
+    Each cut is the first row boundary at or past its share of A's nnz;
+    rows are never split and empty ranges are merged.  A chunk's device
+    cost follows its live partial products padded to a power-of-two bucket
+    (``SpGemmGatherPlan.device_indices``), not its nnz, so on a skewed
+    pattern the chunks' products differ (``spgemm_gather_chunked`` counts
+    the largest as ``gather_chunk_products_max``).  Cutting by products
+    instead is not better by itself: on the webbase-1M stand-in it evens
+    the products (largest chunk 20.0 % over the mean → 0.01 %) but lifts
+    the three chunks that sit just under 2**24 products over that bucket
+    edge, 83.9 M → 134.2 M padded slots.
+    """
     n_chunks = max(1, min(n_chunks, a.n_rows))
     targets = a.nnz * np.arange(1, n_chunks) / n_chunks
     cuts = np.searchsorted(a.indptr, targets, side="left")
@@ -197,11 +209,16 @@ def spgemm_gather_chunked(a: CSR, b: CSR, n_chunks: int = 4,
     list lookup and the pipeline is pure execution.  Returns
     (C, stats, chunkset) so callers can cache the chunk set.
 
+    A's rows are cut into nnz-balanced chunks (``chunk_row_bounds``); a
+    warm ``chunkset`` keeps the bounds it was built with.
+
     Spans, besides ``run_overlapped``'s: ``reap.inspect`` around each chunk
     plan built on a miss (inside that chunk's ``reap.emit``; their sum is
     ``stats["plan_s"]``, 0 on a warm call), the executor's own
     (``spgemm_gather_execute_chunk``), and ``reap.extract`` around the
-    stitch of the chunk results into one CSR.
+    stitch of the chunk results into one CSR.  Counter, besides the
+    executor's: ``gather_chunk_products_max``, the largest chunk plan's
+    live partial products, once per call.
     """
     bounds = (chunkset.row_bounds if chunkset is not None
               else chunk_row_bounds(a, n_chunks))
@@ -224,6 +241,8 @@ def spgemm_gather_chunked(a: CSR, b: CSR, n_chunks: int = 4,
         return spgemm_gather_execute_chunk(plan, a.data[s:e], b.data)
 
     chunks, ostats = run_overlapped(nk, inspect_fn, execute_fn)
+    spans.count("gather_chunk_products_max",
+                max((p.n_pp for p in plans), default=0))
 
     # stitch: chunk output rows are disjoint, contiguous, and ordered
     with spans.span("reap.extract"):

@@ -379,3 +379,77 @@ class TestChunkedGatherOnMesh:
         np.testing.assert_array_equal(c.indptr, ref.indptr)
         np.testing.assert_array_equal(c.indices, ref.indices)
         np.testing.assert_allclose(c.data, ref.data, rtol=1e-5, atol=1e-5)
+
+
+def _directed_powerlaw(n: int, seed: int, hub_len: int = 1200) -> CSR:
+    """A directed pattern with heavy-tailed out- and in-degrees: a third of
+    the rows empty, the rest Pareto out-degrees whose targets are drawn with
+    Pareto popularity, and row 5 a hub of ``hub_len`` distinct targets."""
+    rng = np.random.default_rng(seed)
+    deg = np.minimum(np.floor((1 - rng.random(n)) ** -1.0), 60).astype(int)
+    deg[rng.random(n) < 1 / 3] = 0
+    pop = (1 - rng.random(n)) ** -1.2
+    src = np.repeat(np.arange(n), deg)
+    dst = rng.choice(n, src.shape[0], p=pop / pop.sum())
+    src = np.concatenate([src, np.full(hub_len, 5)])
+    dst = np.concatenate([dst, rng.choice(n, hub_len, replace=False)])
+    key = np.unique(src * n + dst)
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum(np.bincount(key // n, minlength=n), out=indptr[1:])
+    return CSR(n, n, indptr, key % n,
+               rng.standard_normal(key.shape[0]).astype(np.float32))
+
+
+class TestGatherOnDirectedPowerLaw:
+    """The chunked gather pipeline on a directed power-law pattern (the
+    shape of a web graph): empty rows, a hub row whose entries and partial
+    products each fill more than one 1,024-slot tile, A ≠ Aᵀ."""
+
+    N = 2500
+
+    @pytest.mark.parametrize("n_chunks", [1, 4])
+    def test_matches_numpy_and_scipy(self, n_chunks):
+        import scipy.sparse as sp
+        from repro.runtime.pipeline import spgemm_gather_chunked
+        a = _directed_powerlaw(self.N, 50)
+        rows = a.row_lengths
+        assert (rows == 0).sum() > self.N // 4 and rows.max() > 1024
+        products = np.bincount(a.nnz_rows(), rows[a.indices],
+                               minlength=self.N)
+        assert products.max() > 1024
+        m = sp.csr_matrix((a.data.astype(np.float64), a.indices, a.indptr),
+                          shape=(self.N, self.N))
+        assert (m != m.T).nnz > 0
+        c, stats, chunkset = spgemm_gather_chunked(a, a, n_chunks=n_chunks)
+        assert stats["n_chunks"] == n_chunks
+        assert stats["n_pp"] == int(products.sum())
+        ref = spgemm_ref_numpy(a, a)
+        np.testing.assert_array_equal(c.indptr, ref.indptr)
+        np.testing.assert_array_equal(c.indices, ref.indices)
+        np.testing.assert_allclose(c.data, ref.data, rtol=1e-5, atol=1e-5)
+        want = (m @ m).toarray()
+        np.testing.assert_allclose(c.to_dense().astype(np.float64), want,
+                                   rtol=1e-5, atol=1e-4)
+
+    def test_counts_the_largest_chunks_products(self):
+        from repro.runtime import spans
+        a = _directed_powerlaw(self.N, 51)
+        rt = ReapRuntime(n_chunks=4)
+        for _ in range(2):                      # plan miss, then warm
+            _, stats = rt.run("spgemm", a, a, method="gather")
+            rec = spans.recent(1)[0]
+            counters = stats.counters
+            assert rec.op == "spgemm_gather" and rec.calls["reap.launch"] == 4
+            assert counters["gather_chunk_products_max"] \
+                == rec.counters["gather_chunk_products_max"]
+            # the largest of the four chunks' live products, counted once
+            assert counters["gather_products"] / 4 \
+                <= counters["gather_chunk_products_max"] \
+                <= counters["gather_products"]
+        from repro.runtime.pipeline import chunk_row_bounds
+        bounds = chunk_row_bounds(a, 4)
+        products = np.bincount(a.nnz_rows(), a.row_lengths[a.indices],
+                               minlength=self.N)
+        per_chunk = np.add.reduceat(products, bounds[:-1])
+        assert counters["gather_chunk_products_max"] == per_chunk.max()
+        assert counters["gather_products"] == per_chunk.sum()

@@ -98,6 +98,17 @@ def test_gather_executor_cop20k_chunk(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+def test_gather_executor_webbase1m_chunk(one_chip):
+    """The largest of four chunks of the webbase1M stand-in's A² (about
+    20.9M partial products, bucketed to 2**25 slots; about 15.5M outputs,
+    to 2**24), on the chunk's share of A's 3.1M values."""
+    idx = one_chip((1 << 25,), jnp.int64)
+    compiled = _gather_execute_capped.lower(
+        one_chip((776_384,), jnp.float32), one_chip((3_105_536,), jnp.float32),
+        idx, idx, idx, c_cap=1 << 24).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def test_sharded_gather_executor(topo):
     """The sharded gather-SpGEMM program over the four chips of a v5e:2x2."""
     from repro.runtime.shard import _gather_shard_fn
